@@ -21,6 +21,7 @@ out/mlp-out row-parallel).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
@@ -37,6 +38,37 @@ def _mesh_has_axis(axis: str) -> bool:
 
     mesh = current_mesh()
     return mesh is not None and axis in mesh.shape and mesh.shape[axis] > 1
+
+
+def _head_axis(mesh, n_heads: int) -> Optional[str]:
+    """The mesh axis the head dimension shards over: ``model`` when heads
+    are tensor-parallel (column-sharded Wqkv) and divide evenly — the
+    kernel then runs on local heads instead of all-gathering activations
+    over ``model``."""
+    size = mesh.shape.get("model", 1)
+    return "model" if size > 1 and n_heads % size == 0 else None
+
+
+def _sharded_flash(flash, mesh, q, k, v, kmask):
+    """The flash kernel under a multi-device mesh. Mosaic kernels are opaque
+    to GSPMD ("cannot be automatically partitioned" — a lowering error, first
+    seen on the four-chip host), so the call runs inside a shard_map over
+    the axes attention is independent along: batch rows over ``data``,
+    heads over ``model`` (see ``_head_axis``). Every other mesh axis sees
+    replicated operands."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    data = "data" if q.shape[0] % mesh.shape.get("data", 1) == 0 else None
+    spec = P(data, None, _head_axis(mesh, q.shape[2]), None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if kmask is not None:
+        args, in_specs = args + (kmask,), in_specs + (P(data, None),)
+    # pallas_call outputs carry no vma annotation: check_vma off
+    return shard_map(
+        lambda q_, k_, v_, m_=None: flash(q_, k_, v_, kmask=m_),
+        mesh=mesh, in_specs=in_specs, out_specs=spec,
+        check_vma=False)(*args)
 
 
 _FLASH_BLOCKS: Dict[str, int] = {}
@@ -144,15 +176,9 @@ class MultiHeadAttention(LayerConfig):
             from deeplearning4j_tpu.parallel.context import current_mesh
 
             mesh = current_mesh()
-            # tp+sp composition: when heads are tensor-parallel (column-sharded
-            # Wqkv) and divide evenly, keep the head axis sharded through the
-            # ring kernel instead of all-gathering activations over "model".
-            head_axis = (
-                "model"
-                if ("model" in mesh.shape and mesh.shape["model"] > 1
-                    and q.shape[2] % mesh.shape["model"] == 0)
-                else None
-            )
+            # tp+sp composition: keep tensor-parallel heads sharded through
+            # the ring kernel
+            head_axis = _head_axis(mesh, q.shape[2])
             # flash-backed ring (Pallas chunk kernels + exact lse merge) on
             # TPU, same policy as the single-chip flash gate; forced
             # use_flash=True engages it anywhere. kmask rides the ring
@@ -176,13 +202,18 @@ class MultiHeadAttention(LayerConfig):
                 # Block sizes are env-tunable for perf sweeps; validated and
                 # captured at first use (see _flash_block); 128/128 is the
                 # measured default.
-                bq = _flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128)
-                bk = _flash_block("DL4J_TPU_FLASH_BLOCK_K", 128)
-                return flash_attention(q, k, v, kmask=kmask,
-                                       causal=self.causal,
-                                       block_q=bq, block_k=bk,
-                                       interpret=not on_tpu,
-                                       bwd="pallas" if on_tpu else "xla")
+                from deeplearning4j_tpu.parallel.context import (
+                    partitioning_mesh)
+
+                flash = functools.partial(
+                    flash_attention, causal=self.causal,
+                    block_q=_flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128),
+                    block_k=_flash_block("DL4J_TPU_FLASH_BLOCK_K", 128),
+                    interpret=not on_tpu, bwd="pallas" if on_tpu else "xla")
+                mesh = partitioning_mesh()
+                if mesh is None:
+                    return flash(q, k, v, kmask=kmask)
+                return _sharded_flash(flash, mesh, q, k, v, kmask)
         return local_attention(q, k, v, causal=self.causal, kmask=kmask)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):

@@ -206,13 +206,22 @@ class LSTM(BaseRecurrent):
     def apply_seq(self, params, x, carry, mask=None):
         import os as _os
 
+        from deeplearning4j_tpu.ops.fused_lstm import fits_vmem, fused_lstm
+        from deeplearning4j_tpu.parallel.context import partitioning_mesh
+
         policy = _os.environ.get("DL4J_TPU_FUSED_LSTM", "auto")
         on_tpu = jax.default_backend() == "tpu"
-        use_fused = (policy == "1" or (policy == "auto" and on_tpu)) \
+        # auto: on the TPU, for shapes whose resident set fits VMEM, and not
+        # under a multi-device mesh — GSPMD cannot partition a Mosaic kernel
+        # (the forced "1" skips these checks and surfaces the compiler's
+        # own error)
+        auto = policy == "auto" and on_tpu \
+            and partitioning_mesh() is None and fits_vmem(
+                x.shape[0], self.n_out, jnp.dtype(x.dtype).itemsize)
+        use_fused = (policy == "1" or auto) \
             and self._fused_eligible() and not _fused_suppressed()
         if not use_fused:
             return super().apply_seq(params, x, carry, mask)
-        from deeplearning4j_tpu.ops.fused_lstm import fused_lstm
 
         zx = self._input_proj(params, x)
         h0, c0 = carry

@@ -17,8 +17,8 @@ ledger nobody has to pay twice for:
   signature via :func:`note_exemplar`: ``shaped_abstractify`` avals plus a
   weakref to the dispatcher, never live buffers. Resolution is deferred to
   :func:`cost_report`: ``jit.lower(*avals)`` with the exact avals hits
-  jax's jaxpr cache (no re-trace, no compile-counter pollution — verified
-  against jax 0.4.37) and ``Lowered.cost_analysis()`` prices the HLO
+  jax's jaxpr cache (no re-trace, no compile-counter pollution) and
+  ``Lowered.cost_analysis()`` prices the HLO
   without compiling. Lazy entries have no ``memory_analysis`` (that needs a
   compile), so ``peak_hbm_bytes`` is reported only for AOT-warmed sites.
 - **Roofline division** — achieved per-dispatch wall time comes from the
@@ -118,12 +118,9 @@ def _gauges():
 # ---------------------------------------------------------------------------
 
 def _device_kind() -> str:
-    try:
-        import jax
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
+    return jax.devices()[0].device_kind
 
 
 def roofline(device_kind: Optional[str] = None) -> dict:

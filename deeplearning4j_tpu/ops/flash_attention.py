@@ -39,13 +39,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU builds too; guard for safety
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
 
@@ -165,9 +159,7 @@ def _fwd_pallas_call(qt, kt, vt, *, D, bq, bk, q_pad, k_pad, t_real_k,
         _kernel, block_q=bq, block_k=bk, t_real=t_real_k, t_pad=k_pad,
         causal=causal, scale=scale, q_off=q_off, k_off=k_off,
         has_kmask=kmask is not None)
-    kw = {}
-    if _VMEM is not None and not interpret:
-        kw["memory_space"] = _VMEM
+    kw = {} if interpret else {"memory_space": pltpu.VMEM}
     in_specs = [
         pl.BlockSpec((1, bq, D), lambda bh, qi: (bh, qi, 0), **kw),
         pl.BlockSpec((1, k_pad, D), lambda bh, qi: (bh, 0, 0), **kw),
@@ -335,9 +327,7 @@ def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, D, bq, bk, q_pad,
     padded (dq, dk, dv). ``delta`` may already carry the -dlse shift.
     ``kmask``: optional [B, 1, k_pad] f32 rows (per batch; bh // H)."""
     BH = qt.shape[0]
-    kw = {}
-    if _VMEM is not None and not interpret:
-        kw["memory_space"] = _VMEM
+    kw = {} if interpret else {"memory_space": pltpu.VMEM}
     full = lambda bh, i: (bh, 0, 0)          # noqa: E731
     blkq = lambda bh, i: (bh, i, 0)          # noqa: E731
     row = lambda bh, i: (bh, 0, i)           # noqa: E731

@@ -20,6 +20,14 @@ def current_mesh() -> Optional[Mesh]:
     return _ACTIVE_MESH
 
 
+def partitioning_mesh() -> Optional[Mesh]:
+    """The active mesh if it spans more than one device, else None: the
+    case where GSPMD partitions the jitted step, which it cannot do to a
+    Mosaic kernel — Pallas-backed layers ask this before taking one."""
+    mesh = _ACTIVE_MESH
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
     global _ACTIVE_MESH

@@ -83,6 +83,7 @@ from jax.flatten_util import ravel_pytree
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.nn.model import MultiLayerNetwork, _iter_batches
+from deeplearning4j_tpu.parallel.pipeline import pvary
 from deeplearning4j_tpu.parallel.ring import shard_map
 from deeplearning4j_tpu.train.updaters import make_updater
 
@@ -461,17 +462,7 @@ class GPipeTrainer:
         def make_shard_fn(with_masks: bool):
             def shard_fn(params_local, x_mic, rng_, masks_=None):
                 def _pvary(x):
-                    try:
-                        return lax.pcast(x, axis_name, to="varying")
-                    except ValueError:  # already varying over the pipe axis
-                        return x
-                    except (AttributeError, TypeError):
-                        pass
-                    try:
-                        return lax.pvary(x, axis_name)  # jax ~0.5/0.6
-                    except AttributeError:
-                        # jax 0.4.x: no varying-axis aval types to cast
-                        return x
+                    return pvary(x, axis_name)
 
                 # Each branch is rematerialized (jax.checkpoint): classic
                 # GPipe per-stage activation recomputation, AND it makes
@@ -516,21 +507,8 @@ class GPipeTrainer:
         # outputs carry no vma) therefore cannot run inside stages: the
         # fused-LSTM dispatch is suppressed at trace time (see
         # no_fused_lstm in fit_batch / nn/layers/recurrent.py).
-        try:
-            return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                             out_specs=out_specs)(*args)
-        except Exception as e:  # noqa: BLE001 — jax raises bare Exception here
-            # jax 0.4.x has no pvary, so the lax.switch branches cannot be
-            # unified under its replication checker ("mismatched replication
-            # types"). The check is static-only; disabling it keeps the
-            # psum/ppermute ring semantics intact on 0.4.x.
-            if "replication" not in str(e) and "check_rep" not in str(e):
-                raise
-            try:
-                return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)(*args)
-            except TypeError:
-                raise e
+        return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
+                         out_specs=out_specs)(*args)
 
     def _loss(self, params, x_micro, y_micro, rng, masks_all=None,
               head_mask=None):

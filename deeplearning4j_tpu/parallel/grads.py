@@ -46,8 +46,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from jax.experimental.shard_map import shard_map
+from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu import obs
@@ -417,7 +416,7 @@ class DataParallelStep:
             call, "dp.step", model=self.model,
             wrap_body=lambda b: shard_map(
                 b, mesh=self.mesh, in_specs=in_specs,
-                out_specs=out_specs, check_rep=False),
+                out_specs=out_specs, check_vma=False),
             guard_site="cg.step" if self.is_graph else "mln.step",
             hits_site="dp.fit", extra_allowed=1)
 

@@ -28,6 +28,14 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deeplearning4j_tpu.parallel.ring import shard_map
 
 
+def pvary(x, axis_name):
+    """Type ``x`` as device-varying over ``axis_name`` (a no-op when it
+    already is — ``lax.pcast`` refuses varying -> varying)."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return lax.pcast(x, axis_name, to="varying")
+
+
 def _gpipe_shard(params_local, x_micro, *, stage_apply, axis_name, n_stages,
                  aux_width=None, aux_combine=None):
     """Runs on each pipe rank. params_local: this rank's stage params (leading
@@ -76,19 +84,7 @@ def _gpipe_shard(params_local, x_micro, *, stage_apply, axis_name, n_stages,
 
     # carries must be typed as device-varying over the pipe axis from the
     # start (they become varying after the first ppermute/update)
-    def _pvary(x):
-        try:
-            return lax.pcast(x, axis_name, to="varying")
-        except ValueError:  # already varying
-            return x
-        except (AttributeError, TypeError):
-            pass
-        try:
-            return lax.pvary(x, axis_name)  # jax ~0.5/0.6 spelling
-        except AttributeError:
-            # jax 0.4.x: avals carry no varying-axis type, so there is
-            # nothing to cast — the carry is usable as-is
-            return x
+    _pvary = functools.partial(pvary, axis_name=axis_name)
 
     buf = _pvary(jnp.zeros_like(x_micro[0]))
     outs = _pvary(jnp.zeros_like(x_micro))

@@ -21,13 +21,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # jax>=0.6 top-level, older: experimental
-    from jax import shard_map  # type: ignore
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 _NEG_BIG = -1e30
 
@@ -184,19 +179,10 @@ def ring_self_attention(
             interpret=jax.default_backend() != "tpu")
         in_specs = (spec, spec, spec) if kmask is None else (spec, spec, spec, mspec)
         args = (q, k, v) if kmask is None else (q, k, v, kmask)
-        try:
-            # pallas_call outputs carry no vma annotation; disable the
-            # shard_map varying-axes check for this (correct) spec
-            return shard_map(fn_flash, mesh=mesh, in_specs=in_specs,
-                             out_specs=spec, check_vma=False)(*args)
-        except TypeError:
-            pass
-        try:  # jax 0.4/0.5 spell the same knob check_rep
-            return shard_map(fn_flash, mesh=mesh, in_specs=in_specs,
-                             out_specs=spec, check_rep=False)(*args)
-        except TypeError:  # neither parameter exists
-            return shard_map(fn_flash, mesh=mesh, in_specs=in_specs,
-                             out_specs=spec)(*args)
+        # pallas_call outputs carry no vma annotation; disable the
+        # shard_map varying-axes check for this (correct) spec
+        return shard_map(fn_flash, mesh=mesh, in_specs=in_specs,
+                         out_specs=spec, check_vma=False)(*args)
     fn = functools.partial(_ring_attention_shard, axis_name=seq_axis, causal=causal)
     if kmask is None:
         def fn_nomask(q, k, v):

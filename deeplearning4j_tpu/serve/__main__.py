@@ -2,7 +2,7 @@
 
 Stand up the inference server: import each model (Keras ``.h5`` or DL4J
 ``.zip``, format auto-detected), run the AOT warm pipeline (restoring /
-writing ``<path>.aotbundle`` sidecars where persistence is validated), and
+writing ``<path>.aotbundle`` sidecars where ``DL4J_TPU_AOT_BUNDLE=1``), and
 serve them all from one port. The socket binds only after every model is
 warm — time-to-first-request never pays an XLA compile.
 
@@ -46,4 +46,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()   # process entry only: main() stays config-pure
     sys.exit(main())

@@ -912,7 +912,10 @@ class GenerateWorker:
         t0 = time.perf_counter()
         _, ids = self.program.dispatch(
             self._table_for([s], npb), [s.cached], tokens, [len(chunk)])
-        tok = int(ids[0])  # host sync: the emitted token IS the product
+        # host sync: the emitted token IS the product (fetch, THEN index —
+        # indexing the device array would compile two eager ops on the
+        # first request)
+        tok = int(np.asarray(ids)[0])
         dt = time.perf_counter() - t0
         self.latency.observe(f"{self.name}:prefill", tc, dt)
         s.fed += len(chunk)

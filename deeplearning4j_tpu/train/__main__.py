@@ -109,4 +109,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from deeplearning4j_tpu.utils.compile_cache import enable_compilation_cache
+
+    enable_compilation_cache()   # process entry only: main() stays config-pure
     sys.exit(main())

@@ -236,8 +236,8 @@ def save_checkpoint(model, path, normalizer: Optional[dict] = None) -> dict:
     obs.event("checkpoint_saved", path=str(path), crc=info["crc"],
               size=info["size"], duration_s=round(dur, 6))
     # executable bundle sidecar (nn/aot.py): resume restores params AND
-    # compiled executables. save_bundle gates itself (validation-proven
-    # backends only; default off on XLA:CPU) and never raises — the
+    # compiled executables. save_bundle gates itself (opt-in via
+    # DL4J_TPU_AOT_BUNDLE=1) and never raises — the
     # checkpoint above is durable regardless of what happens here.
     from deeplearning4j_tpu.nn import aot
 

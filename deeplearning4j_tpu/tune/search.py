@@ -174,10 +174,21 @@ def tune_model(model, features, labels,
     recorded DB entry (with the search history under ``"history"``, which
     is NOT persisted). Offline-only: call this from a tuning script or
     bench arm, never from inside fit()/serve."""
+    import jax
+
     from deeplearning4j_tpu.nn import aot
     from deeplearning4j_tpu.tune import db as _db
     from deeplearning4j_tpu.tune import trial as _trial
 
+    backend = jax.default_backend()   # the live model already initialised it
+    if runner is run_subprocess_trial and backend != "cpu":
+        # one process per chip: this process holds the device its trial
+        # children would need — every trial would fail or hang and the
+        # defaults would be recorded as the "winner"
+        raise RuntimeError(
+            f"tune_model runs each trial in a child process, but this "
+            f"process holds the {backend} device. On an accelerator pass a "
+            f"runner that trains in this process.")
     if knob_names is None:
         # the default online search is intentionally small: the two axes
         # that reshape the step itself (micro-batching, chained dispatch)

@@ -1,53 +1,41 @@
-"""Persistent XLA compilation cache — first-epoch compile amortization.
+"""Persistent XLA compilation cache — compile once per checkout, not once
+per process.
 
-Big unrolled programs (the Word2Vec epoch scan: 52.2s of compiles on the
-first epoch, ~5x a warm epoch — BENCH_r04 end_to_end_split_sec; the
-transformer/flash kernels: 20-40s each) recompile from scratch in every
-fresh process. JAX ships a persistent on-disk cache that keys compiled
-executables by HLO fingerprint; enabling it makes the SECOND process's
-first epoch warm.
+Big programs (the Word2Vec epoch scan, the transformer step with its flash
+kernels, the decode warm grid) recompile from scratch in every fresh
+process. JAX ships a persistent on-disk cache that keys compiled
+executables by HLO fingerprint; pointing every entry point at ONE fixed
+directory makes the second process's compiles cache reads.
 
-Opt-in (global config mutation should never happen on library import):
-
-    from deeplearning4j_tpu.utils.compile_cache import enable_compilation_cache
-    enable_compilation_cache()            # ~/.cache/deeplearning4j_tpu/xla
-
-or set ``DL4J_TPU_COMPILE_CACHE=/path`` (empty value = the default dir)
-and call ``enable_compilation_cache_from_env()`` — bench.py does this so
-driver re-runs skip the Word2Vec scan compile.
+Global config mutation never happens on library import: the entry points
+(``chip_smoke.py``, ``bench.py``, ``python -m deeplearning4j_tpu.train``,
+``python -m deeplearning4j_tpu.serve``) call :func:`enable_compilation_cache`
+first thing.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-_DEFAULT = os.path.join(os.path.expanduser("~"), ".cache",
-                        "deeplearning4j_tpu", "xla")
+# <checkout>/.jax_cache (git-ignored), derived from the package's own
+# location: the directory is part of the cache key, so it must not move
+# between processes — never ~, a temp name, a pid or a time
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None,
-                             min_compile_time_secs: float = 1.0) -> str:
-    """Point JAX's persistent compilation cache at ``cache_dir`` (created
-    if missing). Only compiles slower than ``min_compile_time_secs`` are
-    persisted — the long-pole scans/kernels, not trivial jits."""
+def enable_compilation_cache() -> str:
+    """Turn the persistent compilation cache on and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it: this
+    touches nothing and returns that directory. Otherwise the cache goes
+    to ``<checkout>/.jax_cache``."""
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
     import jax
 
-    path = cache_dir or _DEFAULT
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
-    except AttributeError:  # older jax: flag absent; cache still works
-        pass
-    return path
-
-
-def enable_compilation_cache_from_env() -> Optional[str]:
-    """Enable the cache iff DL4J_TPU_COMPILE_CACHE is set (empty value =
-    default location). Returns the directory or None."""
-    val = os.environ.get("DL4J_TPU_COMPILE_CACHE")
-    if val is None:
-        return None
-    return enable_compilation_cache(val or None)
+    os.makedirs(CHECKOUT_CACHE_DIR, exist_ok=True)
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR

@@ -14,11 +14,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-try:
-    _enable_x64 = jax.enable_x64  # jax >= 0.5
-except AttributeError:  # jax 0.4.x keeps it under experimental
-    from jax.experimental import enable_x64 as _enable_x64
-
 
 def check_gradients(
     model,
@@ -39,7 +34,7 @@ def check_gradients(
     ``subset``: check only N randomly chosen parameters per tensor (the
     reference checks all; sub-sampling keeps CI fast for big nets).
     """
-    with _enable_x64(True):
+    with jax.enable_x64(True):
         def to64(t):
             if t is None:
                 return None

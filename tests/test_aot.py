@@ -48,11 +48,7 @@ def _clean_env(monkeypatch):
     bucketing.telemetry().reset()
     retrace_guard.reset_aot_warmed()
     retrace_guard.reset_warnings()
-    saved = dict(aot._validated)
-    aot._validated.clear()
     yield
-    aot._validated.clear()
-    aot._validated.update(saved)
     retrace_guard.reset_aot_warmed()
     bucketing.telemetry().reset()
 
@@ -97,12 +93,9 @@ def _max_leaf_diff(a, b):
 
 
 def _allow_cpu_bundles(monkeypatch):
-    """Persistence gate for tests: mode=1 + validation marked passed, so
-    the zip/manifest machinery runs without a subprocess per test (the real
-    harness is exercised by test_validation_harness_subprocess and
-    tools/aot_smoke.sh)."""
+    """Persistence is opt-in on every backend (and the opt-in is trusted):
+    the zip/manifest machinery runs under DL4J_TPU_AOT_BUNDLE=1."""
     monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "1")
-    monkeypatch.setitem(aot._validated, jax.default_backend(), True)
 
 
 # ---------------------------------------------------------------------------
@@ -392,35 +385,30 @@ class TestBundles:
 
 
 class TestPersistenceGating:
-    def test_default_off_on_cpu(self, monkeypatch):
-        """auto mode never persists on XLA:CPU — no subprocess is even
-        spawned (validate_persistence would cache an entry)."""
+    def test_default_is_off(self, monkeypatch):
+        """auto (the default) never persists, on any backend."""
         monkeypatch.delenv("DL4J_TPU_AOT_BUNDLE", raising=False)
-        assert jax.default_backend() == "cpu"
         assert not aot.persistence_allowed()
-        assert aot._validated == {}
+        monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "auto")
+        assert not aot.persistence_allowed()
 
     def test_mode_zero_never_persists(self, monkeypatch):
         monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "0")
-        monkeypatch.setitem(aot._validated, "cpu", True)
         assert not aot.persistence_allowed()
 
-    def test_validation_failure_falls_back_to_recompile(
-            self, tmp_path, monkeypatch):
-        """Validation failing (the PR 4 scenario) -> save is a no-op,
-        restore rejects, training recompiles; nothing crashes."""
-        monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "1")
+    def test_gated_off_falls_back_to_recompile(self, tmp_path, monkeypatch):
+        """Without the opt-in: save is a no-op, restore rejects, training
+        recompiles; nothing crashes."""
         monkeypatch.setenv("DL4J_TPU_AOT", "1")
-        monkeypatch.setitem(aot._validated, jax.default_backend(), False)
         m = _mln()
         m.fit(_data(), epochs=1, batch_size=8)
         path = str(tmp_path / "gated.aotbundle")
         assert aot.save_bundle(m, path) is None
         assert not os.path.exists(path)
-        # a bundle produced elsewhere is likewise refused on this backend
-        monkeypatch.setitem(aot._validated, jax.default_backend(), True)
+        # a bundle produced under the opt-in is likewise refused without it
+        monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "1")
         assert aot.save_bundle(m, path) is not None
-        monkeypatch.setitem(aot._validated, jax.default_backend(), False)
+        monkeypatch.delenv("DL4J_TPU_AOT_BUNDLE")
         obs.reset()
         fresh = _mln()
         assert aot.restore_bundle(fresh, path) == 0
@@ -429,23 +417,32 @@ class TestPersistenceGating:
             "reason=persistence_disabled": 1}
         fresh.fit(_data(8), epochs=1)  # clean recompile, no crash
 
-    def test_harness_failure_detection(self, monkeypatch):
-        """A crashing/garbled validation subprocess reads as NOT validated."""
+    def test_opt_in_is_trusted_and_starts_no_process(self, monkeypatch):
+        """One process per chip: the gate validates nothing at run time and
+        spawns nothing; it says so once in an aot_validation event."""
         import subprocess as sp
 
-        def fake_run(*a, **kw):
-            raise sp.TimeoutExpired(cmd="x", timeout=1)
+        def no_children(*a, **kw):
+            raise AssertionError("persistence gate spawned a process")
 
-        monkeypatch.setattr(sp, "run", fake_run)
-        assert not aot.validate_persistence("fakebackend")
-        assert aot._validated["fakebackend"] is False
+        monkeypatch.setattr(sp, "run", no_children)
+        monkeypatch.setattr(sp, "Popen", no_children)
+        monkeypatch.setattr(aot, "_opt_in_announced", False)
+        monkeypatch.setenv("DL4J_TPU_AOT_BUNDLE", "1")
+        seen = []
+        monkeypatch.setattr(
+            obs, "event", lambda kind, **f: seen.append((kind, f)))
+        assert aot.persistence_allowed() and aot.persistence_allowed()
+        ev = [f for k, f in seen if k == "aot_validation"]
+        assert len(ev) == 1 and ev[0]["mode"] == "opt_in_trusted"
 
-    @pytest.mark.slow
-    def test_validation_harness_subprocess(self):
-        """The real thing once: serialize->deserialize->execute bitwise
-        parity proven in a subprocess on this backend."""
-        assert aot.validate_persistence(jax.default_backend(),
-                                        timeout_s=300)
+    def test_parity_harness(self):
+        """The standalone harness body (python -m deeplearning4j_tpu.nn.aot):
+        serialize->deserialize->execute bitwise parity on this backend —
+        with 8 local devices, so a one-device executable must load onto
+        its own device, not all of them."""
+        result = aot._selftest()
+        assert result["ok"] and all(c["parity"] for c in result["cases"])
 
 
 # ---------------------------------------------------------------------------

@@ -492,3 +492,44 @@ class TestKmask:
         # fully-masked query rows (0..4): dq exactly zero in both
         np.testing.assert_allclose(np.asarray(gp[0])[:, :5], 0.0, atol=0)
         np.testing.assert_allclose(np.asarray(gx[0])[:, :5], 0.0, atol=0)
+
+
+class TestUnderAMesh:
+    """GSPMD cannot partition a Mosaic kernel, so under an active
+    multi-device mesh the attention layer runs the kernel inside a
+    shard_map over (data, model) — nn/layers/attention.py _sharded_flash.
+    Values and gradients must not depend on the mesh shape."""
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kmask"])
+    def test_sharded_kernel_matches_unsharded(self, masked):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from deeplearning4j_tpu.nn.input_type import InputType
+        from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
+        from deeplearning4j_tpu.parallel import MeshSpec, make_mesh, use_mesh
+
+        B, T, C = 4, 32, 64
+        layer = MultiHeadAttention(n_heads=4, causal=True, use_flash=True)
+        params = layer.init(jax.random.PRNGKey(0),
+                            InputType.recurrent(C, T), jnp.float32)
+        x = jax.random.normal(jax.random.PRNGKey(1), (B, T, C))
+        lens = jnp.array([32, 20, 9, 32])
+        m = (jnp.arange(T)[None] < lens[:, None]).astype(jnp.float32) \
+            if masked else None
+
+        def loss(p, x, m):
+            y, _ = layer.apply(p, {}, x, mask=m)
+            return jnp.sum(y ** 2)
+
+        ref = jax.jit(jax.value_and_grad(loss))(params, x, m)
+        for spec in (MeshSpec(data=4), MeshSpec(data=2, model=2)):
+            mesh = make_mesh(spec, jax.devices()[:4])
+            rows = NamedSharding(mesh, P("data"))
+            with use_mesh(mesh):
+                got = jax.jit(jax.value_and_grad(loss))(
+                    params, jax.device_put(x, rows),
+                    None if m is None else jax.device_put(m, rows))
+            for a, b in zip(jax.tree_util.tree_leaves(got),
+                            jax.tree_util.tree_leaves(ref)):
+                np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                           rtol=2e-4, atol=2e-4)

@@ -315,20 +315,35 @@ class TestMemoryReportCG:
 
 
 class TestCompileCache:
-    def test_env_gating(self, monkeypatch, tmp_path):
-        from deeplearning4j_tpu.utils import compile_cache as cc
-
-        monkeypatch.delenv("DL4J_TPU_COMPILE_CACHE", raising=False)
-        assert cc.enable_compilation_cache_from_env() is None
-        monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE", str(tmp_path / "xc"))
-        d = cc.enable_compilation_cache_from_env()
-        assert d == str(tmp_path / "xc") and os.path.isdir(d)
+    def test_env_set_is_left_untouched(self, monkeypatch, tmp_path):
+        """JAX_COMPILATION_CACHE_DIR set -> JAX reads it; the function
+        touches no config and creates nothing."""
         import jax
-        assert jax.config.jax_compilation_cache_dir == d
 
-    def test_empty_value_means_default_dir(self, monkeypatch):
         from deeplearning4j_tpu.utils import compile_cache as cc
 
-        monkeypatch.setenv("DL4J_TPU_COMPILE_CACHE", "")
-        d = cc.enable_compilation_cache_from_env()
-        assert d == cc._DEFAULT and os.path.isdir(d)
+        def no_update(*a, **kw):
+            raise AssertionError("jax.config.update called with the env set")
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xc"))
+        monkeypatch.setattr(jax.config, "update", no_update)
+        assert cc.enable_compilation_cache() == str(tmp_path / "xc")
+        assert not (tmp_path / "xc").exists()
+
+    def test_unset_means_checkout_cache(self, monkeypatch):
+        """Unset -> <checkout>/.jax_cache, derived from the package's own
+        location (the directory is part of the cache key: never ~, a temp
+        name, a pid or a time)."""
+        import jax
+
+        from deeplearning4j_tpu.utils import compile_cache as cc
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert cc.CHECKOUT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+        seen = {}
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: seen.__setitem__(k, v))
+        assert cc.enable_compilation_cache() == cc.CHECKOUT_CACHE_DIR
+        assert seen == {"jax_compilation_cache_dir": cc.CHECKOUT_CACHE_DIR}
+        assert os.path.isdir(cc.CHECKOUT_CACHE_DIR)

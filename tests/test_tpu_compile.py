@@ -1,0 +1,226 @@
+"""Every Pallas entry point compiles for the TPU — no chip needed.
+
+libtpu ships a compile-only client: ``get_topology_desc("v5e:2x2")`` hands
+back four ``TPU v5 lite`` devices that can be compiled FOR (Mosaic included)
+but not run on, even under ``JAX_PLATFORMS=cpu``. The interpret-mode tests
+(test_flash_attention.py, test_fused_lstm.py) prove the kernels' math; this
+file proves the TPU lowering accepts their blocks, layouts and VMEM budgets
+— the class of failure that interpret mode cannot see (a (128, 2, 1024)
+block of a (128, 50, 1024) array; a sublane broadcast Mosaic rejects).
+Shapes are the ones ``chip_smoke.py`` runs on the chip, cut to what
+compiles in seconds. Whether they RUN right is chip_smoke.py's business.
+"""
+
+import functools
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+pytest.importorskip("libtpu")
+
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import SingleDeviceSharding  # noqa: E402
+
+from deeplearning4j_tpu.ops.flash_attention import (  # noqa: E402
+    flash_attention, flash_attention_block_grad, merge_attention_blocks)
+from deeplearning4j_tpu.ops.fused_lstm import fits_vmem, fused_lstm  # noqa: E402
+
+
+@functools.lru_cache(maxsize=None)
+def _topology():
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2",
+                                        platform="tpu")
+    assert "v5" in topo.devices[0].device_kind.lower()
+    return topo
+
+
+def _sharding():
+    return SingleDeviceSharding(_topology().devices[0])
+
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=_sharding())
+
+
+def _compile(fn, *avals):
+    """Lower + compile for the v5e topology; returns the HLO text."""
+    return jax.jit(fn).lower(*avals).compile().as_text()
+
+
+def _n_mosaic(hlo: str) -> int:
+    return hlo.count('custom_call_target="tpu_custom_call"')
+
+
+def _f32sum(*xs):
+    return sum(jnp.sum(x.astype(jnp.float32)) for x in xs)
+
+
+# ---------------------------------------------------------------------------
+# flash attention (ops/flash_attention.py)
+# ---------------------------------------------------------------------------
+
+FLASH_SHAPES = [
+    pytest.param((16, 2048, 16, 128), jnp.bfloat16, id="flagship-bf16"),
+    pytest.param((2, 1000, 4, 64), jnp.float32, id="unaligned-f32"),
+]
+
+
+@pytest.mark.parametrize("shape,dtype", FLASH_SHAPES)
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kmask"])
+def test_flash_fwd_bwd_compiles(shape, dtype, masked):
+    B, T = shape[:2]
+
+    def loss(q, k, v, km):
+        return _f32sum(flash_attention(
+            q, k, v, kmask=km if masked else None, causal=True))
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                   *[_sds(shape, dtype)] * 3, _sds((B, T), jnp.float32))
+    assert _n_mosaic(hlo) == 3      # forward + dq + dk/dv kernels
+
+
+def test_flash_ring_blocks_compile():
+    """The ring path's building blocks: two differentiable key chunks
+    merged by their logsumexp (parallel/ring.py does exactly this per
+    ring step)."""
+    B, T, H, D = 4, 1024, 8, 128
+
+    def loss(q, k, v):
+        half = T // 2
+        parts = [
+            flash_attention_block_grad(
+                q, k[:, s:s + half], v[:, s:s + half], q_offset=0,
+                k_offset=s, causal=True)
+            for s in (0, half)]
+        return _f32sum(merge_attention_blocks(parts))
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                   *[_sds((B, T, H, D), jnp.bfloat16)] * 3)
+    assert _n_mosaic(hlo) == 6      # (fwd + dq + dk/dv) x 2 chunks
+
+
+# ---------------------------------------------------------------------------
+# fused LSTM (ops/fused_lstm.py)
+# ---------------------------------------------------------------------------
+
+LSTM_SHAPES = [
+    pytest.param(128, 50, 256, jnp.float32, id="B128-T50-H256-f32"),
+    pytest.param(512, 50, 1024, jnp.bfloat16, id="B512-T50-H1024-bf16"),
+    pytest.param(16, 53, 128, jnp.float32, id="prime-T-padded"),
+]
+
+
+@pytest.mark.parametrize("B,T,H,dtype", LSTM_SHAPES)
+@pytest.mark.parametrize("peephole", [False, True], ids=["lstm", "graves"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "mask"])
+def test_fused_lstm_fwd_bwd_compiles(B, T, H, dtype, peephole, masked):
+    assert fits_vmem(B, H, jnp.dtype(dtype).itemsize)
+
+    def loss(zx, wh, h0, c0, m, p):
+        out, (hT, cT) = fused_lstm(zx, wh, h0, c0, m if masked else None,
+                                   p if peephole else None)
+        return _f32sum(out, hT, cT)
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 5)),
+                   _sds((B, T, 4 * H), dtype), _sds((H, 4 * H), dtype),
+                   _sds((B, H), dtype), _sds((B, H), dtype),
+                   _sds((B, T), jnp.float32), _sds((3 * H,), dtype))
+    assert _n_mosaic(hlo) == 2      # forward + backward kernels
+
+
+@pytest.mark.parametrize("d,m", [(4, 1), (2, 2)], ids=["data4", "data2xmodel2"])
+def test_flash_under_a_mesh_compiles(monkeypatch, d, m):
+    """GSPMD cannot partition a Mosaic kernel ("Please wrap the call in a
+    shard_map" — what MeshTrainer hit on the four-chip host): under an
+    active multi-device mesh the attention layer runs the kernel inside a
+    shard_map over (data, model). Compiled here for all four chips of the
+    v5e:2x2 topology, at the flagship's attention shape."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from deeplearning4j_tpu.nn.input_type import InputType
+    from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
+    from deeplearning4j_tpu.parallel.context import use_mesh
+
+    B, T, C = 16, 2048, 2048
+    layer = MultiHeadAttention(n_heads=16, causal=True)
+    params = layer.init(jax.random.PRNGKey(0), InputType.recurrent(C, T),
+                        jnp.bfloat16)
+    devices = _topology().devices
+    mesh = Mesh(np.array(devices).reshape(d, m, 1, 1),
+                ("data", "model", "seq", "pipe"))
+    repl = NamedSharding(mesh, P())
+    p_sds = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=repl),
+        params)
+    x_sds = jax.ShapeDtypeStruct((B, T, C), jnp.bfloat16,
+                                 sharding=NamedSharding(mesh, P("data")))
+
+    def loss(p, x):
+        y, _ = layer.apply(p, {}, x)
+        return jnp.sum(y.astype(jnp.float32))
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with use_mesh(mesh):
+        hlo = jax.jit(jax.grad(loss)).lower(p_sds, x_sds).compile().as_text()
+    assert _n_mosaic(hlo) == 3
+
+
+# ---------------------------------------------------------------------------
+# whole train steps: the layer gates take the kernels at default settings
+# ---------------------------------------------------------------------------
+
+
+def _step_hlo(model, x, y, with_carries):
+    """The model's own train-step body, compiled for v5e. The layer gates
+    (nn/layers/recurrent.py, nn/layers/attention.py) key on
+    ``jax.default_backend()`` — the CPU in this sandbox — so callers patch
+    it to answer "tpu": the trace then makes the choices it makes on the
+    chip (kernel on, ``interpret=False``)."""
+    carries = tuple(
+        l.initial_carry(x.shape[0], model.dtype) if f else ()
+        for l, f in zip(model.layers, model._carry_flags)
+    ) if with_carries else ()
+    to_sds = lambda t: jax.tree_util.tree_map(   # noqa: E731
+        lambda a: _sds(a.shape, a.dtype), t)
+    body = model._step_body(with_carries)
+    return _compile(
+        lambda p, o, s, it, rng, x, y, c: body(p, o, s, it, rng, x, y,
+                                               None, None, c),
+        to_sds(model.params), to_sds(model.opt_state), to_sds(model.state),
+        _sds((), jnp.int32), to_sds(jax.random.PRNGKey(0)), x, y,
+        to_sds(carries))
+
+
+def test_text_generation_lstm_step_takes_the_kernel(monkeypatch):
+    """BASELINE #3 at its bench shape and DEFAULT settings: the tBPTT train
+    step compiles for v5e with the fused kernel in it, forward + backward
+    per GravesLSTM layer."""
+    from deeplearning4j_tpu.models import TextGenerationLSTM
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+
+    monkeypatch.delenv("DL4J_TPU_FUSED_LSTM", raising=False)
+    model = MultiLayerNetwork(TextGenerationLSTM()).init()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T, V = 128, 50, 77
+    hlo = _step_hlo(model, _sds((B, T, V), jnp.float32),
+                    _sds((B, T, V), jnp.float32), with_carries=True)
+    assert _n_mosaic(hlo) == 4      # (fwd + bwd) x 2 layers
+
+
+def test_transformer_lm_step_takes_the_kernel(monkeypatch):
+    """The flagship at full width (depth cut to one block): the flash gate
+    takes the Pallas forward and both Pallas backward kernels."""
+    from deeplearning4j_tpu.models import TransformerLM
+    from deeplearning4j_tpu.nn.model import MultiLayerNetwork
+
+    model = MultiLayerNetwork(TransformerLM(
+        vocab_size=2048, max_len=2048, d_model=2048, n_heads=16,
+        n_blocks=1)).init()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    B, T = 16, 2048
+    hlo = _step_hlo(model, _sds((B, T), jnp.int32), _sds((B, T), jnp.int32),
+                    with_carries=False)
+    assert _n_mosaic(hlo) == 3      # 3 per block
