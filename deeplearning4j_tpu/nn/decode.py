@@ -53,6 +53,7 @@ import numpy as np
 
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn import aot
+from deeplearning4j_tpu.nn.step_program import layer_scope
 from deeplearning4j_tpu.utils import bucketing
 
 __all__ = ["DecodeProgram"]
@@ -262,26 +263,28 @@ class DecodeProgram:
         logits = None
         for li, (kind, layer) in enumerate(self._plan):
             p = params[li]
-            if kind == "block":
-                if self.paged:
-                    view = _PagedView(new_pools[bi], table, positions, valid,
-                                      self.page_tokens)
-                else:
-                    view = _ContiguousView(new_pools[bi], table, positions,
-                                           valid)
-                a = layer.decode_apply(p, a, cache=view, positions=positions)
-                new_pools[bi] = view.pool
-                bi += 1
-            elif kind == "pos":
-                a = layer.decode_apply(p, a, positions)
-            elif kind == "out":
-                last = jnp.clip(n_new - 1, 0, Tc - 1).astype(jnp.int32)
-                a_last = jnp.take_along_axis(a, last[:, None, None],
-                                             axis=1)[:, 0]        # [B, C]
-                logits = layer.preactivation(p, a_last).astype(jnp.float32)
-            else:  # positionwise passthrough, eval mode
-                a, _ = layer.apply(p, state[li], a, train=False, rng=None,
-                                   mask=None)
+            with layer_scope(layer, li):
+                if kind == "block":
+                    if self.paged:
+                        view = _PagedView(new_pools[bi], table, positions,
+                                          valid, self.page_tokens)
+                    else:
+                        view = _ContiguousView(new_pools[bi], table, positions,
+                                               valid)
+                    a = layer.decode_apply(p, a, cache=view,
+                                           positions=positions)
+                    new_pools[bi] = view.pool
+                    bi += 1
+                elif kind == "pos":
+                    a = layer.decode_apply(p, a, positions)
+                elif kind == "out":
+                    last = jnp.clip(n_new - 1, 0, Tc - 1).astype(jnp.int32)
+                    a_last = jnp.take_along_axis(a, last[:, None, None],
+                                                 axis=1)[:, 0]        # [B, C]
+                    logits = layer.preactivation(p, a_last).astype(jnp.float32)
+                else:  # positionwise passthrough, eval mode
+                    a, _ = layer.apply(p, state[li], a, train=False, rng=None,
+                                       mask=None)
         ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return tuple(new_pools), logits, ids
 

@@ -39,6 +39,7 @@ from deeplearning4j_tpu.nn.config import LayerConfig, layer_from_dict, _encode_v
 from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.model import _cast_input, _cast_labels, _sig_dtype
+from deeplearning4j_tpu.nn.step_program import layer_scope
 from deeplearning4j_tpu.nn.preprocessors import infer_preprocessor
 from deeplearning4j_tpu.utils import bucketing
 from deeplearning4j_tpu.train.updaters import (
@@ -820,40 +821,41 @@ class ComputationGraph:
                 continue
             vtrain = train and not (
                 deterministic and getattr(v.config, "uses_rng", lambda: False)())
-            if v.spec.is_layer():
-                x, m = xs[0], in_masks[0]
-                it = self.vertex_types[v.inputs[0]] if v.inputs[0] in self.vertex_types \
-                    else self.conf.input_types[v.inputs[0]]
-                if v.pre is not None:
-                    x, _ = v.pre.apply({}, {}, x, train=vtrain, rng=None, mask=m)
-                    m = v.pre.propagate_mask(m, it)
-                    it = v.input_types[0]
-                p_v = params[name]
-                if vtrain and v.config.weight_noise and rng is not None:
-                    p_v = v.config.maybe_weight_noise(
-                        p_v, vtrain, jax.random.fold_in(rng, 0x5EED)
-                    )
-                if new_carries is not None and name in new_carries:
-                    x2 = v.config.maybe_dropout_input(x, vtrain, rng)
-                    y, c = v.config.apply_seq(p_v, x2, new_carries[name], m)
-                    new_carries[name] = c
-                    ns = state[name]
-                elif ex_weight is not None and getattr(v.config, "CONSUMES_EXAMPLE_WEIGHT", False):
-                    y, ns = v.config.apply(p_v, state[name], x, train=vtrain,
-                                           rng=rng, mask=m, ex_weight=ex_weight)
+            with layer_scope(v.config, name):
+                if v.spec.is_layer():
+                    x, m = xs[0], in_masks[0]
+                    it = self.vertex_types[v.inputs[0]] if v.inputs[0] in self.vertex_types \
+                        else self.conf.input_types[v.inputs[0]]
+                    if v.pre is not None:
+                        x, _ = v.pre.apply({}, {}, x, train=vtrain, rng=None, mask=m)
+                        m = v.pre.propagate_mask(m, it)
+                        it = v.input_types[0]
+                    p_v = params[name]
+                    if vtrain and v.config.weight_noise and rng is not None:
+                        p_v = v.config.maybe_weight_noise(
+                            p_v, vtrain, jax.random.fold_in(rng, 0x5EED)
+                        )
+                    if new_carries is not None and name in new_carries:
+                        x2 = v.config.maybe_dropout_input(x, vtrain, rng)
+                        y, c = v.config.apply_seq(p_v, x2, new_carries[name], m)
+                        new_carries[name] = c
+                        ns = state[name]
+                    elif ex_weight is not None and getattr(v.config, "CONSUMES_EXAMPLE_WEIGHT", False):
+                        y, ns = v.config.apply(p_v, state[name], x, train=vtrain,
+                                               rng=rng, mask=m, ex_weight=ex_weight)
+                    else:
+                        y, ns = v.config.apply(p_v, state[name], x,
+                                               train=vtrain, rng=rng, mask=m)
+                    mask_acts[name] = v.config.propagate_mask(m, it)
                 else:
-                    y, ns = v.config.apply(p_v, state[name], x,
-                                           train=vtrain, rng=rng, mask=m)
-                mask_acts[name] = v.config.propagate_mask(m, it)
-            else:
-                # mask_input: vertex reads the mask of a NAMED input instead
-                # of its propagated one (rnn/LastTimeStepVertex.java semantics)
-                ms = getattr(v.config, "mask_input", None)
-                if ms is not None:
-                    in_masks = [mask_acts.get(ms)] + in_masks[1:]
-                y, ns = v.config.apply(params[name], state[name], xs,
-                                       train=vtrain, rng=rng, masks=in_masks)
-                mask_acts[name] = v.config.propagate_mask(in_masks, v.input_types)
+                    # mask_input: vertex reads the mask of a NAMED input instead
+                    # of its propagated one (rnn/LastTimeStepVertex.java semantics)
+                    ms = getattr(v.config, "mask_input", None)
+                    if ms is not None:
+                        in_masks = [mask_acts.get(ms)] + in_masks[1:]
+                    y, ns = v.config.apply(params[name], state[name], xs,
+                                           train=vtrain, rng=rng, masks=in_masks)
+                    mask_acts[name] = v.config.propagate_mask(in_masks, v.input_types)
             acts[name] = y
             new_state[name] = ns
         return acts, new_state, mask_acts, new_carries
@@ -867,20 +869,23 @@ class ComputationGraph:
             ex_weight=ex_weight, carries=carries, deterministic=deterministic,
         )
         total = jnp.asarray(0.0, jnp.float32)
-        for i, oname in enumerate(self.conf.outputs):
-            if oname not in stop:
-                continue
-            v = self.rt[oname]
-            y = labels[i] if isinstance(labels, (tuple, list)) else labels
-            lm = None
-            if lmasks is not None:
-                lm = lmasks[i] if isinstance(lmasks, (tuple, list)) else lmasks
-            if lm is None:
-                lm = mask_acts.get(oname)
-            total = total + v.config.score(params[oname], acts[oname], y, mask=lm, average=True)
-        for name in self.topo_order:
-            v = self.rt[name]
-            total = total + v.config.regularization_penalty(params[name])
+        with jax.named_scope("loss"):
+            for i, oname in enumerate(self.conf.outputs):
+                if oname not in stop:
+                    continue
+                v = self.rt[oname]
+                y = labels[i] if isinstance(labels, (tuple, list)) else labels
+                lm = None
+                if lmasks is not None:
+                    lm = lmasks[i] if isinstance(lmasks, (tuple, list)) else lmasks
+                if lm is None:
+                    lm = mask_acts.get(oname)
+                with layer_scope(v.config, oname):
+                    total = total + v.config.score(
+                        params[oname], acts[oname], y, mask=lm, average=True)
+            for name in self.topo_order:
+                v = self.rt[name]
+                total = total + v.config.regularization_penalty(params[name])
         return total, (new_state, new_carries)
 
     # -- jitted step -------------------------------------------------------
@@ -921,8 +926,6 @@ class ComputationGraph:
             if grad_exchange is not None:
                 opt_state, residuals = opt_state
             batch = (inputs, labels, fmasks, lmasks, ex_weight)
-            # trace-time phase spans: fire once per compile, attributing
-            # trace cost per phase (runtime attribution: DL4J_TPU_PHASE_SPANS)
             if not with_carries and _accum_applicable(accum, batch):
                 # DL4J_TPU_GRAD_ACCUM: scan over micro-batches, average the
                 # grads, run the (single) update/exchange below on the mean —
@@ -937,9 +940,8 @@ class ComputationGraph:
 
                     return loss_fn
 
-                with obs.span("phase.bwd", mode="trace"):
-                    loss, new_state, grads = _accum_value_and_grad(
-                        accum, params, state, batch, rng, make_loss_fn)
+                loss, new_state, grads = _accum_value_and_grad(
+                    accum, params, state, batch, rng, make_loss_fn)
                 new_carries = None
             else:
                 rngs = list(jax.random.split(rng, len(order)))
@@ -949,9 +951,8 @@ class ComputationGraph:
                                       rngs, ex_weight=ex_weight,
                                       carries=carries if with_carries else None)
 
-                with obs.span("phase.bwd", mode="trace"):
-                    ((loss, (new_state, new_carries)), grads) = jax.value_and_grad(
-                        loss_fn, has_aux=True)(params)
+                ((loss, (new_state, new_carries)), grads) = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             if grad_exchange is not None:
                 loss = grad_exchange.mean_loss(loss)
                 new_state = grad_exchange.mean_state(new_state)
@@ -966,9 +967,8 @@ class ComputationGraph:
                     new_state = resilience.guard_select(ok, new_state, state)
                 return (new_params, (new_opt, new_res), new_state,
                         new_carries, loss)
-            with obs.span("phase.update", mode="trace"):
-                new_params, new_opt = self._update_params(
-                    params, opt_state, grads, it)
+            new_params, new_opt = self._update_params(
+                params, opt_state, grads, it)
             if g_skip:
                 ok = resilience.guard_ok(loss, g_limit)
                 new_params = resilience.guard_select(ok, new_params, params)
@@ -980,8 +980,7 @@ class ComputationGraph:
 
     def _update_params(self, params, opt_state, grads, it):
         """Per-vertex optimizer update (normalization → updater →
-        constraints), shared by the fused step body and the split-dispatch
-        phase mode so both paths run identical math."""
+        constraints) of the fused step body, under the scope ``update``."""
         order = self.topo_order
         updaters = self._updaters
         new_params, new_opt = {}, {}
@@ -992,19 +991,20 @@ class ComputationGraph:
                 new_opt[name] = opt_state[name]
                 continue
             cfg = self.rt[name].config
-            gn = getattr(cfg, "gradient_normalization", None)
-            if gn:
-                g = apply_gradient_normalization(
-                    gn, getattr(cfg, "gradient_normalization_threshold", 1.0), g
+            with jax.named_scope("update"), layer_scope(cfg, name):
+                gn = getattr(cfg, "gradient_normalization", None)
+                if gn:
+                    g = apply_gradient_normalization(
+                        gn, getattr(cfg, "gradient_normalization_threshold", 1.0), g
+                    )
+                upd, ns = updaters[name].update(g, opt_state[name], params[name], it)
+                p_new = jax.tree_util.tree_map(
+                    lambda p, d: p - d, params[name], upd
                 )
-            upd, ns = updaters[name].update(g, opt_state[name], params[name], it)
-            p_new = jax.tree_util.tree_map(
-                lambda p, d: p - d, params[name], upd
-            )
-            if getattr(cfg, "constraints", None):
-                from deeplearning4j_tpu.nn.constraints import apply_constraints
+                if getattr(cfg, "constraints", None):
+                    from deeplearning4j_tpu.nn.constraints import apply_constraints
 
-                p_new = apply_constraints(cfg, p_new)
+                    p_new = apply_constraints(cfg, p_new)
             new_params[name] = p_new
             new_opt[name] = ns
         return new_params, new_opt
@@ -1225,36 +1225,47 @@ class ComputationGraph:
                     from deeplearning4j_tpu.datasets.iterator import prefetch_to_device
 
                     stream = prefetch_to_device(stream)
-                for f, l, fm, lm, ew, n_real in stream:
-                    batch = (f, l, fm, lm)
-                    chainable = (
-                        chain_k > 1 and fm is None and lm is None
-                        and l is not None and all(y is not None for y in l)
-                        and (not buf or _batch_sig(f + l)
-                             == _batch_sig(buf[0][0] + buf[0][1]))
-                    )
-                    if chainable:
-                        buf.append((f, l))
+                stream = iter(stream)
+                while True:
+                    # one loop turn = one cg.iter span (mirrors
+                    # MultiLayerNetwork.fit)
+                    with obs.span("cg.iter", step=self.iteration):
+                        with obs.span("cg.feed"):
+                            item = next(stream, None)
+                        if item is None:
+                            break
+                        f, l, fm, lm, ew, n_real = item
+                        batch = (f, l, fm, lm)
+                        chainable = (
+                            chain_k > 1 and fm is None and lm is None
+                            and l is not None and all(y is not None for y in l)
+                            and (not buf or _batch_sig(f + l)
+                                 == _batch_sig(buf[0][0] + buf[0][1]))
+                        )
+                        if chainable:
+                            buf.append((f, l))
+                            self.batch_in_epoch += 1
+                            if len(buf) == chain_k:
+                                flush(True)
+                            continue
+                        flush(False)
+                        with obs.span("cg.fit_batch"):
+                            if tbptt:
+                                score = self._fit_tbptt(*batch)
+                            else:
+                                score = self.fit_batch(batch, ew=ew)
                         self.batch_in_epoch += 1
-                        if len(buf) == chain_k:
-                            flush(True)
-                        continue
-                    flush(False)
-                    with obs.span("cg.fit_batch"):
-                        if tbptt:
-                            score = self._fit_tbptt(*batch)
-                        else:
-                            score = self.fit_batch(batch, ew=ew)
-                    self.batch_in_epoch += 1
-                    if guard is not None:
-                        guard.observe(self, score)
-                    if self.listeners:
-                        # n_real came from the pre-padding host side of the
-                        # stream
-                        score = float(score)  # graftlint: disable=host-sync
-                        resilience.note_score(score)
-                        for l in self.listeners:
-                            l.iteration_done(self, self.iteration, score, n_real)
+                        if guard is not None:
+                            guard.observe(self, score)
+                        if self.listeners:
+                            # n_real came from the pre-padding host side of the
+                            # stream
+                            with obs.span("cg.loss_fetch"):
+                                score = float(score)  # graftlint: disable=host-sync
+                            resilience.note_score(score)
+                            with obs.span("cg.listeners"):
+                                for lst in self.listeners:
+                                    lst.iteration_done(self, self.iteration, score, n_real)
                 flush(False)
                 if guard is not None:
                     guard.flush(self)
@@ -1456,24 +1467,24 @@ class ComputationGraph:
         fm = self._norm_multi(fmasks, len(self.conf.inputs)) if fmasks is not None else None
         self._get_output_fn()
         n = feats[0].shape[0] if feats else 0
-        with obs.span("cg.output"):
-            if (bucketing.bucketing_enabled() and n > 0
-                    and not self._has_batch_vertices):
-                target = bucketing.bucket_size(n)
-                bucketing.telemetry().record_hit("cg.output", n, target)
-                if target > n:
-                    feats = tuple(bucketing.pad_rows_zero(x, target) for x in feats)
-                    if fm is not None:
-                        fm = tuple(bucketing.pad_rows_zero(m, target)
-                                   if m is not None else None for m in fm)
-                    outs = self._output_fn.dispatch(
-                        self.params, self.state, self._input_dict(feats),
-                        self._mask_dict(fm))
-                    outs = tuple(bucketing.unpad(o, n) for o in outs)
-                    return outs[0] if len(outs) == 1 else outs
-            outs = self._output_fn.dispatch(
-                self.params, self.state, self._input_dict(feats),
-                self._mask_dict(fm))
+        # dispatch() opens the cg.output span
+        if (bucketing.bucketing_enabled() and n > 0
+                and not self._has_batch_vertices):
+            target = bucketing.bucket_size(n)
+            bucketing.telemetry().record_hit("cg.output", n, target)
+            if target > n:
+                feats = tuple(bucketing.pad_rows_zero(x, target) for x in feats)
+                if fm is not None:
+                    fm = tuple(bucketing.pad_rows_zero(m, target)
+                               if m is not None else None for m in fm)
+                outs = self._output_fn.dispatch(
+                    self.params, self.state, self._input_dict(feats),
+                    self._mask_dict(fm))
+                outs = tuple(bucketing.unpad(o, n) for o in outs)
+                return outs[0] if len(outs) == 1 else outs
+        outs = self._output_fn.dispatch(
+            self.params, self.state, self._input_dict(feats),
+            self._mask_dict(fm))
         return outs[0] if len(outs) == 1 else outs
 
     # -- streaming RNN inference (ComputationGraph.rnnTimeStep:2718) -------

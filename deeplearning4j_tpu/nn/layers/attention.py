@@ -321,22 +321,26 @@ class TransformerBlock(LayerConfig):
     def _apply_inner(self, params, x, train, rng, mask):
         rng_in, rng_attn = (jax.random.split(rng) if rng is not None else (None, None))
         x = self.maybe_dropout_input(x, train, rng_in)
-        h = self._ln(params["ln1"], x)
-        a, _ = self._mha().apply(params["attn"], {}, h, train=train, rng=rng_attn, mask=mask)
-        x = x + a
-        h = self._ln(params["ln2"], x)
-        h = self.activation_fn()(h @ params["Wi"] + params["bi"])
-        return x + (h @ params["Wo"] + params["bo"])
+        with jax.named_scope("attn"):
+            h = self._ln(params["ln1"], x)
+            a, _ = self._mha().apply(params["attn"], {}, h, train=train, rng=rng_attn, mask=mask)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = self._ln(params["ln2"], x)
+            h = self.activation_fn()(h @ params["Wi"] + params["bi"])
+            return x + (h @ params["Wo"] + params["bo"])
 
     def decode_apply(self, params, x, *, cache, positions):
         """The block's eval-mode forward for a new-token chunk against a KV
         cache: identical composition to :meth:`_apply_inner` with the MHA
         swapped for its cache-backed decode path (see
         MultiHeadAttention.decode_apply)."""
-        h = self._ln(params["ln1"], x)
-        a = self._mha().decode_apply(params["attn"], h, cache=cache,
-                                     positions=positions)
-        x = x + a
-        h = self._ln(params["ln2"], x)
-        h = self.activation_fn()(h @ params["Wi"] + params["bi"])
-        return x + (h @ params["Wo"] + params["bo"])
+        with jax.named_scope("attn"):
+            h = self._ln(params["ln1"], x)
+            a = self._mha().decode_apply(params["attn"], h, cache=cache,
+                                         positions=positions)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = self._ln(params["ln2"], x)
+            h = self.activation_fn()(h @ params["Wi"] + params["bi"])
+            return x + (h @ params["Wo"] + params["bo"])

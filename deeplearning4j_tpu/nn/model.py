@@ -176,6 +176,7 @@ from deeplearning4j_tpu.nn.step_program import (  # noqa: F401,E402
     accum_value_and_grad as _accum_value_and_grad,
     chain_k_from_env as _chain_k_from_env,
     grad_accum_from_env as _grad_accum_from_env,
+    layer_scope,
 )
 
 
@@ -396,16 +397,17 @@ class MultiLayerNetwork:
             if ltrain and layer.weight_noise and lrng is not None:
                 # separate stream from input dropout on the same layer
                 p_i = layer.maybe_weight_noise(p_i, ltrain, jax.random.fold_in(lrng, 0x5EED))
-            if new_carries is not None and self._carry_flags[i]:
-                a2 = layer.maybe_dropout_input(a, ltrain, lrng)
-                a, c = layer.apply_seq(p_i, a2, new_carries[i], mask)
-                new_carries[i] = c
-                ns = state[i]
-            elif ex_weight is not None and getattr(layer, "CONSUMES_EXAMPLE_WEIGHT", False):
-                a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng,
-                                    mask=mask, ex_weight=ex_weight)
-            else:
-                a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng, mask=mask)
+            with layer_scope(layer, i):
+                if new_carries is not None and self._carry_flags[i]:
+                    a2 = layer.maybe_dropout_input(a, ltrain, lrng)
+                    a, c = layer.apply_seq(p_i, a2, new_carries[i], mask)
+                    new_carries[i] = c
+                    ns = state[i]
+                elif ex_weight is not None and getattr(layer, "CONSUMES_EXAMPLE_WEIGHT", False):
+                    a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng,
+                                        mask=mask, ex_weight=ex_weight)
+                else:
+                    a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng, mask=mask)
             new_state[i] = ns
             mask = layer.propagate_mask(mask, self.layer_input_types[i])
             if collect:
@@ -439,11 +441,13 @@ class MultiLayerNetwork:
         )
         out_layer = self.layers[-1]
         out_mask = lmask if lmask is not None else prop_mask
-        loss = out_layer.score(params[-1], a, y, mask=out_mask, average=True)
-        # Unconditional: wrapper layers (Bidirectional etc.) delegate to their
-        # inner layer's l1/l2 even when the wrapper's own are zero.
-        reg = sum(l.regularization_penalty(p) for l, p in zip(self.layers, params))
-        return loss + reg, (new_state, new_carries)
+        with jax.named_scope("loss"):
+            with layer_scope(out_layer, len(self.layers) - 1):
+                loss = out_layer.score(params[-1], a, y, mask=out_mask, average=True)
+            # Unconditional: wrapper layers (Bidirectional etc.) delegate to their
+            # inner layer's l1/l2 even when the wrapper's own are zero.
+            reg = sum(l.regularization_penalty(p) for l, p in zip(self.layers, params))
+            return loss + reg, (new_state, new_carries)
 
     # -- jitted step -------------------------------------------------------
     def _make_step(self, with_carries: bool) -> StepProgram:
@@ -476,11 +480,6 @@ class MultiLayerNetwork:
             if grad_exchange is not None:
                 opt_state, residuals = opt_state
             batch = (x, y, fmask, lmask, ex_weight)
-            # phase spans here run at TRACE time (the python body executes
-            # once per compile): they attribute compile cost per phase and
-            # nest under the enclosing fit/compile span in the trace export.
-            # Runtime per-phase wall time needs the split-dispatch mode
-            # (DL4J_TPU_PHASE_SPANS=1, _fit_batch_phases).
             if not with_carries and _accum_applicable(accum, batch):
                 # DL4J_TPU_GRAD_ACCUM: scan over micro-batches, average the
                 # grads, run the (single) update/exchange below on the mean —
@@ -495,9 +494,8 @@ class MultiLayerNetwork:
 
                     return loss_fn
 
-                with obs.span("phase.bwd", mode="trace"):
-                    loss, new_state, grads = _accum_value_and_grad(
-                        accum, params, state, batch, rng, make_loss_fn)
+                loss, new_state, grads = _accum_value_and_grad(
+                    accum, params, state, batch, rng, make_loss_fn)
                 new_carries = None
             else:
                 rngs = list(jax.random.split(rng, len(layers)))
@@ -507,10 +505,9 @@ class MultiLayerNetwork:
                                       carries if with_carries else None,
                                       ex_weight=ex_weight)
 
-                with obs.span("phase.bwd", mode="trace"):
-                    (loss, (new_state, new_carries)), grads = jax.value_and_grad(
-                        loss_fn, has_aux=True
-                    )(params)
+                (loss, (new_state, new_carries)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True
+                )(params)
 
             if grad_exchange is not None:
                 loss = grad_exchange.mean_loss(loss)
@@ -527,9 +524,8 @@ class MultiLayerNetwork:
                 return (new_params, (new_opt, new_res), new_state,
                         new_carries, loss)
 
-            with obs.span("phase.update", mode="trace"):
-                out_params, out_opt = self._update_params(
-                    params, opt_state, grads, it)
+            out_params, out_opt = self._update_params(
+                params, opt_state, grads, it)
             if g_skip:
                 ok = resilience.guard_ok(loss, g_limit)
                 out_params = resilience.guard_select(ok, out_params, params)
@@ -541,8 +537,7 @@ class MultiLayerNetwork:
 
     def _update_params(self, params, opt_state, grads, it):
         """The per-layer optimizer update (normalization → updater →
-        constraints), shared by the fused step body and the split-dispatch
-        phase mode so both paths run identical math."""
+        constraints) of the fused step body, under the scope ``update``."""
         new_params = []
         new_opt = []
         for i, (u, layer) in enumerate(zip(self._updaters, self.layers)):
@@ -551,97 +546,46 @@ class MultiLayerNetwork:
                 new_params.append(params[i])
                 new_opt.append(opt_state[i])
                 continue
-            gn = getattr(layer, "gradient_normalization", None)
-            if gn:
-                g = apply_gradient_normalization(
-                    gn, getattr(layer, "gradient_normalization_threshold", 1.0), g
-                )
-            upd, new_s = u.update(g, opt_state[i], params[i], it)
-            p_new = jax.tree_util.tree_map(lambda p, d: p - d, params[i], upd)
-            if getattr(layer, "constraints", None):
-                # post-update projection, fused into the same executable
-                from deeplearning4j_tpu.nn.constraints import apply_constraints
+            with jax.named_scope("update"), layer_scope(layer, i):
+                gn = getattr(layer, "gradient_normalization", None)
+                if gn:
+                    g = apply_gradient_normalization(
+                        gn, getattr(layer, "gradient_normalization_threshold", 1.0), g
+                    )
+                upd, new_s = u.update(g, opt_state[i], params[i], it)
+                p_new = jax.tree_util.tree_map(lambda p, d: p - d, params[i], upd)
+                if getattr(layer, "constraints", None):
+                    # post-update projection, fused into the same executable
+                    from deeplearning4j_tpu.nn.constraints import apply_constraints
 
-                p_new = apply_constraints(layer, p_new)
+                    p_new = apply_constraints(layer, p_new)
             new_params.append(p_new)
             new_opt.append(new_s)
         return tuple(new_params), tuple(new_opt)
 
-    # -- split-dispatch phase profiling ------------------------------------
-    def _make_phase_fns(self):
-        """Three executables for the DL4J_TPU_PHASE_SPANS=1 profiling mode:
-        forward-only loss, value_and_grad (its forward recompute is the
-        price of splitting — bwd wall includes one fwd), and the optimizer
-        update. Same loss/update code as the fused step; the same rng key
-        feeds fwd and bwd so both see identical dropout draws. Nothing
-        donates: arguments are re-used across phases, and a profiling mode
-        measures wall time, not allocator behavior."""
-        layers = self.layers
+    def _get_grads_fn(self):
+        """The loss's value_and_grad as a program of its own (site
+        ``mln.grads``): ``(loss, new_state, grads)`` with nothing donated.
+        The elastic trainer's per-vshard backward (train/elastic.py), whose
+        update runs segmented across workers, outside any step."""
+        if getattr(self, "_grads_fn", None) is None:
+            layers = self.layers
 
-        def fwd(params, state, x, y, fmask, lmask, rng, ex_weight):
-            bucketing.telemetry().record_trace("mln.phase.fwd", np.shape(x))
-            rngs = list(jax.random.split(rng, len(layers)))
-            loss, _ = self._loss(params, state, x, y, fmask, lmask, rngs,
-                                 None, ex_weight=ex_weight)
-            return loss
+            def grads(params, state, x, y, fmask, lmask, rng, ex_weight):
+                bucketing.telemetry().record_trace("mln.grads", np.shape(x))
+                rngs = list(jax.random.split(rng, len(layers)))
 
-        def bwd(params, state, x, y, fmask, lmask, rng, ex_weight):
-            bucketing.telemetry().record_trace("mln.phase.bwd", np.shape(x))
-            rngs = list(jax.random.split(rng, len(layers)))
+                def loss_fn(p):
+                    return self._loss(p, state, x, y, fmask, lmask, rngs, None,
+                                      ex_weight=ex_weight)
 
-            def loss_fn(p):
-                return self._loss(p, state, x, y, fmask, lmask, rngs, None,
-                                  ex_weight=ex_weight)
+                (loss, (new_state, _)), g = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
+                return loss, new_state, g
 
-            (loss, (new_state, _)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
-            return loss, new_state, grads
-
-        def upd(params, opt_state, grads, it):
-            bucketing.telemetry().record_trace("mln.phase.update", ())
-            return self._update_params(params, opt_state, grads, it)
-
-        return (
-            StepProgram(fwd, "mln.phase.fwd", donate_argnums=(),
-                        aot_wrap=False),
-            StepProgram(bwd, "mln.phase.bwd", donate_argnums=(),
-                        aot_wrap=False),
-            StepProgram(upd, "mln.phase.update", donate_argnums=(),
-                        aot_wrap=False),
-        )
-
-    def _get_phase_fns(self):
-        if getattr(self, "_phase_fns", None) is None:
-            self._phase_fns = self._make_phase_fns()
-        return self._phase_fns
-
-    def _fit_batch_phases(self, x, y, fm, lm, ew):
-        """One training step as three blocked dispatches under nested
-        phase.fwd/phase.bwd/phase.update spans (inside the caller's
-        mln.fit_batch span). The block_until_ready barriers are the POINT
-        of this mode — per-phase wall times instead of one fused opaque
-        dispatch — and also why it is opt-in: blocking forfeits pipeline
-        overlap, so it profiles, never trains by default. Parameter math is
-        identical to the fused step; the divergence-guard fused select and
-        grad-exchange variants fall back to the fused path in _fit_batch."""
-        fwd, bwd, upd = self._get_phase_fns()
-        it = jnp.asarray(self.iteration, jnp.int32)
-        rng = self._next_rng()
-        ew_a = jnp.asarray(ew, self.dtype) if ew is not None else None
-        with obs.span("phase.fwd"):
-            loss_fwd = fwd(self.params, self.state, x, y, fm, lm, rng, ew_a)
-            jax.block_until_ready(loss_fwd)
-        with obs.span("phase.bwd"):
-            loss, new_state, grads = bwd(
-                self.params, self.state, x, y, fm, lm, rng, ew_a)
-            jax.block_until_ready(grads)
-        with obs.span("phase.update"):
-            self.params, self.opt_state = upd(
-                self.params, self.opt_state, grads, it)
-            jax.block_until_ready(self.params)
-        self.state = new_state
-        self.iteration += 1
-        return loss
+            self._grads_fn = StepProgram(grads, "mln.grads", donate_argnums=(),
+                                         aot_wrap=False)
+        return self._grads_fn
 
     def _make_chain_step(self):
         """K train steps per DISPATCH: lax.scan of the step body over
@@ -816,38 +760,49 @@ class MultiLayerNetwork:
                     from deeplearning4j_tpu.datasets.iterator import prefetch_to_device
 
                     stream = prefetch_to_device(stream)
-                for x, y, fm, lm, ew, n_real in stream:
-                    chainable = (
-                        chain_k > 1 and fm is None and lm is None
-                        and not (tbptt and np.ndim(x) == 3)
-                        and (not buf or _batch_sig((x, y))
-                             == _batch_sig((buf[0][0], buf[0][1])))
-                    )
-                    if chainable:
-                        buf.append((x, y))
+                stream = iter(stream)
+                while True:
+                    # one loop turn = one mln.iter span; its children share
+                    # its step number (obs/spans.py hands it down)
+                    with obs.span("mln.iter", step=self.iteration):
+                        with obs.span("mln.feed"):
+                            item = next(stream, None)
+                        if item is None:
+                            break
+                        x, y, fm, lm, ew, n_real = item
+                        chainable = (
+                            chain_k > 1 and fm is None and lm is None
+                            and not (tbptt and np.ndim(x) == 3)
+                            and (not buf or _batch_sig((x, y))
+                                 == _batch_sig((buf[0][0], buf[0][1])))
+                        )
+                        if chainable:
+                            buf.append((x, y))
+                            self.batch_in_epoch += 1
+                            if len(buf) == chain_k:
+                                flush(True)
+                            continue
+                        flush(False)
+                        with obs.span("mln.fit_batch"):
+                            if not sgd:
+                                score = self._fit_solver(x, y, fm, lm)
+                            elif tbptt and np.ndim(x) == 3:
+                                score = self._fit_tbptt(x, y, fm, lm)
+                            else:
+                                score = self._fit_batch(x, y, fm, lm, ew=ew)
                         self.batch_in_epoch += 1
-                        if len(buf) == chain_k:
-                            flush(True)
-                        continue
-                    flush(False)
-                    with obs.span("mln.fit_batch"):
-                        if not sgd:
-                            score = self._fit_solver(x, y, fm, lm)
-                        elif tbptt and np.ndim(x) == 3:
-                            score = self._fit_tbptt(x, y, fm, lm)
-                        else:
-                            score = self._fit_batch(x, y, fm, lm, ew=ew)
-                    self.batch_in_epoch += 1
-                    if guard is not None:
-                        guard.observe(self, score)
-                    # score is a device scalar; only sync the host when a
-                    # listener actually consumes it (keeps dispatch async);
-                    # n_real came from the pre-padding host side of the stream
-                    if self.listeners:
-                        score = float(score)  # graftlint: disable=host-sync
-                        resilience.note_score(score)
-                        for l in self.listeners:
-                            l.iteration_done(self, self.iteration, score, n_real)
+                        if guard is not None:
+                            guard.observe(self, score)
+                        # score is a device scalar; only sync the host when a
+                        # listener actually consumes it (keeps dispatch async);
+                        # n_real came from the pre-padding host side of the stream
+                        if self.listeners:
+                            with obs.span("mln.loss_fetch"):
+                                score = float(score)  # graftlint: disable=host-sync
+                            resilience.note_score(score)
+                            with obs.span("mln.listeners"):
+                                for l in self.listeners:
+                                    l.iteration_done(self, self.iteration, score, n_real)
                 flush(False)
                 if guard is not None:
                     guard.flush(self)
@@ -877,12 +832,6 @@ class MultiLayerNetwork:
         y = _cast_labels(y, self.dtype)
         fm = jnp.asarray(fm, self.dtype) if fm is not None else None
         lm = jnp.asarray(lm, self.dtype) if lm is not None else None
-        if (obs.phase_spans_enabled()
-                and getattr(self, "divergence_guard", None) is None):
-            # opt-in profiling mode: three blocked dispatches under nested
-            # phase spans; the fused step (guard select, donation, chaining)
-            # stays the production path
-            return self._fit_batch_phases(x, y, fm, lm, ew)
         step = self._get_step_fn(False)
         # dispatch() runs the step, then the retrace-guard check the program
         # owns: traces land at mln.step (inside the jitted body), bucket
@@ -981,18 +930,17 @@ class MultiLayerNetwork:
         x = _cast_input(x, self.dtype)
         fmask = jnp.asarray(fmask, self.dtype) if fmask is not None else None
         n = x.shape[0]
-        with obs.span("mln.output"):
-            if bucketing.bucketing_enabled() and n > 0:
-                target = bucketing.bucket_size(n)
-                bucketing.telemetry().record_hit("mln.output", n, target)
-                if target > n:
-                    x = bucketing.pad_rows_zero(x, target)
-                    fmask = bucketing.pad_rows_zero(fmask, target)
-                    return bucketing.unpad(
-                        self._output_fn.dispatch(
-                            self.params, self.state, x, fmask), n)
-            out = self._output_fn.dispatch(self.params, self.state, x, fmask)
-        return out
+        # dispatch() opens the mln.output span
+        if bucketing.bucketing_enabled() and n > 0:
+            target = bucketing.bucket_size(n)
+            bucketing.telemetry().record_hit("mln.output", n, target)
+            if target > n:
+                x = bucketing.pad_rows_zero(x, target)
+                fmask = bucketing.pad_rows_zero(fmask, target)
+                return bucketing.unpad(
+                    self._output_fn.dispatch(
+                        self.params, self.state, x, fmask), n)
+        return self._output_fn.dispatch(self.params, self.state, x, fmask)
 
     def predict(self, x) -> np.ndarray:
         # argmax on device: transfer the [B] class indices, not the full

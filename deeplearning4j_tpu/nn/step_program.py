@@ -30,6 +30,7 @@ time. See docs/PARALLELISM.md.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
@@ -47,8 +48,17 @@ __all__ = [
     "accum_value_and_grad",
     "chain_k_from_env",
     "grad_accum_from_env",
+    "layer_scope",
     "mesh_shape_from_env",
 ]
+
+
+def layer_scope(layer, index):
+    """The ``jax.named_scope`` of one layer (or graph vertex) inside a step:
+    its class and its index (or vertex name), as in ``TransformerBlock.3``.
+    HLO metadata only: a profiler trace shows every device operation under
+    ``<site>/<layer scope>/...`` (docs/OBSERVABILITY.md)."""
+    return jax.named_scope(f"{type(layer).__name__}.{index}")
 
 
 class StepProgram:
@@ -63,12 +73,16 @@ class StepProgram:
     - **AOT**: the jitted function is registered at ``site`` on ``model``'s
       AOT registry (``aot.wrap``) so ladder warmup, bundle persistence and
       warm dispatch all find it — ``aot_wrap=False`` opts out for entry
-      points that must bypass the AOT dispatcher (chained steps, phase
-      profiling) while keeping the lazy cost-exemplar harvest;
+      points that must bypass the AOT dispatcher (chained steps) while
+      keeping the lazy cost-exemplar harvest;
     - **retrace guard**: :meth:`dispatch` runs the call followed by the
       guard check for ``guard_site`` (defaults to ``site``) with the
       configured ``hits_site``/``extra_allowed``, so callers can't forget
-      the check or disagree on the budget.
+      the check or disagree on the budget;
+    - **names**: the body runs under ``jax.named_scope(site)``, and every
+      call is one ``obs.span(site)`` over the signature lookup, the enqueue
+      and the guard check: the site's name is on the device operations and
+      on the host's timeline of any profiler trace.
 
     ``wrap_body`` (e.g. a ``shard_map`` closure for the explicit DP
     exchange) transforms the body before jit. Everything not implemented
@@ -92,7 +106,13 @@ class StepProgram:
         self.hits_site = hits_site
         self.extra_allowed = extra_allowed
         self.donate_argnums = tuple(donate_argnums)
-        fn = body if wrap_body is None else wrap_body(body)
+        inner = body if wrap_body is None else wrap_body(body)
+
+        @functools.wraps(inner)
+        def fn(*args, **kw):
+            with jax.named_scope(site):
+                return inner(*args, **kw)
+
         kwargs: dict = {"donate_argnums": self.donate_argnums}
         if static_argnums is not None:
             kwargs["static_argnums"] = tuple(static_argnums)
@@ -104,9 +124,13 @@ class StepProgram:
 
     # -- dispatch ----------------------------------------------------------
     def __call__(self, *args, **kwargs):
+        with obs.span(self.site):
+            return self._run(*args, **kwargs)
+
+    def _run(self, *args, **kwargs):
         out = self._fn(*args, **kwargs)
         if not self._aot:
-            # plain-jit programs (chained dispatch, phase fns) still feed
+            # plain-jit programs (chained dispatch) still feed
             # the cost model: aval capture only on the (rare) compile path
             from deeplearning4j_tpu.obs import profile as _profile
 
@@ -121,8 +145,9 @@ class StepProgram:
 
     def dispatch(self, *args, **kwargs):
         """Call, then run the retrace-guard check this program owns."""
-        out = self(*args, **kwargs)
-        self.guard()
+        with obs.span(self.site):
+            out = self._run(*args, **kwargs)
+            self.guard()
         return out
 
     def guard(self):
@@ -160,15 +185,10 @@ _CHAIN_RNG_WARNED = False
 def chain_k_from_env(uses_rng: bool, n_params: int) -> int:
     """Shared chained-fit gate for MultiLayerNetwork and ComputationGraph:
     DL4J_TPU_CHAIN_STEPS forces a count (0 disables); "auto" chains 8 only
-    for rng-free models small enough to be dispatch-bound. Phase-span
-    profiling (DL4J_TPU_PHASE_SPANS=1) disables auto-chaining: its whole
-    point is per-phase dispatch, which a K-step chain would hide — an
-    explicit DL4J_TPU_CHAIN_STEPS count still wins."""
+    for rng-free models small enough to be dispatch-bound."""
     import os as _os
 
     env = _os.environ.get("DL4J_TPU_CHAIN_STEPS", "auto")
-    if env == "auto" and obs.phase_spans_enabled():
-        return 0
     if env != "auto":
         try:
             k = max(int(env), 0)

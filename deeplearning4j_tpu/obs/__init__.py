@@ -54,8 +54,8 @@ __all__ = [
     "observe_request",
     "observe_shed",
     "observe_ttft",
+    "observe_wait",
     "set_decode_occupancy",
-    "phase_spans_enabled",
     "prometheus_text",
     "recent_spans",
     "registry",
@@ -70,14 +70,6 @@ __all__ = [
 def enabled() -> bool:
     """Master switch (default on). Read per call so tests can flip it."""
     return os.environ.get("DL4J_TPU_OBS", "1") != "0"
-
-
-def phase_spans_enabled() -> bool:
-    """Opt-in split-dispatch profiling mode (DL4J_TPU_PHASE_SPANS=1): the
-    fit loops dispatch fwd/bwd/update as separate blocked executables so
-    nested phase spans carry real per-phase wall time. Costs pipeline
-    overlap — a profiling mode, never the default. Implies enabled()."""
-    return enabled() and os.environ.get("DL4J_TPU_PHASE_SPANS", "0") == "1"
 
 
 # -- metrics ----------------------------------------------------------------
@@ -170,6 +162,15 @@ def observe_ttft(route: str, latency_s: float):
     from deeplearning4j_tpu.obs import slo as _slo
 
     _slo.observe_ttft(route, latency_s)
+
+
+def observe_wait(route: str, stage: str, latency_s: float):
+    """Record what one generation request waited in ``stage`` (``queue``:
+    submit to admit; ``prefill``: admit to first token; see obs/slo.py).
+    No-op when DL4J_TPU_OBS=0; never raises."""
+    from deeplearning4j_tpu.obs import slo as _slo
+
+    _slo.observe_wait(route, stage, latency_s)
 
 
 def observe_itl(route: str, latency_s: float):

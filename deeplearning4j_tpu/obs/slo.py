@@ -51,7 +51,8 @@ from typing import Deque, Dict, Optional, Tuple
 from deeplearning4j_tpu.obs import metrics
 
 __all__ = ["SloTracker", "slo_tracker", "observe_request", "observe_shed",
-           "observe_ttft", "observe_itl", "set_decode_occupancy"]
+           "observe_ttft", "observe_wait", "observe_itl",
+           "set_decode_occupancy"]
 
 
 def _parse_route_thresholds(spec: str) -> Dict[str, float]:
@@ -123,6 +124,12 @@ class SloTracker:
             "dl4j_itl_seconds",
             "inter-token latency by route (decode-step cadence as the "
             "stream consumer sees it)", ("route",))
+        self._wait = self._reg.histogram(
+            "dl4j_request_wait_seconds",
+            "what a generation request waited for before its first token, "
+            "by route and stage: queue (submit to admit) and prefill (admit "
+            "to first token); the two add up to dl4j_ttft_seconds",
+            ("route", "stage"))
         self._tokens = self._reg.counter(
             "dl4j_tokens_generated_total",
             "generated tokens by route (every emitted decode token)",
@@ -200,6 +207,14 @@ class SloTracker:
         except Exception:
             pass
 
+    def observe_wait(self, route: str, stage: str, latency_s: float):
+        """Record one request's wait in ``stage`` (``queue`` or
+        ``prefill``). Never raises."""
+        try:
+            self._wait.observe(latency_s, route=route, stage=stage)
+        except Exception:
+            pass
+
     def observe_itl(self, route: str, latency_s: float):
         """Record one inter-token gap; every call is one more generated
         token. A gap over the ITL threshold burns budget — stream stutter
@@ -265,6 +280,14 @@ def observe_ttft(route: str, latency_s: float):
 
     if obs.enabled():
         slo_tracker().observe_ttft(route, latency_s)
+
+
+def observe_wait(route: str, stage: str, latency_s: float):
+    """Module-level convenience; honors the DL4J_TPU_OBS kill switch."""
+    from deeplearning4j_tpu import obs
+
+    if obs.enabled():
+        slo_tracker().observe_wait(route, stage, latency_s)
 
 
 def observe_itl(route: str, latency_s: float):

@@ -13,9 +13,19 @@
   together locate the bottleneck without device instrumentation.
 
 Nesting is tracked per thread: a span opened while another is active records
-the outer span's name as ``parent`` and its own ``depth``. Finished spans go
+the outer span's name as ``parent`` and its own ``depth``, and takes over the
+outer span's ``step`` attribute where it has none of its own, so the spans
+of one training iteration share one identifier. Finished spans go
 to a bounded ring buffer (most recent last) and into the
 ``dl4j_span_seconds`` histogram family in the metrics registry.
+
+A span is also a ``jax.profiler.TraceAnnotation`` of the same name and
+extent, carrying ``span_depth`` and the span's scalar attributes: whenever a
+profiler trace is being taken (``ProfilerListener``, the benchmark's traced
+run) every span lies in the trace's host plane, on the same clock as the
+device's operations, and an idle gap of the device can be read off the span
+that covers it (docs/OBSERVABILITY.md, "Reading a profile"). With no trace
+active the annotation is TraceMe's inactive path (under a microsecond).
 
 Ring records carry everything ``obs/trace_export.py`` needs to render a
 Chrome/Perfetto timeline: ``t0_s`` (span start on the process-local
@@ -60,12 +70,33 @@ def _ring_capacity() -> int:
     return n if n > 0 else _RING_DEFAULT
 
 
-class _ActiveSpan:
-    __slots__ = ("name", "attrs", "t0", "c0")
+SPAN_DEPTH_STAT = "span_depth"  # the stat that marks a program span in a trace
 
-    def __init__(self, name: str, attrs: Dict[str, object]):
+_TraceAnnotation = None
+
+
+def _annotation(name: str, depth: int, attrs: Dict[str, object]):
+    """The span as the profiler sees it. jax is imported on the first span,
+    not with the obs layer."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    scalars = {k: v for k, v in attrs.items()
+               if isinstance(v, (bool, int, float, str))}
+    scalars[SPAN_DEPTH_STAT] = depth
+    return _TraceAnnotation(name, **scalars)
+
+
+class _ActiveSpan:
+    __slots__ = ("name", "attrs", "t0", "c0", "annotation")
+
+    def __init__(self, name: str, attrs: Dict[str, object], depth: int):
         self.name = name
         self.attrs = attrs
+        self.annotation = _annotation(name, depth, attrs)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         self.c0 = time.thread_time()
 
@@ -147,8 +178,12 @@ class SpanTracer:
         return st
 
     def _push(self, name: str, attrs: Dict[str, object]) -> _ActiveSpan:
-        sp = _ActiveSpan(name, attrs)
-        self._stack().append(sp)
+        stack = self._stack()
+        if stack and "step" in stack[-1].attrs and "step" not in attrs:
+            # the spans of one iteration share its step number
+            attrs = dict(attrs, step=stack[-1].attrs["step"])
+        sp = _ActiveSpan(name, attrs, len(stack))
+        stack.append(sp)
         return sp
 
     def _pop(self, sp: Optional[_ActiveSpan], error: bool = False):
@@ -156,6 +191,7 @@ class SpanTracer:
             return
         wall = time.perf_counter() - sp.t0
         cpu = time.thread_time() - sp.c0
+        sp.annotation.__exit__(None, None, None)
         stack = self._stack()
         # tolerate exotic unwinds: pop through to OUR frame
         while stack and stack[-1] is not sp:

@@ -8,8 +8,8 @@ one microsecond axis and emits the `trace_event format`_ that both
 ``chrome://tracing`` and https://ui.perfetto.dev load directly:
 
 - every finished span becomes a complete event (``ph: "X"``) on its
-  thread's lane, so nested phase spans (``phase.fwd`` under
-  ``mln.fit_batch``) render as stacked slices;
+  thread's lane, so nested spans (``mln.step`` under ``mln.fit_batch``
+  under ``mln.iter``) render as stacked slices;
 - ``compile`` spans keep their ``site``/``mode`` attrs as args (cold-start
   analysis: the compile wall is literally visible);
 - event-log records become instant events (``ph: "i"``) — their wall-clock

@@ -183,6 +183,7 @@ def _fwd_pallas_call(qt, kt, vt, *, D, bq, bk, q_pad, k_pad, t_real_k,
             jax.ShapeDtypeStruct((BH, 1, q_pad), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
 
 
@@ -356,6 +357,7 @@ def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, D, bq, bk, q_pad,
         out_specs=pl.BlockSpec((1, bq, D), blkq, **kw),
         out_shape=jax.ShapeDtypeStruct((BH, q_pad, D), dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*dq_args)
 
     blkk = lambda bh, i: (bh, i, 0)          # noqa: E731
@@ -388,6 +390,7 @@ def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, D, bq, bk, q_pad,
             jax.ShapeDtypeStruct((BH, k_pad, D), dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*dkv_args)
     return dq, dk, dv
 

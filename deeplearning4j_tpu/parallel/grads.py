@@ -49,7 +49,6 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.analysis import retrace_guard
 from deeplearning4j_tpu.parallel import compress as compression
 from deeplearning4j_tpu.train.updaters import apply_gradient_normalization
@@ -173,7 +172,7 @@ class GradExchange:
         if e.compress:
             # residual + encode run in f32 regardless of the param dtype so
             # sub-threshold error feedback never rounds away in bf16
-            with obs.span("phase.compress", mode="trace"):
+            with jax.named_scope("compress"):
                 gflat32 = _pad_flat(_flat(g).astype(jnp.float32), e.n_pad)
                 packed, r = compression.encode_packed(
                     gflat32, r_loc.reshape(-1), thr)
@@ -212,10 +211,9 @@ class GradExchange:
         """Replaces the step body's per-layer update loop. Returns
         ``(new_params, new_opt, new_residuals)`` in the model's container
         type (tuple of layers / dict of vertices)."""
-        # trace-time span: this whole method runs inside the shard_map trace,
-        # so a runtime span here would time tracing, not the collectives —
-        # mode="trace" records exactly that (compile-cost attribution)
-        with obs.span("phase.exchange", mode="trace"):
+        # a scope, not a span: this method runs inside the shard_map trace,
+        # and the scope puts the collectives' device time under dp.step/exchange
+        with jax.named_scope("exchange"):
             return self._update_traced(grads, params, opt_state, residuals, it)
 
     def _update_traced(self, grads, params, opt_state, residuals, it):
@@ -610,12 +608,12 @@ class DataParallelStep:
         fm = jnp.asarray(fm, model.dtype) if fm is not None else None
         lm = jnp.asarray(lm, model.dtype) if lm is not None else None
         ew = jnp.asarray(ew, model.dtype) if ew is not None else None
-        with obs.span("dp.step"):
-            (model.params, (self._opt_flat, self._residual), model.state,
-             _, loss) = self._step.dispatch(
-                model.params, (self._opt_flat, self._residual), model.state,
-                jnp.asarray(model.iteration, jnp.int32), model._next_rng(),
-                x, y, fm, lm, (), ew)
+        # dispatch() opens the dp.step span
+        (model.params, (self._opt_flat, self._residual), model.state,
+         _, loss) = self._step.dispatch(
+            model.params, (self._opt_flat, self._residual), model.state,
+            jnp.asarray(model.iteration, jnp.int32), model._next_rng(),
+            x, y, fm, lm, (), ew)
         model.iteration += 1
         return loss
 
@@ -634,11 +632,10 @@ class DataParallelStep:
             chaos.maybe_slow(model.iteration)
             f = chaos.maybe_nan_batch(model.iteration, f)
         ew = jnp.asarray(ew, model.dtype) if ew is not None else None
-        with obs.span("dp.step"):
-            (model.params, (self._opt_flat, self._residual), model.state,
-             _, loss) = self._step.dispatch(
-                model.params, (self._opt_flat, self._residual), model.state,
-                jnp.asarray(model.iteration, jnp.int32), model._next_rng(),
-                model._input_dict(f), l, model._mask_dict(fm), lm, {}, ew)
+        (model.params, (self._opt_flat, self._residual), model.state,
+         _, loss) = self._step.dispatch(
+            model.params, (self._opt_flat, self._residual), model.state,
+            jnp.asarray(model.iteration, jnp.int32), model._next_rng(),
+            model._input_dict(f), l, model._mask_dict(fm), lm, {}, ew)
         model.iteration += 1
         return loss

@@ -37,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.nn.step_program import StepProgram, mesh_shape_from_env
 from deeplearning4j_tpu.parallel.context import use_mesh
 from deeplearning4j_tpu.parallel.mesh import MeshSpec, make_mesh
@@ -293,7 +292,7 @@ class MeshTrainer:
         fm = self._shard_batch(fm)
         lm = self._shard_batch(lm)
         ew = self._shard_batch(ew)
-        with use_mesh(self.mesh), obs.span("mesh.step"):
+        with use_mesh(self.mesh):     # dispatch() opens the mesh.step span
             (model.params, model.opt_state, model.state, _,
              loss) = step.dispatch(
                 model.params, model.opt_state, model.state,

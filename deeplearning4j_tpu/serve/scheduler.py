@@ -621,7 +621,7 @@ class _Stream:
 
     __slots__ = ("prompt", "max_new", "eos", "deadline", "arrival", "out",
                  "state", "fed", "cached", "generated", "next_tok", "pages",
-                 "slot", "last_emit", "sid")
+                 "slot", "last_emit", "sid", "admitted")
 
     def __init__(self, prompt: List[int], max_new: int, eos: Optional[int],
                  deadline: float, arrival: float, sid: int):
@@ -640,6 +640,7 @@ class _Stream:
         self.pages: List[int] = []   # owned page ids (paged mode)
         self.slot: Optional[int] = None       # owned strip (contiguous mode)
         self.last_emit: Optional[float] = None
+        self.admitted: Optional[float] = None  # when it joined the batch
 
 
 class GenerateStream:
@@ -731,7 +732,11 @@ class GenerateWorker:
             self._free_pages = None
             self._free_slots = list(range(program.max_batch))
         self.stats_counters = {"joins": 0, "leaves": 0, "generated": 0,
-                               "shed_midstream": 0, "max_occupancy": 0}
+                               "shed_midstream": 0, "max_occupancy": 0,
+                               # per request: submit -> admit, admit -> first
+                               # token (sum of seconds, number of requests)
+                               "queue_wait_s": 0.0, "queue_waits": 0,
+                               "prefill_wait_s": 0.0, "prefill_waits": 0}
         self._thread = threading.Thread(target=self._engine_loop, daemon=True,
                                         name=f"generate-{name}")
         self._thread.start()
@@ -827,11 +832,20 @@ class GenerateWorker:
                     s.slot = self._free_slots.pop()
                 s.state = "prefill"
                 self._active.append(s)
+            s.admitted = time.perf_counter()
+            self._note_wait("queue", s.admitted - s.arrival)
             self.stats_counters["joins"] += 1
             occ = len(self._active)
             if occ > self.stats_counters["max_occupancy"]:
                 self.stats_counters["max_occupancy"] = occ
             obs.set_decode_occupancy(self.name, occ)
+
+    def _note_wait(self, stage: str, seconds: float):
+        """One request's wait in ``stage`` (``queue``: submit to admit;
+        ``prefill``: admit to first token), once per request."""
+        self.stats_counters[f"{stage}_wait_s"] += seconds
+        self.stats_counters[f"{stage}_waits"] += 1
+        obs.observe_wait(self.route, stage, seconds)
 
     def _leave(self, s: _Stream, reason: str):
         """Stream leaves the batch at a token boundary; its cache capacity
@@ -859,6 +873,7 @@ class GenerateWorker:
         self.stats_counters["generated"] += 1
         if s.last_emit is None:
             obs.observe_ttft(self.route, now - s.arrival)
+            self._note_wait("prefill", now - s.admitted)
         else:
             obs.observe_itl(self.route, now - s.last_emit)
         s.last_emit = now
@@ -910,19 +925,22 @@ class GenerateWorker:
         tokens[0, :len(chunk)] = chunk
         bucketing.telemetry().record_hit("serve.gen.prefill", len(chunk), tc)
         t0 = time.perf_counter()
-        _, ids = self.program.dispatch(
-            self._table_for([s], npb), [s.cached], tokens, [len(chunk)])
-        # host sync: the emitted token IS the product (fetch, THEN index —
-        # indexing the device array would compile two eager ops on the
-        # first request)
-        tok = int(np.asarray(ids)[0])
+        with obs.span("serve.prefill_chunk", tc=tc, pages=npb,
+                      model=self.name):
+            _, ids = self.program.dispatch(
+                self._table_for([s], npb), [s.cached], tokens, [len(chunk)])
+            # host sync: the emitted token IS the product (fetch, THEN index —
+            # indexing the device array would compile two eager ops on the
+            # first request)
+            tok = int(np.asarray(ids)[0])
         dt = time.perf_counter() - t0
         self.latency.observe(f"{self.name}:prefill", tc, dt)
         s.fed += len(chunk)
         s.cached += len(chunk)
         if s.fed >= len(s.prompt):
             # the final prefill chunk's logits ARE the first token
-            self._emit(s, tok, 1, time.perf_counter())
+            with obs.span("serve.fanout", rows=1):
+                self._emit(s, tok, 1, time.perf_counter())
         return True
 
     def _decode_step(self):
@@ -953,14 +971,16 @@ class GenerateWorker:
             n_new[i] = 1
         bucketing.telemetry().record_hit("serve.gen.decode", B, bb)
         t0 = time.perf_counter()
-        _, ids = self.program.dispatch(table, lengths, tokens, n_new)
-        ids = np.asarray(ids)  # host sync: tokens fan out to streams now
+        with obs.span("serve.decode_step", rows=B, batch=bb, pages=npb):
+            _, ids = self.program.dispatch(table, lengths, tokens, n_new)
+            ids = np.asarray(ids)  # host sync: tokens fan out to streams now
         dt = time.perf_counter() - t0
         self.latency.observe(f"{self.name}:decode", bb, dt)
         now = time.perf_counter()
-        for i, s in enumerate(streams):
-            s.cached += 1
-            self._emit(s, int(ids[i]), bb, now)
+        with obs.span("serve.fanout", rows=B):
+            for i, s in enumerate(streams):
+                s.cached += 1
+                self._emit(s, int(ids[i]), bb, now)
         return True
 
     def _engine_loop(self):
@@ -970,23 +990,30 @@ class GenerateWorker:
                     self._cond.wait()
                 if self._stop:
                     return
+            with obs.span("serve.engine_iter", model=self.name):
+                self._engine_turn()
+
+    def _engine_turn(self):
+        """One turn of the engine loop: admit, one prefill chunk, one decode
+        step."""
+        with obs.span("serve.admit"):
             self._admit(time.perf_counter())
-            try:
-                did = self._prefill_one()
-                did = self._decode_step() or did
-            except Exception as e:  # fail every in-flight stream, keep serving
-                with self._cond:
-                    failing = list(self._active)
-                for s in failing:
-                    # error event first: the consumer stops at the first
-                    # terminal event, _leave's "done" is just queue residue
-                    s.out.put(("error", e))
-                    self._leave(s, "shutdown")
-                continue
-            if not did:
-                # active streams exist but none dispatchable (all queued
-                # behind admit) — yield briefly rather than spin
-                time.sleep(0.0002)
+        try:
+            did = self._prefill_one()
+            did = self._decode_step() or did
+        except Exception as e:  # fail every in-flight stream, keep serving
+            with self._cond:
+                failing = list(self._active)
+            for s in failing:
+                # error event first: the consumer stops at the first
+                # terminal event, _leave's "done" is just queue residue
+                s.out.put(("error", e))
+                self._leave(s, "shutdown")
+            return
+        if not did:
+            # active streams exist but none dispatchable (all queued
+            # behind admit) — yield briefly rather than spin
+            time.sleep(0.0002)
 
     # -- lifecycle ---------------------------------------------------------
 

@@ -253,7 +253,7 @@ class ElasticTrainer:
         self._stragglers: set = set()
         self._last_publish = 0.0
         self._build_plan()
-        _, self._bwd, _ = model._get_phase_fns()
+        self._bwd = model._get_grads_fn()
         self._base_rng = model._rng
         # formed state: segment stats per flat entry {key: {rank: [S, m]}},
         # dense structured opt per dense entry, residuals per owned vshard

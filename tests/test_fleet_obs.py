@@ -16,6 +16,7 @@ import json
 import os
 import subprocess
 import sys
+import time
 import urllib.request
 
 import numpy as np
@@ -326,10 +327,17 @@ class TestHttpPropagation:
                     if r["span"] == "serve.dispatch"]
         assert dispatch
         assert inbound.trace_id in dispatch[-1]["attrs"]["traces"]
-        # and the front-door span itself is stamped
-        http_spans = [r for r in obs.recent_spans()
-                      if r["span"] == "http.request"
-                      and r.get("trace_id") == inbound.trace_id]
+        # and the front-door span itself is stamped; the server closes it
+        # after the reply's last byte is out (serve/httpcommon.py), so the
+        # client may be here first: wait for it
+        deadline = time.monotonic() + 5.0
+        while True:
+            http_spans = [r for r in obs.recent_spans()
+                          if r["span"] == "http.request"
+                          and r.get("trace_id") == inbound.trace_id]
+            if http_spans or time.monotonic() > deadline:
+                break
+            time.sleep(0.01)
         assert http_spans
 
     def test_trace_minted_when_absent(self, server):

@@ -416,45 +416,240 @@ class TestCostModels:
 
 
 # ---------------------------------------------------------------------------
-# phase attribution (DL4J_TPU_PHASE_SPANS=1 split-dispatch profiling mode)
+# one clock: spans as profiler annotations, scopes and kernel names in the HLO
 # ---------------------------------------------------------------------------
 
 
-class TestPhaseSpans:
-    def test_phase_spans_nested_under_fit_batch(self, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_PHASE_SPANS", "1")
-        x, y = _toy_data()
-        model = MultiLayerNetwork(_mlp_conf()).init()
-        model.fit((x, y), epochs=1)
-        by_name = {}
-        for rec in obs.recent_spans():
-            by_name.setdefault(rec["span"], []).append(rec)
-        for name in ("phase.fwd", "phase.bwd", "phase.update"):
-            assert name in by_name, f"missing {name} span"
-            for rec in by_name[name]:
-                assert rec["parent"] == "mln.fit_batch"
-                assert rec["depth"] == 1
+class _ScoreListener(TrainingListener):
+    def iteration_done(self, model, iteration, score, batch_size=0):
+        pass
 
-    def test_phase_mode_params_match_fused(self, monkeypatch):
+
+def _host_annotations(trace_dir):
+    """(name, start_ns, end_ns, stats) of every host-plane event of a
+    jax.profiler trace that carries the spans' ``span_depth`` stat."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from deeplearning4j_tpu.obs.spans import SPAN_DEPTH_STAT
+
+    path = sorted(glob.glob(os.path.join(
+        str(trace_dir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                stats = {k: str(v) for k, v in e.stats}
+                if SPAN_DEPTH_STAT in stats:
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                stats))
+    return out
+
+
+def _traced(tmp_path, fn):
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_annotations(tmp_path)
+
+
+class TestProfilerClock:
+    def test_fit_spans_nest_in_the_profilers_host_plane(self, tmp_path,
+                                                        monkeypatch):
+        monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
+        x, y = _toy_data(32)
+        model = MultiLayerNetwork(_mlp_conf()).init()
+        model.set_listeners(_ScoreListener())
+        model.fit((x, y), epochs=1, batch_size=8)      # compile outside
+        obs.reset()
+        it0 = model.iteration
+        ann = _traced(tmp_path, lambda: model.fit((x, y), epochs=1,
+                                                  batch_size=8))
+        by_step = {}
+        for name, a, b, stats in ann:
+            by_step.setdefault(int(stats["step"]), {}).setdefault(
+                name, []).append((a, b, int(stats["span_depth"])))
+        # four batches, and the turn that finds the stream at its end
+        assert sorted(by_step) == list(range(it0, it0 + 5))
+        for step in range(it0, it0 + 4):
+            spans = by_step[step]
+            assert set(spans) == {"mln.iter", "mln.feed", "mln.fit_batch",
+                                  "mln.step", "mln.loss_fetch",
+                                  "mln.listeners"}
+            assert all(len(v) == 1 for v in spans.values())
+            (ia, ib, idepth), = spans["mln.iter"]
+            assert idepth == 0
+            children = ["mln.feed", "mln.fit_batch", "mln.loss_fetch",
+                        "mln.listeners"]
+            for prev, nxt in zip(children, children[1:]):
+                assert spans[prev][0][1] <= spans[nxt][0][0]
+            for c in children:
+                a, b, depth = spans[c][0]
+                assert ia <= a and b <= ib and depth == 1
+            (fa, fb, _), (sa, sb, sdepth) = (spans["mln.fit_batch"][0],
+                                             spans["mln.step"][0])
+            assert fa <= sa and sb <= fb and sdepth == 2
+        assert set(by_step[it0 + 4]) == {"mln.iter", "mln.feed"}
+        # the ring holds the same spans with the same step numbers
+        ring = [(r["span"], r["attrs"]["step"]) for r in obs.recent_spans()]
+        assert sorted(ring) == sorted(
+            (name, int(stats["step"])) for name, _, _, stats in ann)
+
+    def test_graph_fit_has_the_same_spans(self, monkeypatch):
+        from deeplearning4j_tpu.nn.graph import (
+            ComputationGraph, ComputationGraphConfiguration)
+
+        monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
+        conf = (ComputationGraphConfiguration.builder()
+                .add_inputs("in")
+                .set_input_types(InputType.feed_forward(4))
+                .add_layer("h", Dense(n_out=8, activation="tanh"), "in")
+                .add_layer("out", OutputLayer(n_out=2, activation="softmax"),
+                           "h")
+                .set_outputs("out")
+                .updater({"type": "sgd", "lr": 0.05}).build())
+        g = ComputationGraph(conf).init()
+        g.set_listeners(_ScoreListener())
+        x, y = _toy_data(16)
+        g.fit((x, y), epochs=1, batch_size=8)
+        turns = [r for r in obs.recent_spans() if r["span"] == "cg.iter"]
+        assert [r["attrs"]["step"] for r in turns] == [0, 1, 2]
+        for name in ("cg.feed", "cg.fit_batch", "cg.loss_fetch",
+                     "cg.listeners"):
+            recs = [r for r in obs.recent_spans() if r["span"] == name]
+            assert recs and all(r["parent"] == "cg.iter" for r in recs), name
+        steps = [r for r in obs.recent_spans() if r["span"] == "cg.step"]
+        assert [r["parent"] for r in steps] == ["cg.fit_batch"] * 2
+        assert [r["attrs"]["step"] for r in steps] == [0, 1]
+
+    def test_every_call_of_a_step_program_is_one_span_of_its_site(self):
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.nn.step_program import StepProgram
+
+        prog = StepProgram(lambda a: a + 1, "toy.site", donate_argnums=(),
+                           aot_wrap=False)
+        prog(jnp.ones(3))
+        with obs.span("outer", step=41):
+            prog.dispatch(jnp.ones(3))
+        recs = [r for r in obs.recent_spans() if r["span"] == "toy.site"]
+        assert [r["parent"] for r in recs] == [None, "outer"]
+        assert "attrs" not in recs[0] and recs[1]["attrs"] == {"step": 41}
+
+    def test_lowered_step_carries_site_layer_loss_and_update_scopes(self):
+        import jax
+        import jax.numpy as jnp
+
+        model = MultiLayerNetwork(_mlp_conf()).init()
+        x, y = _toy_data(8)
+        step = model._get_step_fn(False)
+        text = step.lower(
+            model.params, model.opt_state, model.state,
+            jnp.asarray(0, jnp.int32), jax.random.PRNGKey(0),
+            jnp.asarray(x), jnp.asarray(y), None, None, (),
+        ).as_text(debug_info=True)
+        for scope in ("mln.step/jvp(Dense.0)", "mln.step/transpose(jvp(Dense.0))",
+                      "mln.step/jvp(loss)/OutputLayer.1",
+                      "mln.step/update/Dense.0",
+                      "mln.step/update/OutputLayer.1"):
+            assert scope in text, scope
+
+    def test_flash_kernels_carry_their_names(self):
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.ops.flash_attention import flash_attention
+
+        q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, causal=True, interpret=True).sum()
+
+        fwd = str(jax.make_jaxpr(loss)(q, q, q))
+        both = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+        names = lambda t: set(re.findall(r"flash_(?:fwd|bwd_\w+)", t))  # noqa: E731
+        assert names(fwd) == {"flash_fwd"}
+        assert names(both) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+
+    def test_scopes_leave_the_steps_results_bit_identical(self, monkeypatch):
+        import contextlib
+
         import jax
 
         monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
         x, y = _toy_data()
-        fused = MultiLayerNetwork(_mlp_conf()).init()
-        fused.fit((x, y), epochs=2)
-        monkeypatch.setenv("DL4J_TPU_PHASE_SPANS", "1")
-        split = MultiLayerNetwork(_mlp_conf()).init()
-        split.fit((x, y), epochs=2)
-        for a, b in zip(jax.tree_util.tree_leaves(fused.params),
-                        jax.tree_util.tree_leaves(split.params)):
+        scoped = MultiLayerNetwork(_mlp_conf()).init()
+        scoped.fit((x, y), epochs=2, batch_size=8)
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        bare = MultiLayerNetwork(_mlp_conf()).init()
+        bare.fit((x, y), epochs=2, batch_size=8)
+        for a, b in zip(jax.tree_util.tree_leaves(scoped.params),
+                        jax.tree_util.tree_leaves(bare.params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
-    def test_phase_mode_disables_auto_chaining(self, monkeypatch):
-        # phase profiling wants per-phase dispatch; the auto K-step chain
-        # would hide it (an explicit CHAIN_STEPS count still wins)
-        monkeypatch.setenv("DL4J_TPU_PHASE_SPANS", "1")
+    def test_kill_switch_opens_no_annotation(self, tmp_path, monkeypatch):
+        monkeypatch.setenv("DL4J_TPU_OBS", "0")
+        monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
+        x, y = _toy_data(16)
         model = MultiLayerNetwork(_mlp_conf()).init()
-        assert model._chain_k() == 0
+        model.set_listeners(_ScoreListener())
+        assert _traced(tmp_path, lambda: model.fit((x, y), epochs=1,
+                                                   batch_size=8)) == []
+        assert obs.recent_spans() == []
+
+    def test_serving_engine_spans_and_request_waits(self):
+        from deeplearning4j_tpu import serve
+        from tests.test_generate import _cfg, _lm, _prompt
+
+        reg = serve.ModelRegistry()
+        try:
+            gw = reg.register_generate("lm", _lm(), warm=True, config=_cfg())
+            obs.reset()
+            streams = [gw.submit(_prompt(n, seed=n), max_new=4)
+                       for n in (5, 19)]
+            assert [len(list(s)) for s in streams] == [4, 4]
+        finally:
+            reg.shutdown()
+        recs = obs.recent_spans()
+        by_name = {}
+        for r in recs:
+            by_name.setdefault(r["span"], []).append(r)
+        assert all(r["parent"] is None and r["attrs"] == {"model": "lm"}
+                   for r in by_name["serve.engine_iter"])
+        for name in ("serve.admit", "serve.prefill_chunk",
+                     "serve.decode_step", "serve.fanout"):
+            assert by_name[name], name
+            assert all(r["parent"] == "serve.engine_iter"
+                       for r in by_name[name]), name
+        # the 19-token prompt takes two chunks of 16, the 5-token one
+        assert sorted(r["attrs"]["tc"] for r in by_name["serve.prefill_chunk"]) \
+            == [4, 8, 16]
+        assert all(set(r["attrs"]) == {"tc", "pages", "model"}
+                   for r in by_name["serve.prefill_chunk"])
+        for r in by_name["serve.decode_step"]:
+            assert set(r["attrs"]) == {"rows", "batch", "pages"}
+            assert 1 <= r["attrs"]["rows"] <= r["attrs"]["batch"]
+        # each dispatch is the decode.step site's own span inside
+        assert {r["parent"] for r in by_name["decode.step"]} == {
+            "serve.prefill_chunk", "serve.decode_step"}
+        # one queue wait and one prefill wait a request
+        c = gw.stats_counters
+        assert (c["queue_waits"], c["prefill_waits"]) == (2, 2)
+        assert 0 <= c["queue_wait_s"] and 0 < c["prefill_wait_s"]
+        waits = obs.registry().histogram(
+            "dl4j_request_wait_seconds", "", ("route", "stage"))
+        for stage in ("queue", "prefill"):
+            assert waits.summary(route=gw.route, stage=stage)["count"] == 2
 
 
 # ---------------------------------------------------------------------------
@@ -505,18 +700,6 @@ class TestTraceExport:
         assert trace_export.validate(doc) == []
         inst = [e for e in doc["traceEvents"] if e["ph"] == "i"]
         assert any(e["name"] == "marker" for e in inst)
-
-    def test_fit_trace_contains_phase_spans(self, monkeypatch):
-        from deeplearning4j_tpu.obs import trace_export
-
-        monkeypatch.setenv("DL4J_TPU_PHASE_SPANS", "1")
-        x, y = _toy_data()
-        MultiLayerNetwork(_mlp_conf()).init().fit((x, y), epochs=1)
-        doc = json.loads(trace_export.live_trace())
-        assert trace_export.validate(doc) == []
-        names = {e["name"] for e in doc["traceEvents"] if e["ph"] == "X"}
-        assert {"mln.fit_batch", "phase.fwd",
-                "phase.bwd", "phase.update"} <= names
 
 
 # ---------------------------------------------------------------------------

@@ -108,12 +108,12 @@ problems = trace_export.validate(live_doc)
 assert not problems, f"/debug/trace invalid: {problems}"
 print(f"/debug/trace OK: {len(live_doc['traceEvents'])} events")
 
-print("== phase 4: phase spans nest in an exported Perfetto trace ==")
-os.environ["DL4J_TPU_PHASE_SPANS"] = "1"
+print("== phase 4: fit's spans nest in an exported Perfetto trace ==")
+os.environ["DL4J_TPU_CHAIN_STEPS"] = "0"
 obs.reset()
-phased = MultiLayerNetwork(conf).init()
-phased.fit((x, y), epochs=1, batch_size=16)
-os.environ.pop("DL4J_TPU_PHASE_SPANS")
+nested = MultiLayerNetwork(conf).init()
+nested.fit((x, y), epochs=1, batch_size=16)
+os.environ.pop("DL4J_TPU_CHAIN_STEPS")
 dump = os.path.join(workdir, "spans.json")
 assert obs.save_spans(dump) > 0, "span dump is empty"
 with open(dump) as fh:
@@ -123,12 +123,13 @@ problems = trace_export.validate(doc)
 assert not problems, f"exported trace invalid: {problems}"
 slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
 names = {e["name"] for e in slices}
-for phase in ("phase.fwd", "phase.bwd", "phase.update"):
-    assert phase in names, f"missing {phase} in trace ({sorted(names)})"
-    recs = [e for e in slices if e["name"] == phase]
-    assert all(e["args"].get("parent") == "mln.fit_batch" for e in recs), \
-        f"{phase} spans not nested under mln.fit_batch"
-print(f"trace export OK: {len(slices)} slices, nested fwd/bwd/update present")
+for name, parent in (("mln.feed", "mln.iter"), ("mln.fit_batch", "mln.iter"),
+                     ("mln.step", "mln.fit_batch")):
+    assert name in names, f"missing {name} in trace ({sorted(names)})"
+    recs = [e for e in slices if e["name"] == name]
+    assert all(e["args"].get("parent") == parent for e in recs), \
+        f"{name} spans not nested under {parent}"
+print(f"trace export OK: {len(slices)} slices, iter > fit_batch > step nested")
 
 obs.configure_event_log(None)
 print("obs smoke OK")
