@@ -23,7 +23,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass, field
-from typing import Any, Dict, Optional
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -69,39 +69,6 @@ def _sharded_flash(flash, mesh, q, k, v, kmask):
         lambda q_, k_, v_, m_=None: flash(q_, k_, v_, kmask=m_),
         mesh=mesh, in_specs=in_specs, out_specs=spec,
         check_vma=False)(*args)
-
-
-_FLASH_BLOCKS: Dict[str, int] = {}
-
-
-def _flash_block(var: str, default: int) -> int:
-    """Validated value of a DL4J_TPU_FLASH_BLOCK_{Q,K} env knob, parsed ONCE
-    per process and cached. A non-integer or non-positive value raises a
-    ValueError naming the variable instead of an opaque int() traceback deep
-    inside a trace.
-
-    The cached value is baked into the kernel grid at the FIRST trace of the
-    flash path — changing the env var later in the process affects neither
-    already-compiled executables nor future traces (the cache pins the first
-    parse precisely so one process can never mix grids silently)."""
-    if var not in _FLASH_BLOCKS:
-        import os as _os
-
-        raw = _os.environ.get(var)
-        if raw is None:
-            _FLASH_BLOCKS[var] = default
-        else:
-            try:
-                val = int(raw)
-            except ValueError:
-                raise ValueError(
-                    f"{var} must be an integer block size (rows per flash "
-                    f"kernel tile), got {raw!r}")
-            if val <= 0:
-                raise ValueError(
-                    f"{var} must be a positive block size, got {raw!r}")
-            _FLASH_BLOCKS[var] = val
-    return _FLASH_BLOCKS[var]
 
 
 @register_layer("positional_embedding")
@@ -199,16 +166,13 @@ class MultiHeadAttention(LayerConfig):
                 # off-TPU (interpreter) the compiled XLA-remat backward is
                 # far faster than three interpreted Pallas kernels; kmask
                 # loads one [1, block_k] validity row per key block in-kernel.
-                # Block sizes are env-tunable for perf sweeps; validated and
-                # captured at first use (see _flash_block); 128/128 is the
-                # measured default.
+                # Each kernel picks its blocks from the shapes it is given
+                # (ops/flash_attention.py choose_blocks).
                 from deeplearning4j_tpu.parallel.context import (
                     partitioning_mesh)
 
                 flash = functools.partial(
                     flash_attention, causal=self.causal,
-                    block_q=_flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128),
-                    block_k=_flash_block("DL4J_TPU_FLASH_BLOCK_K", 128),
                     interpret=not on_tpu, bwd="pallas" if on_tpu else "xla")
                 mesh = partitioning_mesh()
                 if mesh is None:

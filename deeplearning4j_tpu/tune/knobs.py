@@ -143,16 +143,6 @@ KNOBS: Tuple[Knob, ...] = (
         help="lax.scan unroll factor for recurrent layers",
     ),
     Knob(
-        name="flash_block_q", env="DL4J_TPU_FLASH_BLOCK_Q", kind="int",
-        domain=(64, 128, 256), default=128, scope="both",
-        help="flash-attention query block size",
-    ),
-    Knob(
-        name="flash_block_k", env="DL4J_TPU_FLASH_BLOCK_K", kind="int",
-        domain=(64, 128, 256), default=128, scope="both",
-        help="flash-attention key/value block size",
-    ),
-    Knob(
         name="compress_threshold", env="DL4J_TPU_COMPRESS_THRESHOLD",
         kind="float", domain=(1e-4, 1e-3, 1e-2), default=1e-3, scope="fit",
         help="gradient-compression residual threshold (DP exchange)",

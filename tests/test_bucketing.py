@@ -348,26 +348,6 @@ class TestDevicePrefetch:
 
 
 class TestSatellites:
-    def test_flash_block_env_validation(self, monkeypatch):
-        from deeplearning4j_tpu.nn.layers import attention as att
-
-        monkeypatch.setattr(att, "_FLASH_BLOCKS", {})
-        assert att._flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128) == 128
-        monkeypatch.setattr(att, "_FLASH_BLOCKS", {})
-        monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "64")
-        assert att._flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128) == 64
-        # captured at first use: later env changes don't re-parse
-        monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "32")
-        assert att._flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128) == 64
-        monkeypatch.setattr(att, "_FLASH_BLOCKS", {})
-        monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "huge")
-        with pytest.raises(ValueError, match="DL4J_TPU_FLASH_BLOCK_Q"):
-            att._flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128)
-        monkeypatch.setattr(att, "_FLASH_BLOCKS", {})
-        monkeypatch.setenv("DL4J_TPU_FLASH_BLOCK_Q", "-8")
-        with pytest.raises(ValueError, match="positive"):
-            att._flash_block("DL4J_TPU_FLASH_BLOCK_Q", 128)
-
     def test_tbptt_slice_gating(self):
         from deeplearning4j_tpu.nn.graph import _tbptt_slice_t
 

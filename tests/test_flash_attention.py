@@ -3,6 +3,8 @@ equivalence against the XLA reference (the dual-path pattern of
 SURVEY.md §4), gradient parity through the custom VJP, and the layer-level
 "auto"/force policy."""
 
+import importlib
+
 import numpy as np
 import pytest
 
@@ -11,6 +13,9 @@ import jax.numpy as jnp
 
 from deeplearning4j_tpu.ops.flash_attention import (
     _reference, flash_attention)
+
+# the module itself (the package exports the function under the same name)
+fa = importlib.import_module("deeplearning4j_tpu.ops.flash_attention")
 
 
 def _qkv(rs, B, T, H, D, scale=0.5):
@@ -533,3 +538,291 @@ class TestUnderAMesh:
                             jax.tree_util.tree_leaves(ref)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            rtol=2e-4, atol=2e-4)
+
+
+class TestBlockChooser:
+    """Blocks come from the shapes (ops/flash_attention.py choose_blocks):
+    the tier-1 kernels run in the interpreter at explicit small blocks, so
+    the sizes the chip runs at are checked here from the estimate alone."""
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    @pytest.mark.parametrize("T,D,item,mask", [
+        (50, 64, 4, False),        # below one block: the whole length
+        (128, 64, 2, True),
+        (200, 128, 2, False),      # not a multiple of 128: padded to 256
+        (256, 64, 4, False),       # gpt2m-f32-train-b32-t256
+        (1000, 64, 4, True),
+        (1024, 64, 4, False),      # gpt2m-f32-train-b8-t1024
+        (2048, 128, 2, True),      # chip_smoke.py's kernels phase
+        (8192, 64, 2, False),      # docs/PERF.md's long-context envelope
+    ])
+    def test_blocks_divide_and_fit(self, kernel, T, D, item, mask):
+        bq, bk = fa.choose_blocks(kernel, T, T, D, item, mask)
+        t_pad = fa._padded(T)
+        assert t_pad >= T and (t_pad == T or t_pad % 128 == 0)
+        for b in (bq, bk):
+            assert t_pad % b == 0 and (b % 128 == 0 or b == T)
+        # nothing here is near _VMEM_MAX, so: the loop side is the largest
+        # divisor up to the cap, the grid side the whole sequence up to
+        # _MAX_WHOLE (one program a head, no loop) and the same divisor past it
+        cap = max(b for b in (t_pad, 128, 256, 384, 512)
+                  if t_pad % b == 0 and b <= fa._MAX_BLOCK)
+        grid, loop = (bq, bk) if kernel != "dkv" else (bk, bq)
+        assert loop == cap
+        assert grid == (t_pad if t_pad <= fa._MAX_WHOLE else cap)
+        need = fa._working_set(kernel, bq, bk, t_pad, t_pad, D, item, mask)
+        params = fa._compiler_params(kernel, bq, bk, t_pad, t_pad, D, item,
+                                     mask)
+        if need <= fa._VMEM_SHARE * fa._VMEM_DEFAULT:
+            assert params == {}
+        else:       # T = 8192: the whole-sequence operands want more VMEM
+            limit = params["compiler_params"].vmem_limit_bytes
+            assert T >= 1000 and fa._VMEM_DEFAULT <= limit <= fa._VMEM_MAX
+            assert need < limit
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    def test_blocks_shrink_only_past_the_vmem_ceiling(self, kernel):
+        """T = 32768 at D = 128 float32 still runs 512 x 512 under a raised
+        limit; at T = 65536 the whole-sequence operands alone pass what the
+        chip has, the smallest tiling comes back and the limit is the
+        ceiling (such a call belongs on the ring path)."""
+        D, item = 128, 4
+        assert fa.choose_blocks(kernel, 32768, 32768, D, item) == (512, 512)
+        need = fa._working_set(kernel, 512, 512, 32768, 32768, D, item, False)
+        limit = fa._compiler_params(kernel, 512, 512, 32768, 32768, D, item,
+                                    False)["compiler_params"].vmem_limit_bytes
+        assert need < limit <= fa._VMEM_MAX
+        T = 65536
+        assert fa.choose_blocks(kernel, T, T, D, item) == (128, 128)
+        assert fa._compiler_params(kernel, 128, 128, T, T, D, item, False)[
+            "compiler_params"].vmem_limit_bytes == fa._VMEM_MAX
+
+    def test_lane_padding_of_a_narrow_head_is_counted(self):
+        a = fa._working_set("fwd", 256, 256, 1024, 1024, 64, 4, False)
+        b = fa._working_set("fwd", 256, 256, 1024, 1024, 128, 4, False)
+        assert a == b           # D = 64 occupies 128 lanes all the same
+
+    @pytest.mark.parametrize("same_len", [False, True])
+    def test_explicit_blocks_win(self, same_len):
+        blocks, q_pad, k_pad = fa._plan(("dq", "dkv"), 1000, 600, 64, 4,
+                                        False, 32, 16, same_len)
+        assert blocks == {"dq": (32, 16), "dkv": (32, 16)}
+        assert q_pad % 32 == 0 and k_pad % 16 == 0 and q_pad >= 1000
+        # short sequences still clamp to T
+        blocks, q_pad, k_pad = fa._plan(("fwd",), 20, 20, 64, 4, False,
+                                        128, 128, same_len)
+        assert blocks == {"fwd": (20, 20)} and (q_pad, k_pad) == (20, 20)
+        with pytest.raises(ValueError, match="both"):
+            fa._plan(("fwd",), 256, 256, 64, 4, False, 128, None)
+
+
+class TestTileSchedule:
+    """The split loops: tiles wholly on the valid side of the diagonal run
+    a body without iota/compare/where, the diagonal's and the padded tail's
+    run the masked one. Forward, dq and dk/dv against the dense reference
+    and the XLA-remat oracle, at every shape of tiling the ranges have a
+    case for."""
+
+    CASES = [
+        pytest.param(64, 16, 16, False, id="bq=bk"),
+        pytest.param(64, 32, 16, False, id="bq=2bk"),
+        pytest.param(64, 16, 32, False, id="bk=2bq"),
+        pytest.param(50, 16, 16, False, id="padded-tail"),
+        pytest.param(50, 32, 16, False, id="padded-bq=2bk"),
+        pytest.param(50, 16, 32, False, id="padded-bk=2bq"),
+        pytest.param(64, 32, 16, True, id="kmask-bq=2bk"),
+        pytest.param(50, 16, 32, True, id="kmask-padded-bk=2bq"),
+    ]
+
+    @pytest.mark.parametrize("causal", [True, False],
+                             ids=["causal", "full"])
+    @pytest.mark.parametrize("T,bq,bk,masked", CASES)
+    def test_fwd_dq_dkv_match_reference_and_xla(self, T, bq, bk, masked,
+                                                causal):
+        rs = np.random.RandomState(T + bq + 2 * bk)
+        B, H, D = 2, 2, 16
+        q, k, v = _qkv(rs, B, T, H, D)
+        km = TestKmask._mask(rs, B, T) if masked else None
+        w = 1.0 if km is None else jnp.asarray(
+            np.asarray(km)[:, :, None, None])
+
+        def val_and_grads(fn):
+            return jax.value_and_grad(
+                lambda q, k, v: jnp.sum((fn(q, k, v) * w) ** 2),
+                argnums=(0, 1, 2))(q, k, v)
+
+        def flash(bwd):
+            return lambda q, k, v: flash_attention(
+                q, k, v, kmask=km, causal=causal, block_q=bq, block_k=bk,
+                interpret=True, bwd=bwd)
+
+        lp, gp = val_and_grads(flash("pallas"))
+        lx, gx = val_and_grads(flash("xla"))
+        lr, gr = val_and_grads(
+            lambda q, k, v: _reference(q, k, v, causal, kmask=km))
+        np.testing.assert_allclose(float(lp), float(lr), rtol=1e-5)
+        np.testing.assert_allclose(float(lp), float(lx), rtol=1e-6)
+        for a, b, c in zip(gp, gx, gr):
+            assert np.all(np.isfinite(np.asarray(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16), (16, 32)])
+    def test_unequal_offsets_mask_every_tile(self, bq, bk):
+        """q_offset != k_offset (ring and chunked blocks): no tile may take
+        the plain body, whatever the blocks; the second q shard against
+        both key chunks equals its rows of the full causal attention, in
+        value and in all three gradients."""
+        from deeplearning4j_tpu.ops.flash_attention import (
+            flash_attention_block_grad, merge_attention_blocks)
+
+        rs = np.random.RandomState(3)
+        B, T, H, D = 1, 64, 2, 16
+        q, k, v = _qkv(rs, B, T, H, D)
+        half = T // 2
+
+        def loss_chunks(q, k, v):
+            parts = [flash_attention_block_grad(
+                q[:, half:], k[:, ko:ko + half], v[:, ko:ko + half],
+                q_offset=half, k_offset=ko, causal=True, block_q=bq,
+                block_k=bk, interpret=True) for ko in (0, half)]
+            return jnp.sum(merge_attention_blocks(parts) ** 2)
+
+        def loss_full(q, k, v):
+            return jnp.sum(_reference(q, k, v, True)[:, half:] ** 2)
+
+        lc, gc = jax.value_and_grad(loss_chunks, argnums=(0, 1, 2))(q, k, v)
+        lf, gf = jax.value_and_grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+        np.testing.assert_allclose(float(lc), float(lf), rtol=1e-5)
+        for a, b in zip(gc, gf):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=5e-4)
+
+    @staticmethod
+    def _kinds(ranges, n_tiles):
+        """tile index -> the body's flags, for every tile the ranges visit
+        (static counts and bounds only: the cases below keep them so)."""
+        seen = {}
+        for lo, count, kw in ranges:
+            for i in range(int(lo), int(lo) + int(count)):
+                assert i not in seen and 0 <= i < n_tiles
+                seen[i] = kw
+        return seen
+
+    @pytest.mark.parametrize("bq,bk,t_real,t_pad", [
+        (16, 16, 64, 64), (32, 16, 64, 64), (16, 32, 64, 64),
+        (64, 64, 64, 64),                  # one tile: no loop at all
+        (16, 16, 50, 64), (32, 16, 50, 64), (16, 32, 50, 64),
+        (48, 32, 96, 96),                  # neither block divides the other
+    ])
+    @pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+    def test_ranges_against_the_dense_mask(self, bq, bk, t_real, t_pad,
+                                           causal):
+        """Every (q-block, k-block) pair against the dense validity mask,
+        for the key loop (forward, dq) and the query loop (dk/dv): a tile
+        that is not visited holds no valid entry, a tile that takes the
+        plain body holds no invalid one, and when one block divides the
+        other and nothing is padded the diagonal's tiles are a static
+        max(1, bq/bk) (straight-line code, not a second loop)."""
+        rows = np.arange(t_pad)[:, None]
+        cols = np.arange(t_pad)[None, :]
+        ok = (cols < t_real) & (rows < t_real)
+        if causal:
+            ok &= cols <= rows
+        n_q, n_k = t_pad // bq, t_pad // bk
+        split = causal and fa._nested(bq, bk) and t_real == t_pad and n_k > 1
+        for qi in range(n_q):
+            ranges = fa._key_ranges(qi, bq, bk, t_pad, t_pad, t_real, causal,
+                                    True)
+            if split:
+                assert ranges[-1][1] == max(1, bq // bk)
+                assert isinstance(ranges[-1][1], int)
+            kinds = self._kinds(ranges, n_k)
+            for kb in range(n_k):
+                # padded q rows are the caller's to slice off: judge the
+                # key loop on the real rows of the block
+                tile = ok[qi * bq:(qi + 1) * bq, kb * bk:(kb + 1) * bk]
+                real = tile[:max(0, min(bq, t_real - qi * bq))]
+                if kb not in kinds:
+                    assert not tile.any()
+                elif not kinds[kb]["masked"]:
+                    assert real.all()
+        for ki in range(n_k):
+            ranges = fa._query_ranges(ki, bq, bk, t_pad, t_pad, t_real,
+                                      causal, True)
+            if split and n_q > 1:
+                assert ranges[0][1] == max(1, bk // bq)
+            kinds = self._kinds(ranges, n_q)
+            for qb in range(n_q):
+                tile = ok[qb * bq:(qb + 1) * bq, ki * bk:(ki + 1) * bk]
+                real = tile[:, :max(0, min(bk, t_real - ki * bk))]
+                if qb not in kinds:
+                    assert not tile.any()
+                    continue
+                if not kinds[qb]["diag"] and causal:
+                    rr, cc = np.meshgrid(np.arange(qb * bq, (qb + 1) * bq),
+                                         np.arange(ki * bk, (ki + 1) * bk),
+                                         indexing="ij")
+                    assert (cc <= rr).all()
+                if not kinds[qb]["tail"]:
+                    assert (qb + 1) * bq <= t_real
+
+    def test_unequal_offsets_mask_every_tile_ranges(self):
+        assert fa._key_ranges(1, 16, 16, 64, 64, 64, True, False) == [
+            (0, 4, {"masked": True})]
+        assert fa._query_ranges(1, 16, 16, 64, 64, 64, True, False) == [
+            (0, 4, {"diag": True, "tail": False})]
+
+
+class TestKernelNamesAndResults:
+    """benchmark/metrics/flash_{fwd,bwd}_roofline.json find the kernels by
+    the END of the Mosaic call's name, which is its result types: forward
+    = (x[BH,t_pad,D], f32[BH,1,t_pad]); dk/dv = two equal results; dq = one
+    three-dimensional result. The names carry the blocks that ran. A change
+    that would silence a roofline fails here first."""
+
+    @staticmethod
+    def _pallas_eqns(jaxpr, out):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                out.append(e)
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns") and e.primitive.name != "pallas_call":
+                        TestKernelNamesAndResults._pallas_eqns(inner, out)
+        return out
+
+    @pytest.mark.parametrize("T,bq,bk,t_pad", [(64, 32, 16, 64),
+                                               (50, 16, 32, 64)])
+    def test_three_names_with_blocks_and_result_types(self, T, bq, bk, t_pad):
+        B, H, D = 2, 3, 8
+        q = jnp.ones((B, T, H, D), jnp.float32)
+        jp = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True)),
+            argnums=(0, 1, 2)))(q, q, q)
+        got = {e.params["name"]: [(tuple(v.aval.shape), str(v.aval.dtype))
+                                  for v in e.outvars]
+               for e in self._pallas_eqns(jp.jaxpr, [])}
+        BH, x = B * H, "float32"
+        assert got == {
+            f"flash_fwd_q{bq}_k{bk}": [((BH, t_pad, D), x),
+                                       ((BH, 1, t_pad), "float32")],
+            f"flash_bwd_dq_q{bq}_k{bk}": [((BH, t_pad, D), x)],
+            f"flash_bwd_dkv_q{bq}_k{bk}": [((BH, t_pad, D), x)] * 2,
+        }
+
+    def test_chosen_blocks_are_in_the_names(self):
+        """No blocks given: each kernel's name carries its own choice (the
+        trace is where a run says which tiling it took)."""
+        B, T, H, D = 1, 256, 1, 8
+        q = jnp.ones((B, T, H, D), jnp.bfloat16)
+        jp = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, causal=True, interpret=True).astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, q, q)
+        names = {e.params["name"] for e in self._pallas_eqns(jp.jaxpr, [])}
+        want = {f"{fa._NAMES[kn]}_q%d_k%d" % fa.choose_blocks(kn, T, T, D, 2)
+                for kn in ("fwd", "dq", "dkv")}
+        assert names == want

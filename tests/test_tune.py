@@ -36,6 +36,10 @@ from deeplearning4j_tpu.tune import trial as tune_trial
 
 @pytest.fixture(autouse=True)
 def _clean_env(monkeypatch):
+    # tune.maybe_apply writes its winners into os.environ for good, behind
+    # monkeypatch's back: put the environment back as it was, or the next
+    # test file in this worker trains with DL4J_TPU_GRAD_ACCUM=4
+    before = dict(os.environ)
     for k in tune_knobs.KNOBS:
         monkeypatch.delenv(k.env, raising=False)
     monkeypatch.delenv("DL4J_TPU_TUNE", raising=False)
@@ -43,6 +47,9 @@ def _clean_env(monkeypatch):
     # parity must compare the same dispatch shape; chaining is its own knob
     monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
     yield
+    for k in set(os.environ) - set(before):
+        del os.environ[k]
+    os.environ.update(before)
 
 
 _TC = {"jax_version": "0.9", "jaxlib_version": "0.9", "backend": "cpu"}
@@ -109,8 +116,7 @@ class TestKnobRegistry:
     def test_registry_covers_the_issue_knob_space(self):
         names = {k.name for k in tune_knobs.KNOBS}
         assert {"bucket_min", "bucket_growth", "chain_steps", "rnn_unroll",
-                "flash_block_q", "flash_block_k", "compress_threshold",
-                "grad_accum"} <= names
+                "compress_threshold", "grad_accum"} <= names
 
     def test_validate_rejects_out_of_domain(self):
         k = tune_knobs.get("grad_accum")
@@ -121,7 +127,7 @@ class TestKnobRegistry:
         fit = {k.name for k in tune_knobs.all_knobs("fit")}
         serve = {k.name for k in tune_knobs.all_knobs("serve")}
         assert "grad_accum" in fit and "grad_accum" not in serve
-        assert "flash_block_q" in fit and "flash_block_q" in serve
+        assert "rnn_unroll" in fit and "rnn_unroll" in serve
 
 
 # ---------------------------------------------------------------------------
@@ -322,10 +328,10 @@ class TestMaybeApply:
         model = _mln()
         monkeypatch.setenv("DL4J_TPU_TUNE_DB", str(tmp_path / "tunedb.zip"))
         monkeypatch.setenv("DL4J_TPU_TUNE", "auto")
-        self._seed_db(tmp_path, model, {"grad_accum": 4, "flash_block_q": 64})
+        self._seed_db(tmp_path, model, {"grad_accum": 4, "rnn_unroll": 4})
         applied = tune.maybe_apply(model, "serve")
         # grad_accum is fit-scoped; only the both-scoped knob lands
-        assert applied == {"DL4J_TPU_FLASH_BLOCK_Q": "64"}
+        assert applied == {"DL4J_TPU_RNN_UNROLL": "4"}
 
     def test_fit_consults_db_under_auto(self, tmp_path, monkeypatch):
         model = _mln()

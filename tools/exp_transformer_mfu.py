@@ -2,15 +2,15 @@
 
 Three measurements on the bench config (d2048, T2048, B16, 8 blocks):
 
-1. Flash block-size sweep (64/128/256 q x k combos) of the FULL train
-   step — DL4J_TPU_FLASH_BLOCK_{Q,K} env knobs, fresh trace per combo.
+1. The FULL train step as it stands (the flash kernels pick their blocks
+   from the shapes; tools/flash_tile_sweep.py sweeps them per kernel).
 2. Op-mix attribution: jit + cost-analyze the pieces at bench shapes
    (layernorm, residual add, attention core, MLP, adam update) to bound
    which HBM traffic explains the d512-config MFU 0.112 claim.
 3. A remat variant: jax.checkpoint around each TransformerBlock apply,
    measuring whether activation-memory relief buys scheduler headroom.
 
-Usage:  python tools/exp_transformer_mfu.py [sweep|opmix|remat]
+Usage:  python tools/exp_transformer_mfu.py [step|opmix|remat]
 (each mode is one process: a chip belongs to one process at a time).
 """
 
@@ -21,11 +21,7 @@ import time
 import numpy as np
 
 
-def _setup(block_q=None, block_k=None):
-    if block_q:
-        os.environ["DL4J_TPU_FLASH_BLOCK_Q"] = str(block_q)
-    if block_k:
-        os.environ["DL4J_TPU_FLASH_BLOCK_K"] = str(block_k)
+def _setup():
     import jax
     import jax.numpy as jnp
 
@@ -78,17 +74,13 @@ def _mfu(site, key, compiled, dt):
     return entry.get("flops", 0.0) / dt / peak
 
 
-def sweep():
-    combos = [(128, 128), (64, 128), (128, 64), (256, 128), (128, 256),
-              (256, 256), (64, 64)]
-    bq, bk = combos[int(sys.argv[2])] if len(sys.argv) > 2 else combos[0]
-    jax, jnp, model, x, y, cfg = _setup(bq, bk)
+def step():
+    jax, jnp, model, x, y, cfg = _setup()
     _, T, d, _, _, B = cfg
     dt, compiled = _time_step(jax, jnp, model, x, y)
-    tps = B * T / dt
-    mfu = _mfu("exp.transformer", f"bq{bq}bk{bk}", compiled, dt)
-    print(f"RESULT block_q={bq} block_k={bk}: {dt*1000:.1f} ms/step "
-          f"{tps:,.0f} tok/s MFU={mfu:.3f}", flush=True)
+    mfu = _mfu("exp.transformer", "step", compiled, dt)
+    print(f"RESULT step: {dt*1000:.1f} ms/step {B*T/dt:,.0f} tok/s "
+          f"MFU={mfu:.3f}", flush=True)
 
 
 def opmix():
@@ -140,5 +132,5 @@ def remat():
 
 
 if __name__ == "__main__":
-    {"sweep": sweep, "opmix": opmix, "remat": remat}[
-        sys.argv[1] if len(sys.argv) > 1 else "sweep"]()
+    {"step": step, "opmix": opmix, "remat": remat}[
+        sys.argv[1] if len(sys.argv) > 1 else "step"]()
