@@ -138,3 +138,45 @@ def TransformerLM(vocab_size: int = 256, max_len: int = 512, d_model: int = 256,
         seed=seed,
         dtype=dtype,
     )
+
+
+def HybridLM(pattern: str, vocab_size: int, d_model: int, max_len: int = 4096,
+             mamba: dict = None, attention: dict = None, moe: dict = None,
+             eps: float = 1e-5, remat: bool = False, updater=None,
+             seed: int = 12345, dtype: str = "float32") -> MultiLayerConfiguration:
+    """A hybrid state-space / attention / sparse-expert language model of
+    the ``nemotron_h`` kind: an embedding, one ``ResidualBlock`` per letter
+    of ``pattern`` (``x <- x + mixer(RMSNorm(x))``), a final RMSNorm and an
+    untied, bias-free head. ``M`` is a ``Mamba2Mixer`` built from ``mamba``,
+    ``*`` a ``GroupedQueryAttention`` from ``attention`` (no positional
+    encoding: the state-space layers carry position), ``E`` a ``SparseMoE``
+    from ``moe`` (which says which experts this model holds). ``remat``
+    recomputes each layer in the backward pass."""
+    from deeplearning4j_tpu.nn.layers import (
+        EmbeddingSequence,
+        GroupedQueryAttention,
+        Mamba2Mixer,
+        ResidualBlock,
+        RMSNorm,
+        SparseMoE,
+    )
+
+    mixers = {"M": lambda: Mamba2Mixer(eps=eps, **(mamba or {})),
+              "*": lambda: GroupedQueryAttention(**(attention or {})),
+              "E": lambda: SparseMoE(**(moe or {}))}
+    unknown = set(pattern) - set(mixers)
+    if unknown or not pattern:
+        raise ValueError(f"pattern {pattern!r}: letters are M, * and E")
+    layers = [EmbeddingSequence(n_in=vocab_size, n_out=d_model)]
+    layers += [ResidualBlock(mixer=mixers[c](), eps=eps, remat=remat)
+               for c in pattern]
+    layers += [RMSNorm(eps=eps),
+               RnnOutputLayer(n_out=vocab_size, activation="softmax",
+                              loss="mcxent", has_bias=False)]
+    return MultiLayerConfiguration(
+        layers=tuple(layers),
+        input_type=InputType.recurrent(vocab_size, max_len),
+        updater=updater or {"type": "adam", "lr": 3e-4},
+        seed=seed,
+        dtype=dtype,
+    )

@@ -41,13 +41,16 @@ from deeplearning4j_tpu.nn.layers.convolution import (
     ZeroPadding1D,
     ZeroPadding2D,
 )
-from deeplearning4j_tpu.nn.layers.normalization import BatchNorm, LayerNorm, LocalResponseNormalization
+from deeplearning4j_tpu.nn.layers.normalization import BatchNorm, LayerNorm, LocalResponseNormalization, RMSNorm
 from deeplearning4j_tpu.nn.layers.attention import (
+    GroupedQueryAttention,
     MultiHeadAttention,
     PositionalEmbedding,
     TransformerBlock,
 )
-from deeplearning4j_tpu.nn.layers.moe import MixtureOfExperts
+from deeplearning4j_tpu.nn.layers.moe import MixtureOfExperts, SparseMoE
+from deeplearning4j_tpu.nn.layers.residual import ResidualBlock
+from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer
 from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.objdetect import (
     Yolo2OutputLayer,
@@ -105,6 +108,11 @@ __all__ = [
     "PositionalEmbedding",
     "TransformerBlock",
     "MixtureOfExperts",
+    "SparseMoE",
+    "ResidualBlock",
+    "Mamba2Mixer",
+    "GroupedQueryAttention",
+    "RMSNorm",
     "VariationalAutoencoder",
     "Yolo2OutputLayer",
     "get_predicted_objects",

@@ -308,3 +308,53 @@ class TransformerBlock(LayerConfig):
             h = self._ln(params["ln2"], x)
             h = self.activation_fn()(h @ params["Wi"] + params["bi"])
             return x + (h @ params["Wo"] + params["bo"])
+
+
+@register_layer("grouped_query_attention")
+@dataclass
+class GroupedQueryAttention(LayerConfig):
+    """Causal self-attention over [B, T, C] with ``n_heads`` query heads of
+    ``head_dim`` that share ``n_kv_heads`` key/value heads (each serves
+    ``n_heads / n_kv_heads`` query heads), bias-free projections and a head
+    width of its own (``n_heads * head_dim`` need not be ``C``). No
+    positional encoding is applied here. The attention core is
+    ``MultiHeadAttention``'s (flash kernels on the TPU, the XLA path
+    elsewhere); keys and values are repeated to the query heads for it, and
+    autodiff sums their gradients back over the repeat."""
+
+    n_heads: int = 8
+    n_kv_heads: int = 1
+    head_dim: int = 64
+    causal: bool = True
+    weight_init: Any = "xavier"
+    use_flash: Any = "auto"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        C = input_type.size
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"n_kv_heads={self.n_kv_heads} must divide "
+                             f"n_heads={self.n_heads}")
+        q, kv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        kq, kk, kv_, ko = jax.random.split(key, 4)
+        init = lambda k, fi, fo: initializers.initialize(   # noqa: E731
+            self.weight_init, k, (fi, fo), fi, fo, dtype)
+        return {"Wq": init(kq, C, q), "Wk": init(kk, C, kv),
+                "Wv": init(kv_, C, kv), "Wo": init(ko, q, C)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = self.maybe_dropout_input(x, train, rng)
+        B, T, _ = x.shape
+        H, Hkv, D = self.n_heads, self.n_kv_heads, self.head_dim
+        with jax.named_scope("attn"):
+            q = (x @ params["Wq"]).reshape(B, T, H, D)
+            k = (x @ params["Wk"]).reshape(B, T, Hkv, D)
+            v = (x @ params["Wv"]).reshape(B, T, Hkv, D)
+            k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
+            kmask = mask.reshape(B, T) if mask is not None and mask.ndim >= 2 else None
+            core = MultiHeadAttention(n_heads=H, causal=self.causal,
+                                      use_flash=self.use_flash)
+            out = core._attend(q, k, v, kmask)               # [B, T, H, D]
+            return out.reshape(B, T, H * D) @ params["Wo"], state

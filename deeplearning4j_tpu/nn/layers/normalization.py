@@ -183,3 +183,28 @@ class LayerNorm(LayerConfig):
         g = params.get("gamma") if params else None
         b = params.get("beta") if params else None
         return layer_norm(x, g, b, self.eps), state
+
+
+def rms_norm(x, gamma=None, eps: float = 1e-5):
+    """Functional root-mean-square norm over the last axis:
+    ``x * rsqrt(mean(x^2) + eps) * gamma`` (no mean subtracted, no bias).
+    Statistics in f32 for bf16 inputs, result cast back to the input dtype."""
+    dt = x.dtype
+    xs = x.astype(jnp.float32) if dt == jnp.bfloat16 else x
+    y = xs * lax.rsqrt(jnp.mean(xs * xs, axis=-1, keepdims=True) + eps)
+    y = y.astype(dt)
+    return y if gamma is None else y * gamma
+
+
+@register_layer("rms_norm")
+@dataclass
+class RMSNorm(LayerConfig):
+    """RMS normalization over the last (feature) axis, gain only."""
+
+    eps: float = 1e-5
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        return {"gamma": jnp.ones((input_type.size,), dtype)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        return rms_norm(x, params["gamma"], self.eps), state

@@ -798,7 +798,7 @@ class MultiLayerNetwork:
                         # n_real came from the pre-padding host side of the stream
                         if self.listeners:
                             with obs.span("mln.loss_fetch"):
-                                score = float(score)  # graftlint: disable=host-sync
+                                score = self._fetch_score(score)
                             resilience.note_score(score)
                             with obs.span("mln.listeners"):
                                 for l in self.listeners:
@@ -815,6 +815,21 @@ class MultiLayerNetwork:
             # open jax.profiler trace
             close_listeners(self.listeners)
         return self
+
+    def _fetch_score(self, score) -> float:
+        """The step's loss on the host and, in the same fetch, the step
+        counters of the layers that keep some in their state under
+        ``"stats"`` (an expert layer's load), handed to
+        ``layer.publish_stats``: no second wait for the device."""
+        idx = [i for i, s in enumerate(self.state)
+               if isinstance(s, dict) and "stats" in s]
+        if not idx:
+            return float(score)  # graftlint: disable=host-sync
+        score, stats = jax.device_get(  # graftlint: disable=host-sync
+            (score, [self.state[i]["stats"] for i in idx]))
+        for i, st in zip(idx, stats):
+            self.layers[i].publish_stats(i, st)
+        return float(score)
 
     def _fit_batch(self, x, y, fm, lm, ew=None):
         """One step. Returns the loss as a DEVICE scalar — callers decide
