@@ -370,7 +370,9 @@ def _lstm_checks(ctx, B, T, H, dtype, tol) -> None:
                   jax.jit(compare), (zx, wh, h0, c0, peep, w, mask), tol)
 
 
-def phase_kernels(ctx, *, flash=((16, 2048, 16, 128, jnp.bfloat16, 2),),
+def phase_kernels(ctx, *, flash=((16, 2048, 16, 128, jnp.bfloat16, 2),
+                              # two heads of 64 in one 128-lane block
+                              (8, 1024, 16, 64, jnp.float32, 2)),
                   ring=((4, 2048, 16, 128, jnp.bfloat16),),
                   lstm=((128, 50, 256, jnp.float32, 1e-2),
                         (512, 50, 1024, jnp.bfloat16, 4e-2))) -> None:

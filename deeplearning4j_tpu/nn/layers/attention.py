@@ -136,6 +136,27 @@ class MultiHeadAttention(LayerConfig):
             "bo": jnp.zeros((C,), dtype),
         }
 
+    def _flash(self):
+        """``(flash_attention's settings, active mesh)`` where this call
+        takes the flash kernels, else None: "auto" takes them on the TPU,
+        True anywhere (the Pallas interpreter off it)."""
+        if self.use_flash not in ("auto", True):
+            return None
+        if self.sequence_parallel and _mesh_has_axis("seq"):
+            return None
+        on_tpu = jax.default_backend() == "tpu"
+        if not (self.use_flash is True or on_tpu):
+            return None
+        from deeplearning4j_tpu.parallel.context import partitioning_mesh
+
+        # off-TPU (interpreter) the compiled XLA-remat backward is far
+        # faster than three interpreted Pallas kernels; kmask loads one
+        # [1, block_k] validity row per key block in-kernel. Each kernel
+        # picks its blocks from the shapes it is given
+        # (ops/flash_attention.py choose_blocks).
+        return (dict(causal=self.causal, interpret=not on_tpu,
+                     bwd="pallas" if on_tpu else "xla"), partitioning_mesh())
+
     def _attend(self, q, k, v, kmask=None):
         from deeplearning4j_tpu.parallel.ring import local_attention, ring_self_attention
 
@@ -158,26 +179,14 @@ class MultiHeadAttention(LayerConfig):
                 q, k, v, mesh, causal=self.causal, kmask=kmask,
                 head_axis=head_axis, use_flash=ring_flash
             )
-        if self.use_flash in ("auto", True):
+        settings, mesh = self._flash() or (None, None)
+        if settings is not None:
             from deeplearning4j_tpu.ops.flash_attention import flash_attention
 
-            on_tpu = jax.default_backend() == "tpu"
-            if self.use_flash is True or on_tpu:
-                # off-TPU (interpreter) the compiled XLA-remat backward is
-                # far faster than three interpreted Pallas kernels; kmask
-                # loads one [1, block_k] validity row per key block in-kernel.
-                # Each kernel picks its blocks from the shapes it is given
-                # (ops/flash_attention.py choose_blocks).
-                from deeplearning4j_tpu.parallel.context import (
-                    partitioning_mesh)
-
-                flash = functools.partial(
-                    flash_attention, causal=self.causal,
-                    interpret=not on_tpu, bwd="pallas" if on_tpu else "xla")
-                mesh = partitioning_mesh()
-                if mesh is None:
-                    return flash(q, k, v, kmask=kmask)
-                return _sharded_flash(flash, mesh, q, k, v, kmask)
+            flash = functools.partial(flash_attention, **settings)
+            if mesh is None:
+                return flash(q, k, v, kmask=kmask)
+            return _sharded_flash(flash, mesh, q, k, v, kmask)
         return local_attention(q, k, v, causal=self.causal, kmask=kmask)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
@@ -186,12 +195,19 @@ class MultiHeadAttention(LayerConfig):
         B, T, C = x.shape
         H = self.n_heads
         qkv = x @ params["Wqkv"] + params["bqkv"]
-        q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, C // H), 3, axis=2)
         kmask = None
         if mask is not None and mask.ndim >= 2:
             kmask = mask.reshape(B, T)  # [B,T] key validity from feature mask
-        out = self._attend(q, k, v, kmask)  # [B,T,H,D]
-        out = out.reshape(B, T, C)
+        settings, mesh = self._flash() or (None, None)
+        if settings is not None and mesh is None:
+            # one chip: the kernels read q, k and v out of the projection
+            # where it lies and write [B, T, C]; nothing is split or moved
+            from deeplearning4j_tpu.ops.flash_attention import flash_attention_qkv
+
+            out = flash_attention_qkv(qkv, H, kmask=kmask, **settings)
+        else:
+            q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, C // H), 3, axis=2)
+            out = self._attend(q, k, v, kmask).reshape(B, T, C)
         if train and self.attn_dropout > 0.0 and rng_attn is not None:
             keep = 1.0 - self.attn_dropout
             out = jnp.where(jax.random.bernoulli(rng_attn, keep, out.shape), out / keep, 0.0)

@@ -25,7 +25,10 @@ def _qkv(rs, B, T, H, D, scale=0.5):
 
 class TestKernelEquivalence:
     @pytest.mark.parametrize("shape", [(2, 16, 2, 8), (1, 64, 4, 16),
-                                       (2, 50, 3, 32), (1, 130, 2, 64)])
+                                       (2, 50, 3, 32), (1, 130, 2, 64),
+                                       # heads paired up in 128-lane blocks
+                                       (1, 50, 4, 32), (2, 40, 4, 64),
+                                       (1, 40, 3, 128)])
     @pytest.mark.parametrize("causal", [False, True])
     def test_matches_xla_reference(self, shape, causal):
         rs = np.random.RandomState(0)
@@ -65,7 +68,9 @@ class TestPallasBackward:
     vs autodiff of the dense reference."""
 
     @pytest.mark.parametrize("shape", [(2, 16, 2, 8), (1, 64, 4, 16),
-                                       (2, 50, 3, 32), (1, 130, 2, 64)])
+                                       (2, 50, 3, 32), (1, 130, 2, 64),
+                                       (1, 50, 4, 32), (2, 40, 4, 64),
+                                       (1, 40, 3, 128)])
     @pytest.mark.parametrize("causal", [False, True])
     def test_pallas_bwd_matches_xla_bwd(self, shape, causal):
         rs = np.random.RandomState(7)
@@ -146,17 +151,47 @@ class TestLayerPolicy:
         from deeplearning4j_tpu.nn.input_type import InputType
         from deeplearning4j_tpu.nn.layers import MultiHeadAttention
 
+        C = x.shape[-1]
         mha = MultiHeadAttention(n_heads=2, causal=True, use_flash=use_flash)
-        params = mha.init(jax.random.PRNGKey(0), InputType.recurrent(16, 12))
+        params = mha.init(jax.random.PRNGKey(0), InputType.recurrent(C, 12))
         y, _ = mha.apply(params, {}, x, mask=mask)
         return np.asarray(y)
 
-    def test_forced_flash_equals_xla_path(self):
+    # C = 16: heads of 8, transposed around the kernels; C = 128: heads of
+    # 64, the kernels read the fused projection in place
+    @pytest.mark.parametrize("C", [16, 128])
+    def test_forced_flash_equals_xla_path(self, C):
         rs = np.random.RandomState(3)
-        x = jnp.asarray(rs.randn(2, 12, 16).astype(np.float32))
+        x = jnp.asarray(rs.randn(2, 12, C).astype(np.float32))
         np.testing.assert_allclose(
             self._layer_out(True, x), self._layer_out(False, x),
             rtol=1e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kmask"])
+    def test_fused_projection_gradients_equal_xla_path(self, masked):
+        """The layer's parameter and input gradients through the fused
+        entry point (heads of 64: two a lane block) against the XLA path."""
+        from deeplearning4j_tpu.nn.input_type import InputType
+        from deeplearning4j_tpu.nn.layers import MultiHeadAttention
+
+        rs = np.random.RandomState(6)
+        B, T, C = 2, 24, 256
+        x = jnp.asarray(rs.randn(B, T, C).astype(np.float32))
+        mask = jnp.asarray((np.arange(T)[None] < np.array([[24], [15]]))
+                           .astype(np.float32)) if masked else None
+        grads = []
+        for use_flash in (True, False):
+            mha = MultiHeadAttention(n_heads=4, causal=True,
+                                     use_flash=use_flash)
+            params = mha.init(jax.random.PRNGKey(0),
+                              InputType.recurrent(C, T))
+            grads.append(jax.grad(
+                lambda p, x: jnp.sum(mha.apply(p, {}, x, mask=mask)[0] ** 2),
+                argnums=(0, 1))(params, x))
+        for a, b in zip(jax.tree_util.tree_leaves(grads[0]),
+                        jax.tree_util.tree_leaves(grads[1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
 
     def test_auto_on_cpu_uses_xla_path(self):
         # same numbers (it IS the XLA path on CPU) — and no interpreter cost
@@ -505,16 +540,22 @@ class TestUnderAMesh:
     shard_map over (data, model) — nn/layers/attention.py _sharded_flash.
     Values and gradients must not depend on the mesh shape."""
 
+    # heads of 16 are transposed around the kernels on every shard; heads of
+    # 64 pair up where a shard holds an even count (4 heads, or 2 under
+    # model=2) and are transposed where it holds 3 (6 heads under model=2):
+    # the addressing follows the LOCAL head count
+    @pytest.mark.parametrize("H,C", [(4, 64), (4, 256), (6, 384)],
+                             ids=["d16", "d64", "d64-odd-local-heads"])
     @pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kmask"])
-    def test_sharded_kernel_matches_unsharded(self, masked):
+    def test_sharded_kernel_matches_unsharded(self, masked, H, C):
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from deeplearning4j_tpu.nn.input_type import InputType
         from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
         from deeplearning4j_tpu.parallel import MeshSpec, make_mesh, use_mesh
 
-        B, T, C = 4, 32, 64
-        layer = MultiHeadAttention(n_heads=4, causal=True, use_flash=True)
+        B, T = 4, 32
+        layer = MultiHeadAttention(n_heads=H, causal=True, use_flash=True)
         params = layer.init(jax.random.PRNGKey(0),
                             InputType.recurrent(C, T), jnp.float32)
         x = jax.random.normal(jax.random.PRNGKey(1), (B, T, C))
@@ -546,18 +587,24 @@ class TestBlockChooser:
     the sizes the chip runs at are checked here from the estimate alone."""
 
     @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
-    @pytest.mark.parametrize("T,D,item,mask", [
-        (50, 64, 4, False),        # below one block: the whole length
-        (128, 64, 2, True),
-        (200, 128, 2, False),      # not a multiple of 128: padded to 256
-        (256, 64, 4, False),       # gpt2m-f32-train-b32-t256
-        (1000, 64, 4, True),
-        (1024, 64, 4, False),      # gpt2m-f32-train-b8-t1024
-        (2048, 128, 2, True),      # chip_smoke.py's kernels phase
-        (8192, 64, 2, False),      # docs/PERF.md's long-context envelope
+    @pytest.mark.parametrize("T,D,item,mask,heads", [
+        (50, 64, 4, False, 1),     # below one block: the whole length
+        (128, 64, 2, True, 1),
+        (200, 128, 2, False, 1),   # not a multiple of 128: padded to 256
+        (256, 64, 4, False, 1),    # gpt2m-f32-train-b32-t256, transposed
+        (1000, 64, 4, True, 1),
+        (1024, 64, 4, False, 1),   # gpt2m-f32-train-b8-t1024, transposed
+        (2048, 128, 2, True, 1),   # chip_smoke.py's kernels phase
+        (8192, 64, 2, False, 1),   # docs/PERF.md's long-context envelope
+        # two heads of 64 (four of 32) a lane block: the same blocks
+        (256, 64, 4, False, 2),    # gpt2m-f32-train-b32-t256 as it runs
+        (1024, 64, 4, False, 2),   # gpt2m-f32-train-b8-t1024 as it runs
+        (1024, 64, 2, True, 2),
+        (1536, 32, 4, False, 4),
+        (8192, 64, 2, False, 2),
     ])
-    def test_blocks_divide_and_fit(self, kernel, T, D, item, mask):
-        bq, bk = fa.choose_blocks(kernel, T, T, D, item, mask)
+    def test_blocks_divide_and_fit(self, kernel, T, D, item, mask, heads):
+        bq, bk = fa.choose_blocks(kernel, T, T, D, item, mask, heads)
         t_pad = fa._padded(T)
         assert t_pad >= T and (t_pad == T or t_pad % 128 == 0)
         for b in (bq, bk):
@@ -570,9 +617,10 @@ class TestBlockChooser:
         grid, loop = (bq, bk) if kernel != "dkv" else (bk, bq)
         assert loop == cap
         assert grid == (t_pad if t_pad <= fa._MAX_WHOLE else cap)
-        need = fa._working_set(kernel, bq, bk, t_pad, t_pad, D, item, mask)
+        need = fa._working_set(kernel, bq, bk, t_pad, t_pad, D, item, mask,
+                               heads)
         params = fa._compiler_params(kernel, bq, bk, t_pad, t_pad, D, item,
-                                     mask)
+                                     mask, heads)
         if need <= fa._VMEM_SHARE * fa._VMEM_DEFAULT:
             assert params == {}
         else:       # T = 8192: the whole-sequence operands want more VMEM
@@ -601,6 +649,19 @@ class TestBlockChooser:
         a = fa._working_set("fwd", 256, 256, 1024, 1024, 64, 4, False)
         b = fa._working_set("fwd", 256, 256, 1024, 1024, 128, 4, False)
         assert a == b           # D = 64 occupies 128 lanes all the same
+
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    def test_two_heads_a_block_count_their_rows_and_results(self, kernel):
+        """Two heads of 64 in a lane block: the operands are the 128 lanes
+        one head's were counted at, the float32 tiles are one head's (the
+        heads run one after another); what is added is a second lse/delta
+        row a block and the result the heads' columns are gathered into."""
+        one = fa._working_set(kernel, 512, 512, 1024, 1024, 64, 4, False)
+        two = fa._working_set(kernel, 512, 512, 1024, 1024, 64, 4, False, 2)
+        rows = {"fwd": 1, "dq": 2, "dkv": 2}[kernel] * 8 * (
+            1024 if kernel == "dkv" else 512) * 4
+        results = {"fwd": 1, "dq": 1, "dkv": 2}[kernel] * 512 * 128 * 4
+        assert two - one == 2 * rows + results
 
     @pytest.mark.parametrize("same_len", [False, True])
     def test_explicit_blocks_win(self, same_len):
@@ -669,8 +730,10 @@ class TestTileSchedule:
             np.testing.assert_allclose(np.asarray(a), np.asarray(c),
                                        rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("H,D", [(2, 16), (4, 64)],
+                             ids=["transposed", "h2"])
     @pytest.mark.parametrize("bq,bk", [(16, 16), (32, 16), (16, 32)])
-    def test_unequal_offsets_mask_every_tile(self, bq, bk):
+    def test_unequal_offsets_mask_every_tile(self, bq, bk, H, D):
         """q_offset != k_offset (ring and chunked blocks): no tile may take
         the plain body, whatever the blocks; the second q shard against
         both key chunks equals its rows of the full causal attention, in
@@ -679,7 +742,7 @@ class TestTileSchedule:
             flash_attention_block_grad, merge_attention_blocks)
 
         rs = np.random.RandomState(3)
-        B, T, H, D = 1, 64, 2, 16
+        B, T = 1, 64
         q, k, v = _qkv(rs, B, T, H, D)
         half = T // 2
 
@@ -785,20 +848,24 @@ class TestKernelNamesAndResults:
 
     @staticmethod
     def _pallas_eqns(jaxpr, out):
-        for e in jaxpr.eqns:
-            if e.primitive.name == "pallas_call":
-                out.append(e)
-            for v in e.params.values():
-                for sub in (v if isinstance(v, (list, tuple)) else [v]):
-                    inner = getattr(sub, "jaxpr", sub)
-                    if hasattr(inner, "eqns") and e.primitive.name != "pallas_call":
-                        TestKernelNamesAndResults._pallas_eqns(inner, out)
+        out.extend(e for e in _eqns(jaxpr, [])
+                   if e.primitive.name == "pallas_call")
         return out
 
+    @pytest.mark.parametrize("H,D,tag", [(3, 8, ""), (4, 64, "_h2"),
+                                         (4, 32, "_h4"), (3, 128, "_h1"),
+                                         (3, 64, "")],
+                             ids=["d8", "d64", "d32", "d128", "d64-odd"])
     @pytest.mark.parametrize("T,bq,bk,t_pad", [(64, 32, 16, 64),
                                                (50, 16, 32, 64)])
-    def test_three_names_with_blocks_and_result_types(self, T, bq, bk, t_pad):
-        B, H, D = 2, 3, 8
+    def test_three_names_with_blocks_and_result_types(self, T, bq, bk, t_pad,
+                                                      H, D, tag):
+        """``tag``: the heads that share a lane block, in the name; none
+        where the call transposes to [B*H, T, D] (an odd head count at
+        D = 64 says so by its name). Head-addressed results are
+        [B, t_pad, H*D]: still one rank-3 array for dq, two equal ones for
+        dk/dv, and a float32 [B*H, 1, t_pad] beside the forward's."""
+        B = 2
         q = jnp.ones((B, T, H, D), jnp.float32)
         jp = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
             q, k, v, causal=True, block_q=bq, block_k=bk, interpret=True)),
@@ -807,11 +874,13 @@ class TestKernelNamesAndResults:
                                   for v in e.outvars]
                for e in self._pallas_eqns(jp.jaxpr, [])}
         BH, x = B * H, "float32"
+        res = (BH, t_pad, D) if tag == "" else (B, t_pad, H * D)
+        assert fa._layout(H, D).tag == tag
         assert got == {
-            f"flash_fwd_q{bq}_k{bk}": [((BH, t_pad, D), x),
-                                       ((BH, 1, t_pad), "float32")],
-            f"flash_bwd_dq_q{bq}_k{bk}": [((BH, t_pad, D), x)],
-            f"flash_bwd_dkv_q{bq}_k{bk}": [((BH, t_pad, D), x)] * 2,
+            f"flash_fwd{tag}_q{bq}_k{bk}": [(res, x),
+                                            ((BH, 1, t_pad), "float32")],
+            f"flash_bwd_dq{tag}_q{bq}_k{bk}": [(res, x)],
+            f"flash_bwd_dkv{tag}_q{bq}_k{bk}": [(res, x)] * 2,
         }
 
     def test_chosen_blocks_are_in_the_names(self):
@@ -826,3 +895,202 @@ class TestKernelNamesAndResults:
         want = {f"{fa._NAMES[kn]}_q%d_k%d" % fa.choose_blocks(kn, T, T, D, 2)
                 for kn in ("fwd", "dq", "dkv")}
         assert names == want
+
+
+def _eqns(jaxpr, out):
+    """Every equation of a jaxpr and of the jaxprs inside it, a Pallas
+    kernel's body apart."""
+    for e in jaxpr.eqns:
+        out.append(e)
+        if e.primitive.name == "pallas_call":
+            continue
+        for v in e.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _eqns(inner, out)
+    return out
+
+
+class TestHeadAddressing:
+    """The kernels read and write [B, T, H*D] (ops/flash_attention.py
+    ``heads_per_block``): 128 // D heads a 128-lane block where D divides
+    128 and that count divides H, one where D is a multiple of 128, the
+    transposed [B*H, T, D] arrays otherwise."""
+
+    @pytest.mark.parametrize("D,H,want", [
+        (32, 1, None), (32, 3, None), (32, 4, 4), (32, 16, 4),
+        (64, 1, None), (64, 3, None), (64, 4, 2), (64, 16, 2),
+        (128, 1, 1), (128, 3, 1), (128, 4, 1), (128, 16, 1),
+        (256, 3, 1), (80, 4, None), (96, 4, None), (16, 8, 8), (16, 4, None),
+    ])
+    def test_rule(self, D, H, want):
+        assert fa.heads_per_block(H, D) == want
+        lay = fa._layout(H, D, fused=True)
+        assert lay.transposed == (want is None)
+        if want is None:
+            assert (lay.heads, lay.groups, lay.mask_rows, lay.tag) == (
+                1, 1, H, "")
+        else:
+            assert (lay.heads, lay.groups, lay.mask_rows) == (want, H // want,
+                                                              1)
+            assert lay.at == (0, H // want, 2 * H // want)
+            assert lay.tag == f"_h{want}"
+
+    # T = 200: padded along T in the new layout; 256: one tile; 1024: two
+    # static tiles, no loop; 1536: a real loop. Blocks None: the chooser's.
+    CASES = [
+        # B, T, H, D, causal, masked, dtype, blocks
+        pytest.param(1, 200, 4, 32, True, True, "float32", None, id="d32-t200"),
+        pytest.param(1, 256, 16, 32, False, False, "float32", None,
+                     id="d32-h16-t256-full"),
+        pytest.param(2, 200, 4, 64, True, False, "float32", None,
+                     id="d64-t200"),
+        pytest.param(1, 200, 4, 64, False, True, "float32", (64, 32),
+                     id="d64-t200-kmask-full-blocks"),
+        pytest.param(1, 256, 16, 64, True, False, "float32", None,
+                     id="d64-h16-t256"),
+        pytest.param(1, 256, 4, 64, True, True, "bfloat16", None,
+                     id="d64-t256-kmask-bf16"),
+        pytest.param(1, 1024, 4, 64, True, False, "float32", None,
+                     id="d64-t1024"),
+        pytest.param(1, 1536, 2, 64, True, True, "float32", None,
+                     id="d64-t1536-kmask"),
+        pytest.param(1, 1536, 4, 32, True, False, "bfloat16", None,
+                     id="d32-t1536-bf16"),
+        pytest.param(1, 200, 1, 128, True, False, "float32", None,
+                     id="d128-h1-t200"),
+        pytest.param(1, 256, 3, 128, False, True, "float32", (128, 64),
+                     id="d128-h3-t256-kmask-full-blocks"),
+        pytest.param(1, 1024, 4, 128, True, False, "bfloat16", None,
+                     id="d128-t1024-bf16"),
+        pytest.param(1, 1536, 1, 128, True, False, "float32", None,
+                     id="d128-t1536"),
+        # shapes that pair up nowhere say so and take the transposed path
+        pytest.param(1, 256, 3, 64, True, True, "float32", None,
+                     id="d64-h3-transposed"),
+        pytest.param(1, 200, 1, 64, True, False, "float32", None,
+                     id="d64-h1-transposed"),
+        pytest.param(1, 256, 3, 32, False, False, "float32", None,
+                     id="d32-h3-transposed"),
+    ]
+
+    @pytest.mark.parametrize("B,T,H,D,causal,masked,dtype,blocks", CASES)
+    def test_fwd_and_three_gradients_match_reference(
+            self, B, T, H, D, causal, masked, dtype, blocks):
+        rs = np.random.RandomState(T + H + D)
+        qf, kf, vf = _qkv(rs, B, T, H, D)
+        q, k, v = (x.astype(dtype) for x in (qf, kf, vf))
+        km = TestKmask._mask(rs, B, T) if masked else None
+        w = 1.0 if km is None else jnp.asarray(
+            np.asarray(km)[:, :, None, None])
+        bq, bk = blocks or (None, None)
+        assert (fa._layout(H, D).tag == "") == (
+            (D, H) in {(64, 3), (64, 1), (32, 3)})
+
+        def val_and_grads(fn, *xs):
+            return jax.value_and_grad(
+                lambda q, k, v: jnp.sum(
+                    (fn(q, k, v).astype(jnp.float32) * w) ** 2),
+                argnums=(0, 1, 2))(*xs)
+
+        lp, gp = val_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, kmask=km, causal=causal, block_q=bq, block_k=bk,
+            interpret=True), q, k, v)
+        lr, gr = val_and_grads(
+            lambda q, k, v: _reference(q, k, v, causal, kmask=km), qf, kf, vf)
+        tol = dict(rtol=2e-4, atol=2e-4) if dtype == "float32" else dict(
+            rtol=5e-2, atol=5e-2)
+        np.testing.assert_allclose(float(lp), float(lr),
+                                   rtol=1e-5 if dtype == "float32" else 2e-2)
+        for a, b in zip(gp, gr):
+            assert a.dtype == jnp.dtype(dtype)
+            np.testing.assert_allclose(np.asarray(a, np.float32),
+                                       np.asarray(b), **tol)
+
+    @pytest.mark.parametrize("bwd", ["pallas", "xla"])
+    @pytest.mark.parametrize("H,D,masked", [(4, 64, False), (4, 64, True),
+                                            (4, 32, False), (2, 128, True),
+                                            (3, 64, True), (2, 16, False)])
+    def test_fused_projection_equals_split(self, H, D, masked, bwd):
+        """``flash_attention_qkv`` over [B, T, 3*H*D] against
+        ``flash_attention`` over its split: the same numbers, value and
+        cotangent, whether the kernels read the fused array in place or
+        (an odd head count, heads of 16) it is split and transposed."""
+        rs = np.random.RandomState(H * D)
+        B, T = 2, 40
+        q, k, v = _qkv(rs, B, T, H, D)
+        qkv = fa.join_qkv(q, k, v)
+        for a, b in zip(fa.split_qkv(qkv, H), (q, k, v)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        km = TestKmask._mask(rs, B, T) if masked else None
+        kw = dict(kmask=km, causal=True, block_q=16, block_k=32,
+                  interpret=True, bwd=bwd)
+        w = jnp.asarray(rs.randn(B, T, H * D).astype(np.float32))
+
+        lf, gf = jax.value_and_grad(lambda x: jnp.sum(
+            fa.flash_attention_qkv(x, H, **kw) * w))(qkv)
+        ls, gs = jax.value_and_grad(lambda q, k, v: jnp.sum(
+            flash_attention(q, k, v, **kw).reshape(B, T, H * D) * w),
+            argnums=(0, 1, 2))(q, k, v)
+        assert gf.shape == qkv.shape
+        np.testing.assert_allclose(float(lf), float(ls), rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(gf),
+                                   np.asarray(fa.join_qkv(*gs)),
+                                   rtol=1e-6, atol=1e-6)
+
+    @pytest.mark.parametrize("fused", [False, True], ids=["qkv", "fused"])
+    @pytest.mark.parametrize("shape", [(8, 1024, 16, 64), (32, 256, 16, 64),
+                                       (1, 4096, 32, 128)])
+    def test_no_q_sized_transpose_in_the_gradient(self, shape, fused):
+        """The jaxpr of ``jax.grad`` through the kernels at the benchmark's
+        three attention shapes: three Pallas calls and no ``transpose`` of
+        an array as large as q (what ``_pad_bh``/``_from_bh`` were twelve
+        times a layer); delta's [B, T, H] -> [B, H, T] is the only
+        transposition left, a 64th of that."""
+        B, T, H, D = shape
+        if fused:
+            args = (jax.ShapeDtypeStruct((B, T, 3 * H * D), jnp.float32),)
+            fn = lambda x: jnp.sum(                     # noqa: E731
+                fa.flash_attention_qkv(x, H, causal=True))
+        else:
+            args = (jax.ShapeDtypeStruct(shape, jnp.float32),) * 3
+            fn = lambda q, k, v: jnp.sum(               # noqa: E731
+                flash_attention(q, k, v, causal=True))
+        jp = jax.make_jaxpr(jax.grad(fn, argnums=tuple(range(len(args)))))(
+            *args)
+        eqns = _eqns(jp.jaxpr, [])
+        assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+        moved = [e for e in eqns if e.primitive.name == "transpose"
+                 and e.invars[0].aval.size >= B * T * H * D]
+        assert moved == []
+        if fused:       # nor is the projection split or sliced
+            assert not [e for e in eqns if e.primitive.name in (
+                "split", "slice", "dynamic_slice")
+                and e.invars[0].aval.size >= B * T * H * D]
+
+    @pytest.mark.parametrize("D", [16, 64, 128])
+    def test_grouped_query_attention_at_two_kv_heads(self, D):
+        """``GroupedQueryAttention`` (4 query heads over 2 key-value heads)
+        through the kernels against its XLA path, output and every
+        gradient: heads of 16 transposed, of 64 two a lane block, of 128
+        one."""
+        from deeplearning4j_tpu.nn.input_type import InputType
+        from deeplearning4j_tpu.nn.layers import GroupedQueryAttention
+
+        rs = np.random.RandomState(D)
+        B, T, C = 2, 24, 48
+        x = jnp.asarray(rs.randn(B, T, C).astype(np.float32))
+        got = []
+        for use_flash in (True, False):
+            layer = GroupedQueryAttention(n_heads=4, n_kv_heads=2, head_dim=D,
+                                          use_flash=use_flash)
+            params = layer.init(jax.random.PRNGKey(1),
+                                InputType.recurrent(C, T))
+            got.append(jax.value_and_grad(
+                lambda p, x: jnp.sum(layer.apply(p, {}, x)[0] ** 2),
+                argnums=(0, 1))(params, x))
+        for a, b in zip(jax.tree_util.tree_leaves(got[0]),
+                        jax.tree_util.tree_leaves(got[1])):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)

@@ -576,12 +576,14 @@ class TestProfilerClock:
 
         fwd = str(jax.make_jaxpr(loss)(q, q, q))
         both = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
-        # each name ends in the blocks the kernel ran at (one 128-row tile)
+        # each name holds the heads that share a lane block (two of 64) and
+        # ends in the blocks the kernel ran at (one 128-row tile)
         names = lambda t: set(re.findall(  # noqa: E731
-            r"flash_(?:fwd|bwd_dq|bwd_dkv)_q\d+_k\d+", t))
-        assert names(fwd) == {"flash_fwd_q128_k128"}
-        assert names(both) == {"flash_fwd_q128_k128", "flash_bwd_dq_q128_k128",
-                               "flash_bwd_dkv_q128_k128"}
+            r"flash_(?:fwd|bwd_dq|bwd_dkv)(?:_h\d+)?_q\d+_k\d+", t))
+        assert names(fwd) == {"flash_fwd_h2_q128_k128"}
+        assert names(both) == {"flash_fwd_h2_q128_k128",
+                               "flash_bwd_dq_h2_q128_k128",
+                               "flash_bwd_dkv_h2_q128_k128"}
 
     def test_scopes_leave_the_steps_results_bit_identical(self, monkeypatch):
         import contextlib

@@ -81,6 +81,71 @@ def test_flash_fwd_bwd_compiles(shape, dtype, masked):
     assert _n_mosaic(hlo) == 3      # forward + dq + dk/dv kernels
 
 
+def _q_sized_moves(hlo: str, n: int):
+    """The ``copy`` and ``transpose`` instructions of a compiled module whose
+    result holds at least ``n`` elements: what a layout change of a q-sized
+    array costs the device."""
+    import re
+
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                     r"(copy|transpose)\(", line)
+        if m and m.group(2):
+            size = 1
+            for d in m.group(2).split(","):
+                size *= int(d)
+            if size >= n:
+                found.append(line.strip()[:160])
+    return found
+
+
+ATTENTION_LAYERS = [
+    pytest.param("multi_head", 8, 1024, 1024,
+                 dict(n_heads=16), 16 * 64, id="gpt2m-b8-t1024"),
+    pytest.param("multi_head", 32, 256, 1024,
+                 dict(n_heads=16), 16 * 64, id="gpt2m-b32-t256"),
+    pytest.param("multi_head", 1, 4096, 4096,
+                 dict(n_heads=32), 32 * 128, id="d128-b1-t4096"),
+    pytest.param("grouped_query", 1, 4096, 2688,
+                 dict(n_heads=32, n_kv_heads=2, head_dim=128), 32 * 128,
+                 id="twotower-b1-t4096"),
+]
+
+
+@pytest.mark.parametrize("kind,B,T,C,kw,width", ATTENTION_LAYERS)
+def test_attention_layers_move_no_q_sized_array(monkeypatch, kind, B, T, C,
+                                                kw, width):
+    """The benchmark's attention layers (and one of 32 heads of 128, the
+    hybrid cell's q at full key-value heads), float32, forward and backward,
+    compiled for v5e: the three Mosaic calls read the projections where the
+    matmuls leave them and write where the next matmul reads, so no ``copy``
+    or ``transpose`` of an array as large as q is left in the module (the
+    parent's held ten a GPT-2 layer). The grouped-query layer keeps two: dk
+    and dv, computed a query head, are relaid for the sum over the 16 heads
+    that share a key-value head (XLA's ``jnp.repeat`` backward); they go
+    when the kernels take k and v at their own head count (ROADMAP S17)."""
+    from deeplearning4j_tpu.nn.input_type import InputType
+    from deeplearning4j_tpu.nn.layers import (
+        GroupedQueryAttention, MultiHeadAttention)
+
+    layer = {"multi_head": MultiHeadAttention,
+             "grouped_query": GroupedQueryAttention}[kind](causal=True, **kw)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), InputType.recurrent(C, T), jnp.float32))
+    p_sds = jax.tree_util.tree_map(lambda a: _sds(a.shape, a.dtype), params)
+
+    def loss(p, x):
+        return _f32sum(layer.apply(p, {}, x)[0])
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), p_sds,
+                   _sds((B, T, C), jnp.float32))
+    assert _n_mosaic(hlo) == 3
+    moves = _q_sized_moves(hlo, B * T * width)
+    assert len(moves) == (2 if kind == "grouped_query" else 0), moves
+
+
 def test_flash_ring_blocks_compile():
     """The ring path's building blocks: two differentiable key chunks
     merged by their logsumexp (parallel/ring.py does exactly this per
@@ -130,13 +195,19 @@ def test_fused_lstm_fwd_bwd_compiles(B, T, H, dtype, peephole, masked):
     assert _n_mosaic(hlo) == 2      # forward + backward kernels
 
 
-@pytest.mark.parametrize("d,m", [(4, 1), (2, 2)], ids=["data4", "data2xmodel2"])
-def test_flash_under_a_mesh_compiles(monkeypatch, d, m):
+@pytest.mark.parametrize("d,m,H,C", [(4, 1, 16, 2048), (2, 2, 16, 2048),
+                                     (2, 2, 6, 384)],
+                         ids=["data4", "data2xmodel2",
+                              "data2xmodel2-odd-local-heads"])
+def test_flash_under_a_mesh_compiles(monkeypatch, d, m, H, C):
     """GSPMD cannot partition a Mosaic kernel ("Please wrap the call in a
     shard_map" — what MeshTrainer hit on the four-chip host): under an
     active multi-device mesh the attention layer runs the kernel inside a
     shard_map over (data, model). Compiled here for all four chips of the
-    v5e:2x2 topology, at the flagship's attention shape."""
+    v5e:2x2 topology, at the flagship's attention shape; and at six heads of
+    64, where each of two model shards holds three: an odd local count pairs
+    up into no lane block, so the shard's kernels take the transposed
+    arrays (the addressing follows what the call sees, per shard)."""
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -144,8 +215,8 @@ def test_flash_under_a_mesh_compiles(monkeypatch, d, m):
     from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
     from deeplearning4j_tpu.parallel.context import use_mesh
 
-    B, T, C = 16, 2048, 2048
-    layer = MultiHeadAttention(n_heads=16, causal=True)
+    B, T = 16, 2048
+    layer = MultiHeadAttention(n_heads=H, causal=True)
     params = layer.init(jax.random.PRNGKey(0), InputType.recurrent(C, T),
                         jnp.bfloat16)
     devices = _topology().devices
@@ -166,6 +237,7 @@ def test_flash_under_a_mesh_compiles(monkeypatch, d, m):
     with use_mesh(mesh):
         hlo = jax.jit(jax.grad(loss)).lower(p_sds, x_sds).compile().as_text()
     assert _n_mosaic(hlo) == 3
+    assert ("flash_fwd_q" in hlo) == (H // m % 2 == 1)
 
 
 # ---------------------------------------------------------------------------

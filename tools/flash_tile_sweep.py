@@ -1,27 +1,35 @@
-"""Device time of the three flash kernels by tile size, read from a profiler
-trace on the chip (PERF.md section 5 holds the table this printed; the caps in
-``ops/flash_attention.py`` ``_MAX_BLOCK`` stand on it).
+"""Device time of the three flash kernels by tile size and by addressing, read
+from a profiler trace on the chip (PERF.md section 5 holds the tables this
+printed; the caps in ``ops/flash_attention.py`` ``_MAX_BLOCK`` stand on it).
 
     python tools/flash_tile_sweep.py [--shapes gpt2m-f32,...] [--blocks 128,256,512 | chosen]
-        [--baseline path/to/another/flash_attention.py] [--yardstick]
+        [--baseline path/to/another/flash_attention.py] [--yardstick] [--layer]
         [--out chiprun_out/flash_sweep.jsonl]
     JAX_PLATFORMS=cpu python tools/flash_tile_sweep.py --compile-only
 
-Each variant is one jitted call of one kernel over pre-padded [BH, T, D]
-operands, causal, no mask; all variants of a shape run three times inside one
-trace and the kernels' own device durations are read back (a stand-alone call
-is dispatch-bound on the host's clock, docs/PERF.md). ``--baseline`` times
-another copy of the kernel module (the parent commit's) at the same blocks;
-``--yardstick`` times jax.experimental.pallas.ops.tpu.flash_attention, to
-say what Mosaic allows at the same head width, not to adopt it.
-``--compile-only`` compiles every variant for a described v5e without a chip
-and prints which ones Mosaic refuses: no time comes out of that.
+A kernel variant is one jitted call of one kernel over pre-padded operands,
+causal, no mask: ``ours`` over three ``[B, T, H*D]`` arrays, ``fused`` over the
+one ``[B, T, 3*H*D]`` projection the attention layer hands the kernels,
+``baseline`` (``--baseline``: another copy of the kernel module, the parent
+commit's) over whatever layout that module's kernels take. ``--layer`` adds,
+for this tree and for the baseline, one attention layer's forward and backward
+through the public entry point, from the fused projection to its cotangent:
+its ``all`` is every device operation of the program, so ``all`` less the
+kernels is what the layout costs around them (**kernels + copies**).
+``--yardstick`` times jax.experimental.pallas.ops.tpu.flash_attention, to say
+what Mosaic allows at the same head width, not to adopt it.
+
+All variants of a shape run three times inside one trace, each call under a
+host annotation of its own; the device operations that start inside it are
+the call's (a stand-alone call is dispatch-bound on the host's clock,
+docs/PERF.md), least of three. ``--compile-only`` compiles every variant for
+a described v5e without a chip and prints which ones Mosaic refuses: no time
+comes out of that.
 """
 
 from __future__ import annotations
 
 import argparse
-import functools
 import importlib
 import importlib.util
 import json
@@ -36,14 +44,16 @@ sys.path.insert(0, ROOT)
 import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
 
-SHAPES = {                      # (BH, T, D, dtype): what runs today
-    "gpt2m-f32-b8-t1024": (128, 1024, 64, "float32"),
-    "gpt2m-f32-b32-t256": (512, 256, 64, "float32"),
-    "gpt2m-bf16-b16-t1024": (256, 1024, 64, "bfloat16"),
-    "smoke-bf16-t2048-d128": (256, 2048, 128, "bfloat16"),
-    "long-bf16-t8192": (32, 8192, 64, "bfloat16"),
+SHAPES = {                      # (B, H, T, D, dtype): what runs today
+    "gpt2m-f32-b8-t1024": (8, 16, 1024, 64, "float32"),
+    "gpt2m-f32-b32-t256": (32, 16, 256, 64, "float32"),
+    "gpt2m-bf16-b16-t1024": (16, 16, 1024, 64, "bfloat16"),
+    "smoke-bf16-t2048-d128": (16, 16, 2048, 128, "bfloat16"),
+    "twotower-f32-t4096-d128": (1, 32, 4096, 128, "float32"),
+    "long-bf16-t8192": (2, 16, 8192, 64, "bfloat16"),
 }
 REPEATS = 3
+KERNELS = ("fwd", "dq", "dkv")
 
 
 def load_module(path):
@@ -53,72 +63,122 @@ def load_module(path):
     return mod
 
 
-def kernel_fns(mod, shape, bq, bk):
-    """{"fwd": f(q, k, v), "bwd": f(q, k, v, do, lse, delta)} at one tiling;
-    ``mod`` is this tree's kernel module or the baseline's (whose backward
-    call takes one pair of blocks for both kernels)."""
-    BH, T, D, dtype = shape
-    if bq is None:              # what the chooser takes, kernel by kernel
-        chosen = {kn: mod.choose_blocks(kn, T, T, D, jnp.dtype(dtype).itemsize)
-                  for kn in ("fwd", "dq", "dkv")}
-    else:
-        chosen = {kn: (bq, bk) for kn in ("fwd", "dq", "dkv")}
-    common = dict(D=D, q_pad=T, k_pad=T, t_real_k=T, causal=True,
-                  scale=1.0 / D ** 0.5, q_off=0, k_off=0, interpret=False,
-                  dtype=jnp.dtype(dtype))
+def _blocks(mod, shape, bq, bk, heads=1):
+    B, H, T, D, dtype = shape
+    if bq is not None:
+        return {kn: (bq, bk) for kn in KERNELS}
+    kw = {"heads": heads} if heads > 1 else {}   # what the chooser takes
+    return {kn: mod.choose_blocks(kn, T, T, D, jnp.dtype(dtype).itemsize, **kw)
+            for kn in KERNELS}
 
-    def fwd(q, k, v):
-        return mod._fwd_pallas_call(q, k, v, bq=chosen["fwd"][0],
+
+def kernel_fns(mod, shape, bq, bk, fused=False):
+    """``({"fwd": f(*x), "bwd": f(*x)}, avals)`` at one tiling: the kernels
+    of ``mod``, this tree's module (head-addressed ``[B, T, H*D]`` operands,
+    or the one fused array) or an older one (``[B*H, T, D]``)."""
+    B, H, T, D, dtype = shape
+    dt = jnp.dtype(dtype)
+    common = dict(q_pad=T, k_pad=T, t_real_k=T, causal=True,
+                  scale=1.0 / D ** 0.5, q_off=0, k_off=0, interpret=False)
+    rows = jax.ShapeDtypeStruct((B * H, 1, T), jnp.float32)
+    if hasattr(mod, "_layout"):
+        lay = mod._layout(H, D, fused=fused)
+        chosen = _blocks(mod, shape, bq, bk, lay.heads)
+        common["lay"] = lay
+        x = jax.ShapeDtypeStruct(
+            (B * H, T, D) if lay.transposed else (B, T, H * D), dt)
+        qkv = (jax.ShapeDtypeStruct((B, T, 3 * H * D), dt),) if fused else (x,) * 3
+        spread = (lambda a: (a[0],) * 3 + a[1:]) if fused else (lambda a: a)
+    else:                       # the [B*H, T, D] module
+        chosen = _blocks(mod, shape, bq, bk)
+        common.update(D=D, dtype=dt)
+        x = jax.ShapeDtypeStruct((B * H, T, D), dt)
+        qkv, spread = (x,) * 3, (lambda a: a)
+
+    def fwd(*a):
+        return mod._fwd_pallas_call(*spread(a), bq=chosen["fwd"][0],
                                     bk=chosen["fwd"][1], **common)
 
-    def bwd(q, k, v, do, lse, delta):
-        blocks = ({"blocks": chosen} if hasattr(mod, "choose_blocks")
-                  else {"bq": bq, "bk": bk})
-        return mod._bwd_pallas_calls(q, k, v, do, lse, delta, t_real_q=T,
-                                     **blocks, **common)
+    def bwd(*a):
+        blocks = ({"dq_blocks": chosen["dq"], "dkv_blocks": chosen["dkv"]}
+                  if "lay" in common else {"blocks": chosen}
+                  if hasattr(mod, "choose_blocks") else {"bq": bq, "bk": bk})
+        return mod._bwd_pallas_calls(*spread(a), t_real_q=T, **blocks,
+                                     **common)
 
-    return {"fwd": fwd, "bwd": bwd}
+    return ({"fwd": fwd, "bwd": bwd},
+            {"fwd": qkv, "bwd": qkv + (x, rows, rows)})
+
+
+def layer_fns(mod, shape, bq, bk):
+    """One attention layer's flash call as the model makes it, from the
+    fused projection [B, T, 3*H*D] to [B, T, H*D] and back to the
+    projection's cotangent, through ``mod``'s public entry point."""
+    B, H, T, D, dtype = shape
+    kw = dict(causal=True, block_q=bq, block_k=bk)
+    if hasattr(mod, "flash_attention_qkv"):
+        attn = lambda qkv: mod.flash_attention_qkv(qkv, H, **kw)  # noqa: E731
+    else:
+        def attn(qkv):
+            q, k, v = jnp.split(qkv.reshape(B, T, 3 * H, D), 3, axis=2)
+            return mod.flash_attention(q, k, v, **kw).reshape(B, T, H * D)
+
+    def layer(qkv, g):
+        out, vjp = jax.vjp(attn, qkv)
+        return out, vjp(g)[0]
+
+    dt = jnp.dtype(dtype)
+    return ({"layer": layer},
+            {"layer": (jax.ShapeDtypeStruct((B, T, 3 * H * D), dt),
+                       jax.ShapeDtypeStruct((B, T, H * D), dt))})
 
 
 def yardstick_fns(shape, bq, bk):
     from jax.experimental.pallas.ops.tpu import flash_attention as up
 
-    BH, T, D, _ = shape
+    B, H, T, D, dtype = shape
     sizes = up.BlockSizes(
         block_q=bq, block_k_major=bk, block_k=bk, block_b=1,
         block_q_major_dkv=bq, block_k_major_dkv=bk, block_k_dkv=bk,
         block_q_dkv=bq, block_k_major_dq=bk, block_k_dq=bk, block_q_dq=bq)
 
     def attn(q, k, v):
-        return up.flash_attention(q[:, None], k[:, None], v[:, None],
-                                  causal=True, sm_scale=1.0 / D ** 0.5,
-                                  block_sizes=sizes)
+        return up.flash_attention(q, k, v, causal=True,
+                                  sm_scale=1.0 / D ** 0.5, block_sizes=sizes)
 
-    def fwd(q, k, v):
-        return attn(q, k, v)
+    def fwd_bwd(q, k, v, do):
+        return jax.vjp(attn, q, k, v)[1](do)
 
-    def fwd_bwd(q, k, v, do, lse, delta):
-        return jax.vjp(attn, q, k, v)[1](do[:, None])
-
-    return {"fwd": fwd, "bwd": fwd_bwd}
+    x = jax.ShapeDtypeStruct((B, H, T, D), jnp.dtype(dtype))
+    return {"fwd": attn, "bwd": fwd_bwd}, {"fwd": (x,) * 3, "bwd": (x,) * 4}
 
 
-def avals(shape, sharding=None):
-    BH, T, D, dtype = shape
-    kw = {} if sharding is None else {"sharding": sharding}
-    x = jax.ShapeDtypeStruct((BH, T, D), jnp.dtype(dtype), **kw)
-    r = jax.ShapeDtypeStruct((BH, 1, T), jnp.float32, **kw)
-    return {"fwd": (x, x, x), "bwd": (x, x, x, x, r, r)}
-
-
-def custom_calls(trace_dir):
-    """The Mosaic calls of the trace in time order: (name, seconds)."""
+def calls_by_annotation(trace_dir, prefix):
+    """annotation name -> the device operations that ENDED inside it, in
+    time order: (name, seconds). The host's annotations and the device's
+    operations lie on one clock (benchmark/harness/trace.py) and a call ends
+    in ``block_until_ready`` inside its annotation; in a process's first
+    trace the device's stamps ran some tenths of a millisecond early, so a
+    call's first kernel began "before" its annotation and was counted to the
+    call before: an operation's end is the safer mark, and ``main`` throws a
+    first trace away."""
     from benchmark.harness import trace
 
     planes = trace.load_xplane(trace.find_xplane(trace_dir))
-    ev = [e for p in trace.device_planes(planes)[:1] for e in trace.op_events(p)
-          if "tpu_custom_call" in e[0]]
-    return [(n, d / 1e9) for n, s, d in sorted(ev, key=lambda e: e[1])]
+    spans = sorted((s, s + d, n) for p in trace.host_planes(planes)
+                   for l in p["lines"] for n, s, d in l["events"]
+                   if n.startswith(prefix))
+    ops = sorted((e for p in trace.device_planes(planes)[:1]
+                  for e in trace.op_events(p)), key=lambda e: e[1] + e[2])
+    out, i = {}, 0
+    for lo, hi, name in spans:
+        while i < len(ops) and ops[i][1] + ops[i][2] < lo:
+            i += 1
+        j = i
+        while j < len(ops) and ops[j][1] + ops[j][2] < hi:
+            j += 1
+        out[name], i = [(n, d / 1e9) for n, _, d in ops[i:j]], j
+    return out
 
 
 def kind(name):
@@ -133,6 +193,8 @@ def main(argv=None) -> int:
     ap.add_argument("--baseline-blocks", default=None,
                     help="bq:bk,... for the baseline (default: all)")
     ap.add_argument("--yardstick", action="store_true")
+    ap.add_argument("--layer", action="store_true",
+                    help="also one layer's forward and backward, whole")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
                                                   "flash_sweep.jsonl"))
@@ -164,37 +226,54 @@ def main(argv=None) -> int:
         with open(a.out, "a") as f:
             f.write(json.dumps(rec) + "\n")
 
+    def placed(avals):
+        return [jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+                for x in avals]
+
     for sname in a.shapes.split(","):
         shape = SHAPES[sname]
-        BH, T, D, dtype = shape
+        B, H, T, D, dtype = shape
         item = jnp.dtype(dtype).itemsize
-        emit({"shape": sname, "chosen": {
-            kn: ours.choose_blocks(kn, T, T, D, item) for kn in
-            ("fwd", "dq", "dkv")}})
-        variants = []           # (who, pass, bq, bk, compiled)
+        heads = ours.heads_per_block(H, D) or 1
+        emit({"shape": sname, "heads_per_block": ours.heads_per_block(H, D),
+              "chosen": {kn: ours.choose_blocks(kn, T, T, D, item,
+                                                heads=heads)
+                         for kn in KERNELS}})
         pairs = [(bq, bk) for bq in sizes for bk in sizes
                  if T % bq == 0 and T % bk == 0] or [(None, None)]
-        todo = [("ours", functools.partial(kernel_fns, ours), pairs)]
+        given = [p for p in pairs if p[0]]
+        todo = [("ours", lambda s, q, k: kernel_fns(ours, s, q, k), pairs),
+                ("fused", lambda s, q, k: kernel_fns(ours, s, q, k, True),
+                 pairs)]
+        if a.layer:
+            todo.append(("ours", lambda s, q, k: layer_fns(ours, s, q, k),
+                         pairs))
         if base is not None:
-            todo.append(("baseline", functools.partial(kernel_fns, base),
-                         [p for p in pairs if p[0] and (
-                             base_blocks is None or p in base_blocks)]))
+            its = [p for p in given if base_blocks is None or p in base_blocks]
+            if hasattr(base, "choose_blocks"):
+                its = its or pairs
+            todo.append(("baseline",
+                         lambda s, q, k: kernel_fns(base, s, q, k), its))
+            if a.layer:
+                todo.append(("baseline",
+                             lambda s, q, k: layer_fns(base, s, q, k), its))
         if a.yardstick:
             todo.append(("yardstick", yardstick_fns,
-                         [p for p in pairs if p[0] and max(p) <= 512]))
+                         [p for p in given if max(p) <= 512]))
+        variants = []           # (who, pass, bq, bk, compiled, avals)
         for who, make, its_pairs in todo:
             for bq, bk in its_pairs:
-                for pas, fn in make(shape, bq, bk).items():
+                fns, avals = make(shape, bq, bk)
+                for pas, fn in fns.items():
                     t = time.perf_counter()
                     try:
-                        c = jax.jit(fn).lower(
-                            *avals(shape, sharding)[pas]).compile()
+                        c = jax.jit(fn).lower(*placed(avals[pas])).compile()
                     except Exception as e:      # noqa: BLE001
                         emit({"shape": sname, "who": who, "pass": pas,
                               "bq": bq, "bk": bk, "compile_error":
                               str(e).strip().splitlines()[-1][:300]})
                         continue
-                    variants.append((who, pas, bq, bk, c))
+                    variants.append((who, pas, bq, bk, c, avals[pas]))
                     if a.compile_only:
                         emit({"shape": sname, "who": who, "pass": pas,
                               "bq": bq, "bk": bk, "compile_s":
@@ -202,47 +281,55 @@ def main(argv=None) -> int:
         if a.compile_only:
             continue
         key = jax.random.PRNGKey(0)
-        x = [jax.random.normal(jax.random.fold_in(key, i), (BH, T, D),
-                               jnp.float32).astype(dtype) for i in range(4)]
-        r = [jax.random.normal(jax.random.fold_in(key, 9 + i), (BH, 1, T),
-                               jnp.float32) for i in range(2)]
-        args = {"fwd": x[:3], "bwd": x + r}
-        for _, pas, _, _, c in variants:        # warm every executable
-            jax.block_until_ready(c(*args[pas]))
+        made = {}
+
+        def arrays(avals):
+            return [made.setdefault(
+                (i, x.shape, str(x.dtype)), jax.random.normal(
+                    jax.random.fold_in(key, i), x.shape,
+                    jnp.float32).astype(x.dtype)) for i, x in enumerate(avals)]
+
+        for *_, c, avals in variants:           # warm every executable
+            jax.block_until_ready(c(*arrays(avals)))
+        if sname == a.shapes.split(",")[0]:     # a trace nobody reads
+            with tempfile.TemporaryDirectory() as d:
+                jax.profiler.start_trace(d)
+                jax.block_until_ready(variants[0][4](*arrays(variants[0][5])))
+                jax.profiler.stop_trace()
         with tempfile.TemporaryDirectory() as d:
             jax.profiler.start_trace(d)
-            for _, pas, _, _, c in variants:
-                for _ in range(REPEATS):
-                    jax.block_until_ready(c(*args[pas]))
+            for i, (*_, c, avals) in enumerate(variants):
+                args = arrays(avals)
+                for r in range(REPEATS):
+                    with jax.profiler.TraceAnnotation(f"sweep.{i}.{r}"):
+                        jax.block_until_ready(c(*args))
             jax.profiler.stop_trace()
-            calls = custom_calls(d)
-        emit({"shape": sname, "mosaic_calls_in_trace": len(calls),
-              "first": [n for n, _ in calls[:4]]})
-        i = 0
-        for who, pas, bq, bk, c in variants:
-            n = c.as_text().count('custom_call_target="tpu_custom_call"')
-            mine, i = calls[i:i + n * REPEATS], i + n * REPEATS
-            by = {}
-            for j, (name, s) in enumerate(mine):
-                # the yardstick's kernels carry no name of ours: the first
-                # Mosaic call of a backward pass there is its forward
-                k = (kind(name) if who != "yardstick" else
-                     "fwd" if pas == "fwd" or j % n == 0 else "bwd_rest")
-                by.setdefault(k, []).append(s)
-            emit({"shape": sname, "who": who, "pass": pas, "bq": bq, "bk": bk,
-                  "ms": {k: round(1e3 * min(_per_call(v, REPEATS)), 4)
-                         for k, v in by.items()}})
-        if i != len(calls):
-            emit({"shape": sname, "warning": f"{len(calls)} Mosaic calls in "
-                  f"the trace, {i} expected"})
+            calls = calls_by_annotation(d, "sweep.")
+        for i, (who, pas, bq, bk, c, _) in enumerate(variants):
+            per = []            # one {kind: seconds} a repeat
+            for r in range(REPEATS):
+                by = {}
+                for name, s in calls.get(f"sweep.{i}.{r}", []):
+                    by["all"] = by.get("all", 0.0) + s
+                    if "tpu_custom_call" not in name:
+                        continue
+                    # the yardstick's kernels carry no name of ours: the
+                    # first Mosaic call of a backward pass is its forward
+                    k = (kind(name) if who != "yardstick" else
+                         "fwd" if pas == "fwd" or "fwd" not in by
+                         else "bwd_rest")
+                    by[k] = by.get(k, 0.0) + s
+                per.append(by)
+            rec = {"shape": sname, "who": who, "pass": pas, "bq": bq,
+                   "bk": bk, "ms": {k: round(1e3 * min(p.get(k, 0.0)
+                                                       for p in per), 4)
+                                    for k in sorted(set().union(*per))}}
+            if pas == "layer":
+                ms = rec["ms"]
+                rec["ms"]["copies"] = round(ms["all"] - sum(
+                    ms.get(k, 0.0) for k in KERNELS), 4)
+            emit(rec)
     return 0
-
-
-def _per_call(seconds, repeats):
-    """Seconds of one kind of kernel per call, one entry per repeat (a call
-    may hold several kernels of the kind: the yardstick's backward)."""
-    per = len(seconds) // repeats
-    return [sum(seconds[r * per:(r + 1) * per]) for r in range(repeats)]
 
 
 if __name__ == "__main__":
