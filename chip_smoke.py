@@ -6,7 +6,7 @@ One process, five phases in order, at the full width of the flagship
 TransformerLM (d2048 / 16 heads / 8 blocks / vocab 2048 / T2048 / B16, bf16,
 415M parameters, random weights from a seed):
 
-  device      the platform is a TPU and obs/profile.py knows its roofline
+  device      the platform is a TPU; its kind and count are printed
   kernels     every Pallas entry point (flash attention fwd+bwd, the ring
               blocks + merge, the fused LSTM fwd+bwd) compiled by Mosaic and
               run against its XLA reference at the shapes the models use
@@ -56,7 +56,7 @@ from deeplearning4j_tpu.train.listeners import TrainingListener
 from deeplearning4j_tpu.utils import bucketing
 from deeplearning4j_tpu.utils.compile_cache import enable_compilation_cache
 
-# the flagship at full width (bench.py bench_transformer, BENCH_r04)
+# the flagship at full width
 LM = dict(vocab_size=2048, max_len=2048, d_model=2048, n_heads=16,
           n_blocks=8)
 LM_BATCH = 16
@@ -191,8 +191,6 @@ def phase_device(ctx) -> None:
 
     import jaxlib
 
-    from deeplearning4j_tpu.obs import profile
-
     devs = jax.devices()
     d0 = devs[0]
     ctx["device"] = {"platform": d0.platform, "kind": d0.device_kind,
@@ -208,12 +206,6 @@ def phase_device(ctx) -> None:
         raise AssertionError(
             f"no accelerator: jax.devices()[0].platform is {d0.platform!r}, "
             "not 'tpu' — this smoke never passes on a CPU")
-    row = profile.roofline(d0.device_kind)
-    log(f"  roofline row: {row}")
-    if row["source"] != "table":
-        raise AssertionError(
-            f"device_kind {d0.device_kind!r} matches no row of "
-            f"obs/profile.py ROOFLINES (source={row['source']!r})")
     log(f"  memory: {mem()}")
 
 

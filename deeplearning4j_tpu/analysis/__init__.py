@@ -1,6 +1,6 @@
 """graftlint: JAX trace-safety static analysis + runtime retrace guard.
 
-Shape-bucketed execution (utils/bucketing.py, docs/PERF.md) only pays off
+Shape-bucketed execution (utils/bucketing.py) only pays off
 while nothing silently retraces or drags device arrays back to host
 mid-step. The paper's ND4J/libnd4j split made host/device boundaries
 explicit; the JAX port hides them — so this package makes them visible:

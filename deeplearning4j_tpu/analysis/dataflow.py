@@ -65,11 +65,11 @@ __all__ = [
 Key = Tuple[str, ...]
 
 # Path fragments that mark a string as naming a durable artifact: FileStore
-# blobs, checkpoints/bundles, the tune DB, exported weights. Writes reaching
+# blobs, checkpoints/bundles, exported weights. Writes reaching
 # these must go through the CRC-framed atomic helpers (docs/ROBUSTNESS.md).
 DURABLE_PATH_MARKERS = (
     "checkpoint", "ckpt", "bundle", "manifest", "lease", "blob",
-    "aotbundle", "tune_db", "tunedb", "snapshot", "params_", "weights_",
+    "aotbundle", "snapshot", "params_", "weights_",
     ".npz",
 )
 

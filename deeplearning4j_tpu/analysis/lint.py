@@ -24,7 +24,7 @@ the run, and stale-fingerprint noise from unchanged files is suppressed).
 new findings as ``error``/``baselineState: new``, grandfathered ones as
 ``note``/``unchanged``.
 
-Exit codes (the tools/lint.sh contract, asserted by tools/bench_smoke.sh):
+Exit codes (the tools/lint.sh contract):
 0 clean (vs baseline), 1 new findings, 2 usage/parse/git error.
 """
 

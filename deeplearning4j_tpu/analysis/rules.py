@@ -9,7 +9,6 @@
 | lock-discipline   | threaded modules      | unguarded shared mutable state       |
 | monotonic-clock   | everything            | wall clock in duration arithmetic    |
 | cost-analysis-off-hot-path | traced + hot | HLO cost walk / trace export per batch |
-| tuner-off-hot-path | traced + hot         | tuner search/trial (compiles, subprocesses, timers) per batch |
 | step-wiring       | nn/ + parallel/       | donated-carry jit built outside nn/step_program.py |
 | use-after-donate  | dataflow (donations)  | read of a buffer donated into a step |
 | collective-consistency | shard_map bodies | rank-divergent / axis-mismatched collectives |
@@ -54,7 +53,6 @@ ALL_RULES = (
     "lock-discipline",
     "monotonic-clock",
     "cost-analysis-off-hot-path",
-    "tuner-off-hot-path",
     "step-wiring",
 ) + DISTRIBUTED_RULES
 
@@ -91,8 +89,6 @@ def run(index: Index, rules: Optional[Sequence[str]] = None) -> List[Finding]:
         out += _rule_monotonic_clock(index)
     if "cost-analysis-off-hot-path" in active:
         out += _rule_cost_analysis_off_hot_path(index)
-    if "tuner-off-hot-path" in active:
-        out += _rule_tuner_off_hot_path(index)
     if "step-wiring" in active:
         out += _rule_step_wiring(index)
     if active & set(DISTRIBUTED_RULES):
@@ -681,56 +677,6 @@ def _rule_cost_analysis_off_hot_path(index: Index) -> List[Finding]:
                         "collect at report time (obs/fleet.py) instead")
             if f:
                 out.append(f)
-    return out
-
-
-# ---------------------------------------------------------------------------
-# tuner-off-hot-path
-# ---------------------------------------------------------------------------
-
-# measurement/search entry points of deeplearning4j_tpu.tune: every one
-# compiles executables, spawns trial subprocesses, or blocks on timers —
-# offline surfaces by contract (tune.maybe_apply, a DB lookup plus env-var
-# writes, is the ONLY tune call allowed near the hot path)
-_TUNER_MEASURE_CALLS = {
-    "run_trial", "run_subprocess_trial", "successive_halving", "tune_model",
-}
-
-
-def _rule_tuner_off_hot_path(index: Index) -> List[Finding]:
-    """The auto-tuner's search/trial surfaces measure by running: a trial
-    compiles a fresh step executable, a search spawns subprocesses and
-    waits on them. Reachable from a traced body that means host calls baked
-    in at trace time; reachable from per-batch dispatch code it means
-    seconds of stall per step. Tuning is an offline phase — consult its
-    RESULTS online via tune.maybe_apply (env-var application at startup),
-    never the measurement itself."""
-    out = []
-    for q in sorted(index.traced | index.hot):
-        fi = index.functions[q]
-        # the tuner's own modules call these entry points as the offline
-        # flow itself (tune_model → halving → subprocess trial → fit);
-        # self-calls are the feature, not a hot-path leak
-        if "/tune/" in fi.module.path.replace("\\", "/"):
-            continue
-        where = "traced" if q in index.traced else "hot-path"
-        for node in own_nodes(fi.node):
-            if not isinstance(node, ast.Call):
-                continue
-            d = dotted_name(node.func, fi.module) or ""
-            leaf = d.rsplit(".", 1)[-1] if d else (
-                node.func.attr if isinstance(node.func, ast.Attribute)
-                else node.func.id if isinstance(node.func, ast.Name)
-                else "")
-            if leaf in _TUNER_MEASURE_CALLS:
-                f = index.make_finding(
-                    "tuner-off-hot-path", fi, node.lineno,
-                    f"tuner measurement ({leaf}) reachable from {where} "
-                    "code: trials compile executables and spawn "
-                    "subprocesses; tune offline and consult the DB via "
-                    "tune.maybe_apply at startup instead")
-                if f:
-                    out.append(f)
     return out
 
 

@@ -285,7 +285,7 @@ def _rule_use_after_donate(index: Index, df: Dataflow) -> List[Finding]:
 
 # cross-replica primitives that must be issued identically by every member
 # of the axis (arXiv 2004.13336's sharded update is bit-exact only then;
-# mismatches are the gloo-preamble / gpipe-clip taxonomies of TEST_DEBT.md)
+# mismatches are the gloo-preamble / gpipe-clip taxonomies of tools/repro_*.py)
 _COLLECTIVES = {
     "psum", "pmean", "pmax", "pmin", "all_gather", "all_to_all",
     "ppermute", "pshuffle", "psum_scatter", "pcast", "pvary",
@@ -433,7 +433,7 @@ def _rule_collective_consistency(index: Index) -> List[Finding]:
     under rank-dependent control flow, a branch whose arms diverge, or an
     axis name outside the mesh's set deadlocks or miscompiles (the
     gloo-preamble rank disagreement and the gpipe-clip GSPMD taxonomies,
-    docs/TEST_DEBT.md)."""
+    tools/repro_gloo_preamble.py and tools/repro_gpipe_clip_miscompile.py)."""
     out: List[Finding] = []
     scope, env = _collective_scope(index)
     for q in sorted(scope):
@@ -565,7 +565,7 @@ def _open_mode(call: ast.Call) -> str:
 
 
 def _rule_durable_store_protocol(index: Index, df: Dataflow) -> List[Finding]:
-    """Writes reaching FileStore blob / checkpoint / bundle / tune-DB paths
+    """Writes reaching FileStore blob / checkpoint / bundle paths
     must go through the CRC-framed atomic helpers (``_atomic_write_zip``,
     DLES framing, write-tmp-then-``os.replace``): a raw ``open(path, "w")``
     or ``np.save`` on a durable path tears under crash/preemption and the

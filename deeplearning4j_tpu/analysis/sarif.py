@@ -30,7 +30,6 @@ _RULE_DESCRIPTIONS: Dict[str, str] = {
     "lock-discipline": "unguarded shared mutable state",
     "monotonic-clock": "wall clock in duration arithmetic",
     "cost-analysis-off-hot-path": "HLO cost walk per batch",
-    "tuner-off-hot-path": "tuner search on the hot path",
     "step-wiring": "donated-carry jit built outside nn/step_program.py",
     "use-after-donate": "read of a buffer donated into a step executable",
     "collective-consistency":

@@ -78,7 +78,7 @@ def _sg_ns_epoch_scan(params, centers2d, contexts2d, cum_table, key,
     ON-DEVICE by inverse-CDF over the unigram table. One dispatch (and ONE
     host->device transfer of the pair arrays) covers N batches — this
     removes the per-batch dispatch round trip that otherwise dominates
-    end-to-end corpus training (docs/PERF.md Word2Vec)."""
+    end-to-end corpus training."""
     N, B = centers2d.shape
 
     def body(carry, xs):
@@ -96,7 +96,7 @@ def _sg_ns_epoch_scan(params, centers2d, contexts2d, cum_table, key,
     # unroll=4 default: scan-of-scatter on TPU runs ~4x faster partially
     # unrolled (measured 283 -> 64 ms/step at B=64K, V=100K; unroll=16 is
     # no better and triples compile time). unroll=1 ~halves the first-epoch
-    # compile (52.2s at the bench config, BENCH_r04) at ~4x warm-epoch cost
+    # compile at ~4x warm-epoch cost
     # -- or keep 4 and amortize compiles across processes with
     # utils/compile_cache.enable_compilation_cache.
     (params, _, _), losses = jax.lax.scan(
@@ -293,7 +293,7 @@ def _fast_pairs(idx_seqs, window: int, keep: np.ndarray,
     subsampling + dynamic-window SEMANTICS as _PairGenerator.generate (a
     pair (i, i±o) exists iff o <= b_i and in range) but built with per-
     offset numpy masks instead of a per-pair Python loop — ~50x the
-    host-side throughput (docs/PERF.md Word2Vec end-to-end). Draw ORDER
+    host-side throughput. Draw ORDER
     differs from the per-pair generator, so trajectories are not
     bit-identical across backends (the pair multiset per sentence is,
     given equal rng draws). Yields (centers, contexts) int32 arrays."""
@@ -397,7 +397,7 @@ class SequenceVectors:
         sample: float = 1e-3,
         epochs: int = 1,
         # pairs per fused device step; the step is scatter-add bound at
-        # large batches (docs/PERF.md round-4 correction). Raise toward
+        # large batches. Raise toward
         # 65536 on big corpora to amortize dispatch.
         batch_size: int = 8192,
         elements_learning: str = "skipgram",

@@ -665,10 +665,7 @@ def bundle_path_for(checkpoint_path) -> str:
 
 def toolchain_fingerprint() -> dict:
     """The (jax, jaxlib, backend) triple that decides whether a persisted
-    artifact — executable bundle or tuning-DB entry — can still be trusted.
-    Shared by the bundle manifest and ``deeplearning4j_tpu.tune``: a knob
-    choice measured on one toolchain is as stale as a serialized executable
-    compiled on it."""
+    executable bundle can still be trusted."""
     import jax
 
     try:

@@ -1142,14 +1142,6 @@ class ComputationGraph:
             if resilience.resume(self, resume_from) is not None:
                 resume_skip = int(getattr(self, "batch_in_epoch", 0))
                 epochs = max(epochs - self.epoch, 0)
-        import os as _os
-
-        if _os.environ.get("DL4J_TPU_TUNE"):
-            # persisted tuner winner, applied before chain/warm/step-build
-            # read their envs (same hook as MultiLayerNetwork.fit)
-            from deeplearning4j_tpu import tune as _tune
-
-            _tune.maybe_apply(self, "fit")
         guard = getattr(self, "divergence_guard", None)
         if aot.enabled():
             # time-to-first-step becomes a warm-path number: compile (or

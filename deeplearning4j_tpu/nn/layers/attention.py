@@ -287,15 +287,6 @@ class TransformerBlock(LayerConfig):
         return layer_norm(x, p["gamma"], p["beta"], self.eps)
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
-        import os as _os
-
-        if _os.environ.get("DL4J_TPU_REMAT_BLOCKS") == "1":
-            # per-block rematerialization: trade recompute for activation
-            # memory (the classic big-transformer policy; perf-sweepable
-            # via tools/exp_transformer_mfu.py remat)
-            body = jax.checkpoint(
-                lambda p, xx, r, m: self._apply_inner(p, xx, train, r, m))
-            return body(params, x, rng, mask), state
         return self._apply_inner(params, x, train, rng, mask), state
 
     def _apply_inner(self, params, x, train, rng, mask):

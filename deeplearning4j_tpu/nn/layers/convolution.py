@@ -102,8 +102,8 @@ class Conv2D(FeedForwardLayerConfig):
         # NOTE: a slice-then-dense rewrite of strided 1x1 convs (the
         # ResNet-v1 bottleneck pattern) was a +12% win in round 3 but a
         # -12% LOSS on the round-4 toolchain — the strided-gather lowering
-        # improved and the explicit slice now breaks producer fusion. The
-        # null-experiment A/B lives in docs/PERF.md; keep the plain form.
+        # improved and the explicit slice now breaks producer fusion. Keep
+        # the plain form.
         return lax.conv_general_dilated(
             x,
             W,

@@ -139,7 +139,7 @@ class BaseRecurrent(FeedForwardLayerConfig):
         # re-measure (fresh-process A/B, value-fetch sync): unroll 1/8/50
         # all land within run-to-run noise (~1.8-2.0M tokens/s on the
         # char-RNN bench) — the round-3 "+46% at unroll=8" was a phantom of
-        # the sync-elision measurement bug (docs/PERF.md correction).
+        # the sync-elision measurement bug.
         # Default kept at 8: never measured worse, bounds compile time.
         import os as _os
 

@@ -590,8 +590,8 @@ class MultiLayerNetwork:
     def _make_chain_step(self):
         """K train steps per DISPATCH: lax.scan of the step body over
         stacked [K, B, ...] minibatches. Small models are dispatch-bound
-        (a ~4 ms host->device floor per call through remote links —
-        docs/PERF.md LeNet); one dispatch covering K steps amortizes it.
+        (a ~4 ms host->device floor per call through remote links);
+        one dispatch covering K steps amortizes it.
         Per-step rngs derive as fold_in(rng, i) — identical math to the
         per-step path for models that draw no randomness (no dropout /
         weight noise), a different-but-equivalent stream otherwise."""
@@ -644,7 +644,7 @@ class MultiLayerNetwork:
         """Steps chained per dispatch in fit()'s hot loop (0 = per-step).
         DL4J_TPU_CHAIN_STEPS forces a count; "auto" chains 8 only for
         models that draw NO randomness (identical math to per-step) and
-        are small enough to be dispatch-bound (docs/PERF.md LeNet)."""
+        are small enough to be dispatch-bound."""
         uses_rng = any(l.uses_rng() for l in self.layers)
         return _chain_k_from_env(uses_rng, self.num_params())
 
@@ -685,15 +685,6 @@ class MultiLayerNetwork:
             if resilience.resume(self, resume_from) is not None:
                 resume_skip = int(getattr(self, "batch_in_epoch", 0))
                 epochs = max(epochs - self.epoch, 0)
-        import os as _os
-
-        if _os.environ.get("DL4J_TPU_TUNE"):
-            # persisted tuner winner for this (signature, backend,
-            # toolchain) — applied BEFORE chain_k/warm/step-build read
-            # their envs, so it shapes everything compiled below
-            from deeplearning4j_tpu import tune as _tune
-
-            _tune.maybe_apply(self, "fit")
         tbptt = self.conf.backprop_type == "tbptt"
         sgd = self.conf.optimization_algo in (
             "stochastic_gradient_descent", "sgd")

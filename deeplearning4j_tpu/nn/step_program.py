@@ -19,8 +19,7 @@ now the only place that wiring exists:
   the donated step) and :func:`chain_k_from_env` (K steps per dispatch).
 - the **mesh-shape policy**: :func:`mesh_shape_from_env` resolves the
   ``(data, tensor, stage)`` axes of the named-mesh step
-  (``parallel/mesh_step.py``) from the ``DL4J_TPU_MESH_*`` knobs that
-  ``tune/knobs.py`` registers for the successive-halving search.
+  (``parallel/mesh_step.py``) from the ``DL4J_TPU_MESH_*`` knobs.
 
 A graftlint rule (``step-wiring``, ``analysis/rules.py``) forbids new
 direct ``jax.jit(..., donate_argnums=...)`` step construction in ``nn/``
@@ -218,8 +217,7 @@ def grad_accum_from_env() -> int:
     """Micro-batch count for gradient accumulation inside the jitted step
     (DL4J_TPU_GRAD_ACCUM, default 1 = off). Shared by MultiLayerNetwork and
     ComputationGraph; read at step-BUILD time, so a change after the first
-    compile needs ``_clear_compiled()`` (the tuner's trial subprocesses get
-    a fresh build for free). See docs/TUNING.md."""
+    compile needs ``_clear_compiled()``."""
     import os as _os
 
     env = _os.environ.get("DL4J_TPU_GRAD_ACCUM", "1")
@@ -319,15 +317,12 @@ def _axis_env(name: str) -> int:
 def mesh_shape_from_env(n_devices: int) -> Tuple[int, int, int]:
     """Resolve the named-mesh step's ``(data, tensor, stage)`` shape from
     the ``DL4J_TPU_MESH_DATA`` / ``DL4J_TPU_MESH_MODEL`` /
-    ``DL4J_TPU_MESH_PIPE`` knobs (``tune/knobs.py``; 0/unset = auto).
+    ``DL4J_TPU_MESH_PIPE`` knobs (0/unset = auto).
 
     Auto policy: unset tensor/stage axes default to 1 and the unset data
     axis absorbs every remaining device — so with no knobs set this is pure
-    DP over all devices, the baseline the MULTICHIP bench gate compares
-    tuned shapes against. A shape whose product does not divide
-    ``n_devices`` is a configuration error and raises (the knob domains the
-    tuner searches are derived from the local device count precisely so its
-    trials never land here)."""
+    DP over all devices. A shape whose product does not divide
+    ``n_devices`` is a configuration error and raises."""
     t = _axis_env("DL4J_TPU_MESH_MODEL") or 1
     s = _axis_env("DL4J_TPU_MESH_PIPE") or 1
     d = _axis_env("DL4J_TPU_MESH_DATA")

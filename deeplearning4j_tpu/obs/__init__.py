@@ -5,8 +5,8 @@ counters (utils/bucketing.py), comm bytes (parallel/grads.py), guard events
 (train/resilience.py) and listener throughput (train/listeners.py) — all
 land in ONE process-wide metrics registry, queryable three ways:
 
-- ``obs.snapshot()``      JSON dict (embedded in bench.py results and the
-                          resilience checkpoint telemetry field)
+- ``obs.snapshot()``      JSON dict (embedded in the resilience checkpoint
+                          telemetry field)
 - ``/metrics``            Prometheus text exposition on the UI server
 - ``obs.recent_spans()``  ring buffer of recent step spans
 
@@ -22,8 +22,7 @@ Hot-path discipline: recording is host-side dict updates under locks; no
 jax import, no device sync, ``block_until_ready`` never called. Set
 ``DL4J_TPU_OBS=0`` to disable span recording and event emission (counter
 shims underneath ``bucketing.telemetry()`` stay live — they ARE the
-storage); the overhead of the full layer is benched by the ``mnist_mlp``
-arm in bench.py (gate: <= 2%).
+storage).
 """
 
 from __future__ import annotations
@@ -92,7 +91,7 @@ def histogram(name: str, help: str = "", label_names=()) -> _metrics.Histogram:
 
 def prometheus_text() -> str:
     # exposition is report-time: resolve pending lazy cost signatures first
-    # so the XLA cost / MFU gauges reflect every compile seen so far
+    # so the XLA cost gauges reflect every compile seen so far
     try:
         from deeplearning4j_tpu.obs import profile as _profile
 
@@ -132,7 +131,7 @@ def save_spans(path: str) -> int:
 # -- profiling / SLOs -------------------------------------------------------
 
 def cost_report(resolve: bool = True) -> dict:
-    """XLA static costs + roofline utilization (see obs/profile.py).
+    """XLA static costs per site and key (see obs/profile.py).
     Report-time only — resolution may lower pending lazy signatures."""
     from deeplearning4j_tpu.obs import profile as _profile
 
@@ -251,8 +250,8 @@ def configure_event_log(path: Optional[str], max_bytes: int = 4 * 1024 * 1024):
 def snapshot() -> dict:
     """JSON-friendly aggregate of everything the registry knows: metric
     families (counters/gauges plain, histograms summarized), per-span
-    aggregates, and event counts. Embedded in bench.py result JSON and in
-    the resilience checkpoint telemetry field (round-trips through JSON)."""
+    aggregates, and event counts. Embedded in the resilience checkpoint
+    telemetry field (round-trips through JSON)."""
     from deeplearning4j_tpu.obs import profile as _profile
     from deeplearning4j_tpu.utils import bucketing
 
@@ -267,8 +266,8 @@ def snapshot() -> dict:
 
 def reset():
     """Zero every metric series, drop recent spans and the cost ledger,
-    keep configuration (event-log path, family registrations). Tests and
-    bench isolation."""
+    keep configuration (event-log path, family registrations). Test
+    isolation."""
     from deeplearning4j_tpu.obs import profile as _profile
     from deeplearning4j_tpu.obs import slo as _slo
 

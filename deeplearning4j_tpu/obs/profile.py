@@ -1,4 +1,4 @@
-"""Static XLA cost models + roofline utilization (MFU / memory bandwidth).
+"""Static XLA cost models of the executables the process compiles.
 
 This module turns the executables the process already produces into a cost
 ledger nobody has to pay twice for:
@@ -21,28 +21,22 @@ ledger nobody has to pay twice for:
   ``Lowered.cost_analysis()`` prices the HLO
   without compiling. Lazy entries have no ``memory_analysis`` (that needs a
   compile), so ``peak_hbm_bytes`` is reported only for AOT-warmed sites.
-- **Roofline division** — achieved per-dispatch wall time comes from the
-  ``dl4j_span_seconds`` histograms (p50 of the span mapped to each site);
-  dividing harvested flops / bytes-accessed by it and by the per-backend
-  peak table yields ``dl4j_mfu{site}`` and ``dl4j_membw_util{site}``. The
-  peak table absorbs the ad-hoc math previously duplicated in ``bench.py``
-  and ``tools/exp_transformer_mfu.py``; ``DL4J_TPU_PEAK_FLOPS`` /
-  ``DL4J_TPU_HBM_GBPS`` override it so CPU runs (tests, smoke) can exercise
-  the full pipeline.
+
+These are the compiler's static counts, priced at compile time: no time is
+measured here and no utilization derived (``benchmark/`` measures the chip).
 
 Hot-path discipline: :func:`note_trace` / :func:`wants_exemplar` are a set
 add / set lookup with no jax import; everything that touches jax
-(:func:`harvest_compiled`, resolution, :func:`utilization`) runs at
-compile time or report time — never per batch. The ``graftlint`` rule
+(:func:`harvest_compiled`, resolution) runs at compile time or report
+time — never per batch. The ``graftlint`` rule
 ``cost-analysis-off-hot-path`` enforces the same boundary statically.
 """
 
 from __future__ import annotations
 
-import os
 import threading
 import weakref
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 from deeplearning4j_tpu.obs import metrics
 
@@ -51,41 +45,10 @@ __all__ = [
     "harvest_compiled",
     "note_exemplar",
     "note_trace",
-    "peak_flops",
     "reset",
-    "roofline",
     "snapshot",
-    "utilization",
     "wants_exemplar",
 ]
-
-# Per-chip peaks by device_kind substring: (bf16 FLOP/s, f32 FLOP/s,
-# HBM bytes/s). FLOP columns match the table bench.py carried since PR 3
-# (public TPU spec sheets); HBM column from the same sheets. First
-# substring match wins; CPU / unknown kinds return None so utilization is
-# omitted rather than fabricated (unless the env overrides below are set).
-ROOFLINES: Tuple[Tuple[str, float, float, float], ...] = (
-    ("v6", 918e12, 459e12, 1640e9),
-    ("v5p", 459e12, 459e12, 2765e9),
-    ("v5 lite", 197e12, 98e12, 819e9),
-    ("v5e", 197e12, 98e12, 819e9),
-    ("v4", 275e12, 137e12, 1228e9),
-    ("v3", 123e12, 61e12, 900e9),
-    ("v2", 45e12, 22e12, 700e9),
-)
-
-# Which span's per-dispatch wall time prices each harvested site. fit spans
-# wrap exactly one step dispatch; output spans wrap one forward dispatch.
-_SITE_SPANS = {
-    "mln.step": "mln.fit_batch",
-    "mln.step.tbptt": "mln.fit_batch",
-    "mln.chain": "mln.fit_batch",  # one fit_batch span per chain dispatch
-    "cg.step": "cg.fit_batch",
-    "cg.step.tbptt": "cg.fit_batch",
-    "dp.step": "dp.step",
-    "mln.output": "mln.output",
-    "cg.output": "cg.output",
-}
 
 _lock = threading.Lock()
 # (site, key) -> cost entry dict (see harvest_compiled / _resolve_pending)
@@ -111,61 +74,6 @@ def _gauges():
                   "+ temp + generated code bytes (AOT-warmed sites only)",
                   ("site", "key")),
     )
-
-
-# ---------------------------------------------------------------------------
-# Roofline table
-# ---------------------------------------------------------------------------
-
-def _device_kind() -> str:
-    import jax
-
-    return jax.devices()[0].device_kind
-
-
-def roofline(device_kind: Optional[str] = None) -> dict:
-    """Peak numbers for the backend: ``{device_kind, peak_bf16_flops,
-    peak_f32_flops, hbm_bytes_per_s, source}``. Peaks are None for CPU /
-    unknown kinds unless ``DL4J_TPU_PEAK_FLOPS`` (FLOP/s) /
-    ``DL4J_TPU_HBM_GBPS`` (GB/s) override them."""
-    kind = device_kind if device_kind is not None else _device_kind()
-    bf16 = f32 = hbm = None
-    source = "unknown"
-    low = kind.lower()
-    for sub, peak_bf16, peak_f32, peak_hbm in ROOFLINES:
-        if sub in low:
-            bf16, f32, hbm = peak_bf16, peak_f32, peak_hbm
-            source = "table"
-            break
-    env_flops = os.environ.get("DL4J_TPU_PEAK_FLOPS")
-    if env_flops:
-        try:
-            bf16 = f32 = float(env_flops)
-            source = "env"
-        except ValueError:
-            pass
-    env_hbm = os.environ.get("DL4J_TPU_HBM_GBPS")
-    if env_hbm:
-        try:
-            hbm = float(env_hbm) * 1e9
-            source = "env"
-        except ValueError:
-            pass
-    return {
-        "device_kind": kind,
-        "peak_bf16_flops": bf16,
-        "peak_f32_flops": f32,
-        "hbm_bytes_per_s": hbm,
-        "source": source,
-    }
-
-
-def peak_flops(dtype: str = "bfloat16",
-               device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak FLOP/s for the backend at the given matmul precision; None for
-    CPU / unknown (callers omit MFU rather than fabricate it)."""
-    r = roofline(device_kind)
-    return r["peak_bf16_flops"] if dtype == "bfloat16" else r["peak_f32_flops"]
 
 
 # ---------------------------------------------------------------------------
@@ -319,87 +227,30 @@ def _resolve_pending():
 # Reports
 # ---------------------------------------------------------------------------
 
-def utilization(span_summary: Optional[Dict[str, dict]] = None) -> Dict[str, dict]:
-    """MFU / memory-bandwidth utilization per harvested site.
-
-    ``achieved = flops / p50_wall_per_dispatch``; MFU divides by the bf16
-    roofline (jax's default TPU matmul precision multiplies f32 inputs in
-    bf16 — same convention the LSTM bench used), bandwidth by HBM bytes/s.
-    Uses the largest-flops entry per site (the biggest bucket dominates a
-    saturated ladder). Refreshes ``dl4j_mfu`` / ``dl4j_membw_util`` gauges.
-    Empty when the backend has no roofline and no env override."""
-    r = roofline()
-    peak = r["peak_bf16_flops"]
-    hbm = r["hbm_bytes_per_s"]
-    if not peak and not hbm:
-        return {}
-    if span_summary is None:
-        from deeplearning4j_tpu.obs import spans
-
-        span_summary = spans.tracer().summary()
-    with _lock:
-        by_site: Dict[str, dict] = {}
-        for (site, key), entry in _costs.items():
-            if entry.get("flops", 0) > by_site.get(site, {}).get("flops", -1):
-                by_site[site] = {**entry, "key": key}
-    reg = metrics.registry()
-    g_mfu = reg.gauge("dl4j_mfu",
-                      "model FLOPs utilization: achieved flops/s at the "
-                      "site's step span over the bf16 roofline", ("site",))
-    g_bw = reg.gauge("dl4j_membw_util",
-                     "achieved bytes-accessed/s over peak HBM bandwidth",
-                     ("site",))
-    out: Dict[str, dict] = {}
-    for site, entry in by_site.items():
-        span = _SITE_SPANS.get(site, site)
-        s = span_summary.get(span)
-        if not s or not s.get("count") or not s.get("wall_p50_s"):
-            continue
-        wall = s["wall_p50_s"]
-        u = {"span": span, "key": entry["key"], "wall_p50_s": wall,
-             "source": entry["source"]}
-        if peak and entry.get("flops"):
-            u["achieved_flops_per_s"] = entry["flops"] / wall
-            u["mfu"] = entry["flops"] / wall / peak
-            g_mfu.set(round(u["mfu"], 6), site=site)
-        if hbm and entry.get("bytes_accessed"):
-            u["achieved_bytes_per_s"] = entry["bytes_accessed"] / wall
-            u["membw_util"] = entry["bytes_accessed"] / wall / hbm
-            g_bw.set(round(u["membw_util"], 6), site=site)
-        if "mfu" in u or "membw_util" in u:
-            out[site] = u
-    return out
-
-
 def cost_report(resolve: bool = True) -> dict:
-    """The profiling ledger: roofline, per-(site, key) static costs, and
-    derived utilization. ``resolve=True`` prices any pending lazy-compile
-    exemplars first (report time, never the hot path)."""
+    """The profiling ledger: per-(site, key) static costs. ``resolve=True``
+    prices any pending lazy-compile exemplars first (report time, never the
+    hot path)."""
     if resolve:
         _resolve_pending()
     with _lock:
         sites: Dict[str, dict] = {}
         for (site, key), entry in sorted(_costs.items()):
             sites.setdefault(site, {})[key] = dict(entry)
-    return {
-        "roofline": roofline(),
-        "sites": sites,
-        "utilization": utilization(),
-    }
+    return {"sites": sites}
 
 
 def snapshot(resolve: bool = True) -> dict:
-    """JSON-friendly view for ``obs.snapshot()`` (bench results, checkpoint
-    telemetry). Same shape as :func:`cost_report`."""
+    """JSON-friendly view for ``obs.snapshot()`` (checkpoint telemetry).
+    Same shape as :func:`cost_report`."""
     try:
         return cost_report(resolve=resolve)
     except Exception:  # never let profiling break a checkpoint save
-        return {"roofline": {"device_kind": "unknown", "source": "error"},
-                "sites": {}, "utilization": {}}
+        return {"sites": {}}
 
 
 def reset():
-    """Drop the ledger and pending exemplars (tests / bench isolation)."""
+    """Drop the ledger and pending exemplars (test isolation)."""
     with _lock:
         _costs.clear()
         _exemplars.clear()

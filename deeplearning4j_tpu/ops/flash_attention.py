@@ -2,8 +2,7 @@
 
 The XLA attention path (`parallel/ring.py local_attention`) materialises
 the [B, H, T, T] score matrix in HBM; at long T that traffic dominates
-(the framework's ResNet-style roofline analysis, docs/PERF.md, shows HBM
-bandwidth is the binding resource on this chip). This kernel computes
+(PERF.md section 5). This kernel computes
 attention blockwise in VMEM — scores never leave the chip — using the
 standard streaming-softmax recurrence (running max m, normaliser l,
 rescaled accumulator), one (batch, head group, q-block) program per grid

@@ -12,7 +12,7 @@ DL4J_TPU_SHARDED_UPDATE): on a single ICI-connected slice the implicit dense
 all-reduce is already optimal (SURVEY.md §5.8), but when the exchange
 crosses DCN — multi-slice or Ethernet-attached hosts — the 16x ternary wire
 format and the 1/R-per-replica optimizer math pay for themselves. Both
-switches default OFF; see docs/PERF.md.
+switches default OFF.
 """
 
 from deeplearning4j_tpu.parallel.mesh import (

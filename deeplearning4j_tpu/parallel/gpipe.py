@@ -664,8 +664,8 @@ class GPipeTrainer:
         # the [S, Lmax] stage vectors between the two executables — a few
         # tiny elementwise/norm dispatches per step, only for gn-bearing
         # configs — and the (linear-in-grads) updater half stays jitted.
-        # (standalone repro: tools/repro_gpipe_clip_miscompile.py; tracked
-        # in docs/TEST_DEBT.md — retire this split once a fixed XLA lands)
+        # (standalone repro: tools/repro_gpipe_clip_miscompile.py — retire
+        # this split once a fixed XLA lands and the repro exits 2)
         grads_jit = StepProgram(
             lambda params, x_micro, y_micro, rng, masks_all=None,
             head_mask=None: jax.value_and_grad(self._loss, has_aux=True)(

@@ -25,7 +25,7 @@ cannot express:
    params are all-gathered. The redundant R-way replicated update becomes
    1/R of the math and memory.
 
-Both are off by default (``docs/PERF.md``): on a single ICI-connected slice
+Both are off by default: on a single ICI-connected slice
 the dense fused psum is already near-optimal; these switches matter when the
 exchange crosses DCN (multi-slice / multi-host pods) or optimizer state
 dominates HBM.

@@ -58,13 +58,6 @@ class ParallelInference:
         self.model = model
         self.mode = mode
         self.max_batch_size = max_batch_size
-        import os as _os
-
-        if _os.environ.get("DL4J_TPU_TUNE"):
-            # tuner winner applied before bucketing/warmup read their envs
-            from deeplearning4j_tpu import tune as _tune
-
-            _tune.maybe_apply(model, "serve")
         self.bucket = bucketing.bucketing_enabled() if bucket is None else bucket
         if warmup is None:
             from ..nn import aot

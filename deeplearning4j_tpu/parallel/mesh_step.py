@@ -18,13 +18,10 @@ program (``nn/step_program.py``) over a named ``parallel/mesh.py`` mesh:
   ring schedule) remains ``parallel/gpipe.py``, which instantiates the same
   step-program abstraction.
 
-The mesh shape ``(d, t, s)`` is a tuned knob triple
-(``mesh_data``/``mesh_model``/``mesh_pipe`` in ``tune/knobs.py``): with no
-spec given the trainer applies the tuning DB (``tune.maybe_apply``) and
-reads ``DL4J_TPU_MESH_*`` — the fit choke point for PR 9's
-successive-halving search. Compressed gradient exchange (PR 3) composes on
-the pure-data mesh via the explicit shard_map exchange
-(``compress=True``); see docs/PARALLELISM.md for why the compressed DCN
+With no spec given the trainer reads the mesh shape ``(d, t, s)`` from
+``DL4J_TPU_MESH_DATA`` / ``_MODEL`` / ``_PIPE``. Compressed gradient
+exchange (PR 3) composes on the pure-data mesh via the explicit shard_map
+exchange (``compress=True``); see docs/PARALLELISM.md for why the compressed DCN
 tier and the in-jit GSPMD tiers are mutually exclusive per axis.
 """
 
@@ -138,8 +135,7 @@ class MeshTrainer:
     state and the weight update sharded over every spare axis.
 
     ``spec=None`` resolves the mesh shape from the ``DL4J_TPU_MESH_*``
-    knobs (after applying the tuning DB when ``DL4J_TPU_TUNE`` is set) —
-    unset knobs mean pure data parallelism over all devices.
+    knobs — unset knobs mean pure data parallelism over all devices.
 
     ``compress=True`` routes through the explicit shard_map exchange
     (``parallel/grads.py``) with PR 3 gradient compression — only legal on
@@ -149,17 +145,9 @@ class MeshTrainer:
 
     def __init__(self, model, spec: Optional[MeshSpec] = None, *,
                  devices=None, compress: bool = False):
-        import os as _os
-
         self.model = model
         devices = list(devices) if devices is not None else jax.devices()
         if spec is None:
-            if _os.environ.get("DL4J_TPU_TUNE"):
-                # fit choke point for the mesh knobs: the persisted tuner
-                # winner lands in DL4J_TPU_MESH_* BEFORE the shape is read
-                from deeplearning4j_tpu import tune as _tune
-
-                _tune.maybe_apply(model, "fit")
             d, t, s = mesh_shape_from_env(len(devices))
             spec = MeshSpec(data=d, model=t, pipe=s)
         self.spec = spec

@@ -69,8 +69,8 @@ class ParallelWrapper:
         # Explicit-exchange switches (parallel/grads.py): kwargs win, then
         # env (DL4J_TPU_GRAD_COMPRESS / DL4J_TPU_SHARDED_UPDATE /
         # DL4J_TPU_COMPRESS_THRESHOLD), default OFF — on a single
-        # ICI-connected slice the implicit dense psum is already optimal;
-        # see docs/PERF.md "Compressed collectives & sharded weight updates".
+        # ICI-connected slice the implicit dense psum is already optimal
+        # (parallel/grads.py's docstring).
         if grad_compress is None:
             grad_compress = _env_flag("DL4J_TPU_GRAD_COMPRESS")
         if sharded_update is None:

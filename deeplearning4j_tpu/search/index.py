@@ -69,10 +69,9 @@ def _env_int(name: str, default: int) -> int:
 @dataclass(frozen=True)
 class IndexConfig:
     """Build-time configuration. The ``ivf_nlist`` / ``ivf_nprobe`` /
-    ``search_batch_max`` knobs (tune/knobs.py, scope=serve) act here through
-    their env variables when the corresponding field is left at its
-    0/None sentinel — knobs act at BUILD time: a tuner trial rebuilds the
-    index in its fresh subprocess, it cannot re-shape a live one."""
+    ``search_batch_max`` knobs act here through their env variables when
+    the corresponding field is left at its 0/None sentinel — knobs act at
+    BUILD time: they cannot re-shape a live index."""
 
     dim: int
     name: str = "default"

@@ -214,9 +214,7 @@ class AdmissionController:
 @dataclass(frozen=True)
 class GenerateConfig:
     """The generative-serving knob surface (``DL4J_TPU_GEN_*`` plus the
-    two tuner-searched decode knobs, docs/SERVING.md). Read AFTER
-    ``tune.maybe_apply(model, "serve")`` so ``DL4J_TPU_TUNE`` selections
-    for ``kv_page_tokens``/``decode_batch_max`` land here."""
+    two decode-shape knobs, docs/SERVING.md)."""
 
     decode_batch_max: int = 8    # token-level continuous-batch width cap
     kv_page_tokens: int = 64     # KV-cache page size (tokens per page)

@@ -79,26 +79,17 @@ class ModelRegistry:
         decode engine under ``name`` (``/v1/generate``).
 
         Same lifecycle as :meth:`register` but for the DECODE executable
-        set: tuner selections land first (``kv_page_tokens`` /
-        ``decode_batch_max`` are scope=serve knobs, so ``GenerateConfig``
-        is read AFTER ``tune.maybe_apply``), then the
-        :class:`~deeplearning4j_tpu.nn.decode.DecodeProgram`'s jitted step
-        registers on the model's AOT site table — a ``bundle`` restore
-        installs its serialized executables BEFORE ``warm`` enumerates the
+        set: the :class:`~deeplearning4j_tpu.nn.decode.DecodeProgram`'s
+        jitted step registers on the model's AOT site table — a ``bundle``
+        restore installs its serialized executables BEFORE ``warm`` enumerates the
         (batch x chunk x table) bucket grid, and the now-warm set persists
         back to the bundle, so a cold process streams tokens with zero
         request-path compiles."""
-        import os as _os
-
         from deeplearning4j_tpu.nn import aot
         from deeplearning4j_tpu.nn.decode import DecodeProgram
 
         if getattr(model, "params", None) is None:
             model.init()
-        if _os.environ.get("DL4J_TPU_TUNE"):
-            from deeplearning4j_tpu import tune as _tune
-
-            _tune.maybe_apply(model, "serve")
         cfg = config or GenerateConfig.from_env()
         program = DecodeProgram(
             model, page_tokens=cfg.kv_page_tokens,
@@ -148,9 +139,8 @@ class ModelRegistry:
         enumerates the (B, k, nprobe) signature grid — on a cold
         bundle-restored process every grid entry is a cache hit and the
         request path never compiles. The tier knobs (``ivf_nlist`` /
-        ``ivf_nprobe`` / ``search_batch_max``) act at index BUILD time, so
-        a tuner trial rebuilds in its subprocess; by registration the index
-        shape is already final."""
+        ``ivf_nprobe`` / ``search_batch_max``) act at index BUILD time: by
+        registration the index shape is already final."""
         from deeplearning4j_tpu.nn import aot
 
         restored = 0
@@ -207,13 +197,6 @@ class ModelRegistry:
 
         if getattr(model, "params", None) is None:
             model.init()
-        import os as _os
-
-        if _os.environ.get("DL4J_TPU_TUNE"):
-            # tuner winner must land before warm_serving compiles buckets
-            from deeplearning4j_tpu import tune as _tune
-
-            _tune.maybe_apply(model, "serve")
         restored = 0
         warmed = 0
         warm_dt = 0.0

@@ -135,7 +135,7 @@ class MaxParamNormIterationTerminationCondition(IterationTerminationCondition):
     non-finite). A stable log-softmax loss cannot overflow, and a huge
     divergent step can even land a toy model on a perfect separator with
     score exactly 0.0 — the parameter norm is the signal that still
-    explodes when the score cannot (docs/TEST_DEBT.md, divergence row).
+    explodes when the score cannot.
 
     ``needs_model = True``: the iteration guard passes the live model so the
     norm is read from ``model.params``. One scalar host sync per iteration,

@@ -8,7 +8,7 @@ executables by HLO fingerprint; pointing every entry point at ONE fixed
 directory makes the second process's compiles cache reads.
 
 Global config mutation never happens on library import: the entry points
-(``chip_smoke.py``, ``bench.py``, ``python -m deeplearning4j_tpu.train``,
+(``chip_smoke.py``, ``python -m deeplearning4j_tpu.train``,
 ``python -m deeplearning4j_tpu.serve``) call :func:`enable_compilation_cache`
 first thing.
 """

@@ -20,7 +20,7 @@ the pinned jaxlib:
        ~4 out of 5 isolated launches (measured), too flaky to hold a
        tier-1 gate even behind retries.
 Both live on verbatim in tools/repro_gloo_preamble.py — exit 2 there is
-the trigger to restore them here (docs/TEST_DEBT.md).
+the trigger to restore them here.
 """
 
 import json

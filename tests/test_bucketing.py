@@ -398,29 +398,3 @@ class TestSatellites:
         monkeypatch.setattr(builtins, "__import__", no_resource)
         html = UIServer().render_system_html()
         assert "n/a" in html
-
-
-class TestServingBenchSmoke:
-    @pytest.mark.slow
-    def test_bench_serving_smoke(self, monkeypatch):
-        import importlib.util
-        import os as _os
-        import sys as _sys
-
-        monkeypatch.setenv("BENCH_SMOKE", "1")
-        root = _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__)))
-        spec = importlib.util.spec_from_file_location(
-            "bench_smoke_mod", _os.path.join(root, "bench.py"))
-        mod = importlib.util.module_from_spec(spec)
-        _sys.modules["bench_smoke_mod"] = mod
-        try:
-            spec.loader.exec_module(mod)
-            out = mod.bench_serving_mixed()
-        finally:
-            _sys.modules.pop("bench_smoke_mod", None)
-        assert out["metric"] == "serving_mixed_batch_throughput"
-        assert out["value"] > 0
-        assert out["distinct_request_sizes"] >= 8
-        # exactly one trace/compile per warmed bucket, none in the timed run
-        assert out["observed_compiles"] == out["buckets_warmed"]
-        assert out["compiles_after_warmup"] == 0

@@ -152,9 +152,16 @@ def test_kill_one_worker_shrinks_and_continues(baseline):
 def test_killed_worker_rejoins_bit_exact(baseline):
     """The launcher relaunches the killed worker; it re-leases under a new
     incarnation, the survivors grow the view back, and the handoff restores
-    bit-exact state on BOTH workers (including the rejoined one)."""
+    bit-exact state on BOTH workers (including the rejoined one).
+
+    The survivor needs 2 s (the lease TTL) to shrink and a moment for the
+    three steps that are left; a relaunched interpreter needs longer than
+    that to import jax. So the survivor is held at iteration 4 (one stall,
+    rank 0 only) until the newcomer has its lease: without it the job is
+    over before anyone can rejoin, and nothing of the grow path runs."""
     out = _launch(baseline["root"], "rejoin", workers=2, world=2,
-                  chaos="host_kill@iter:3:rank1", relaunch=1)
+                  chaos="host_kill@iter:3:rank1,slow_iter@iter:4:rank0:15",
+                  relaunch=1)
     ref = _result(baseline["out1"])
     for wid in ("w0", "w1"):
         got = _result(out, wid)

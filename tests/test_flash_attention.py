@@ -595,7 +595,7 @@ class TestBlockChooser:
         (1000, 64, 4, True, 1),
         (1024, 64, 4, False, 1),   # gpt2m-f32-train-b8-t1024, transposed
         (2048, 128, 2, True, 1),   # chip_smoke.py's kernels phase
-        (8192, 64, 2, False, 1),   # docs/PERF.md's long-context envelope
+        (8192, 64, 2, False, 1),   # the long-context envelope
         # two heads of 64 (four of 32) a lane block: the same blocks
         (256, 64, 4, False, 2),    # gpt2m-f32-train-b32-t256 as it runs
         (1024, 64, 4, False, 2),   # gpt2m-f32-train-b8-t1024 as it runs

@@ -322,23 +322,24 @@ class TestHttpPropagation:
         assert echoed.trace_id == inbound.trace_id
         assert echoed.span_id != inbound.span_id
         assert body["request_id"] == inbound.trace_id
-        # the trace resolved through the scheduler into the dispatch span
-        dispatch = [r for r in obs.recent_spans()
-                    if r["span"] == "serve.dispatch"]
-        assert dispatch
-        assert inbound.trace_id in dispatch[-1]["attrs"]["traces"]
-        # and the front-door span itself is stamped; the server closes it
-        # after the reply's last byte is out (serve/httpcommon.py), so the
-        # client may be here first: wait for it
-        deadline = time.monotonic() + 5.0
-        while True:
-            http_spans = [r for r in obs.recent_spans()
-                          if r["span"] == "http.request"
-                          and r.get("trace_id") == inbound.trace_id]
-            if http_spans or time.monotonic() > deadline:
-                break
-            time.sleep(0.01)
-        assert http_spans
+        # the trace resolved through the scheduler into the dispatch span,
+        # and the front-door span itself is stamped. Both close on the
+        # server's threads after the reply's last byte is out (the worker
+        # fulfils the future inside serve.dispatch, serve/httpcommon.py
+        # writes inside http.request), so the client may be here first:
+        # wait for each
+        def closed(match):
+            deadline = time.monotonic() + 5.0
+            while True:
+                found = [r for r in obs.recent_spans() if match(r)]
+                if found or time.monotonic() > deadline:
+                    return found
+                time.sleep(0.01)
+
+        assert closed(lambda r: r["span"] == "serve.dispatch"
+                      and inbound.trace_id in r["attrs"]["traces"])
+        assert closed(lambda r: r["span"] == "http.request"
+                      and r.get("trace_id") == inbound.trace_id)
 
     def test_trace_minted_when_absent(self, server):
         x = np.zeros((2, 4), np.float32).tolist()

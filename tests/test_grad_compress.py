@@ -231,8 +231,8 @@ class TestShardedUpdateParity:
 class TestCompressedExchange:
     def test_compressed_mode_trains(self):
         """Ternary exchange with error feedback converges on the toy task
-        (threshold matched to the gradient scale; see docs/PERF.md for why
-        per-step transmitted magnitude is capped at the threshold)."""
+        (threshold matched to the gradient scale: per-step transmitted
+        magnitude is capped at the threshold)."""
         x, y = _data(64)
         m = _model(seed=9)
         pw = ParallelWrapper(m, mesh=make_mesh(MeshSpec(data=8)),

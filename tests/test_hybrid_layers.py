@@ -319,13 +319,12 @@ def test_hybrid_lm_builder_round_trips_and_names_its_layers():
         _tiny_lm(pattern="MXE")
 
 
-def test_recomputation_is_a_field_and_changes_no_number(monkeypatch):
+def test_recomputation_is_a_field_and_changes_no_number():
     """``remat`` comes from the configuration, and no environment variable
     is read in the new layers: the flag changes what is kept between the
     passes, not the loss nor a gradient."""
     from deeplearning4j_tpu.nn.model import MultiLayerNetwork
 
-    monkeypatch.setenv("DL4J_TPU_REMAT_BLOCKS", "1")      # not theirs to read
     ids = np.random.RandomState(0).randint(0, 50, (2, 24)).astype(np.int32)
     out = []
     for remat in (False, True):

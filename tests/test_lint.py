@@ -133,17 +133,6 @@ class TestRuleTruePositives:
         # plain dict lookups on the dispatch path stay allowed
         assert not _hits(fs, rule, "cost_analysis_bad.py", "step_ok")
 
-    def test_tuner_off_hot_path(self, fixture_findings):
-        fs = fixture_findings
-        rule = "tuner-off-hot-path"
-        assert _hits(fs, rule, "tuner_bad.py", "fit_batch")
-        assert _hits(fs, rule, "tuner_bad.py", "fit_measure")
-        assert _hits(fs, rule, "tuner_bad.py", "fit_halving")
-        # trial measurement inside a traced body
-        assert _hits(fs, rule, "tuner_bad.py", "step_traced.body")
-        # consulting the DB (maybe_apply) on the dispatch path stays legal
-        assert not _hits(fs, rule, "tuner_bad.py", "fit_ok")
-
     def test_step_wiring(self, fixture_findings):
         fs = fixture_findings
         rule = "step-wiring"
@@ -163,7 +152,6 @@ class TestRuleTruePositives:
             ("monotonic-clock", "clock_bad.py", "suppressed"),
             ("cost-analysis-off-hot-path", "cost_analysis_bad.py",
              "step_suppressed"),
-            ("tuner-off-hot-path", "tuner_bad.py", "fit_suppressed"),
             ("step-wiring", "step_wiring_bad.py", "make_step_suppressed"),
             ("use-after-donate", "donate_bad.py", "read_suppressed"),
             ("collective-consistency", "collective_bad.py",

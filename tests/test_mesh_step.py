@@ -22,8 +22,6 @@ from deeplearning4j_tpu.nn.step_program import (
 from deeplearning4j_tpu.parallel import (
     DataParallelStep, MeshSpec, MeshTrainer, make_mesh, shard_update_spec,
 )
-from deeplearning4j_tpu.tune import db as tune_db
-from deeplearning4j_tpu.tune import knobs as tune_knobs
 from deeplearning4j_tpu.utils import bucketing
 
 MESH_ENVS = ("DL4J_TPU_MESH_DATA", "DL4J_TPU_MESH_MODEL",
@@ -34,7 +32,6 @@ MESH_ENVS = ("DL4J_TPU_MESH_DATA", "DL4J_TPU_MESH_MODEL",
 def _clean_env(monkeypatch):
     for var in MESH_ENVS + (
             "DL4J_TPU_GRAD_ACCUM", "DL4J_TPU_CHAIN_STEPS",
-            "DL4J_TPU_TUNE", "DL4J_TPU_TUNE_DB",
             "DL4J_TPU_GRAD_COMPRESS", "DL4J_TPU_SHARDED_UPDATE"):
         monkeypatch.delenv(var, raising=False)
     bucketing.telemetry().reset()
@@ -301,7 +298,7 @@ class TestShardedUpdate:
 
 
 # ---------------------------------------------------------------------------
-# Mesh-shape knobs: env resolution, registry, tuned apply
+# Mesh-shape knobs: env resolution, the default
 # ---------------------------------------------------------------------------
 
 
@@ -325,30 +322,6 @@ class TestMeshKnobs:
         monkeypatch.setenv("DL4J_TPU_MESH_MODEL", "3")
         with pytest.raises(ValueError):
             mesh_shape_from_env(8)
-
-    def test_knobs_registered(self):
-        for name in ("mesh_data", "mesh_model", "mesh_pipe"):
-            k = tune_knobs.get(name)
-            assert k is not None, name
-            assert k.scope == "fit"
-            assert k.default == 0 and 0 in k.domain
-            # finite power-of-two domain derived from the device count
-            assert all(v == 0 or (v & (v - 1)) == 0 for v in k.domain)
-
-    def test_tuned_mesh_shape_applies(self, tmp_path, monkeypatch):
-        """A fresh DL4J_TPU_TUNE=auto trainer picks up the persisted (d,t,s)
-        winner through tune.maybe_apply at the fit choke point."""
-        model = _model(seed=41)
-        monkeypatch.setenv("DL4J_TPU_TUNE_DB", str(tmp_path / "tunedb.zip"))
-        monkeypatch.setenv("DL4J_TPU_TUNE", "auto")
-        db = tune_db.TuningDB(tmp_path / "tunedb.zip")
-        db.record(aot.model_signature(model),
-                  {"mesh_data": 2, "mesh_model": 2, "mesh_pipe": 2}, {}, 1,
-                  toolchain=aot.toolchain_fingerprint())
-        tr = MeshTrainer(model)  # spec=None → DB → DL4J_TPU_MESH_* → shape
-        assert (tr.shape[0], tr.shape[1], tr.shape[3]) == (2, 2, 2)
-        x, y = _data(64)
-        assert np.isfinite(float(tr.fit_batch(x, y)))
 
     def test_untuned_default_is_pure_dp(self):
         tr = MeshTrainer(_model(seed=43))

@@ -7,7 +7,7 @@ batch — the SPMD replacement for the reference's multi-node Spark masters
 The gloo TCP transport in the pinned jaxlib intermittently aborts a worker
 mid-collective (`op.preamble.length <= op.nbytes` and the follow-on
 connection-reset/heartbeat cascade on the surviving peer — pinned repro:
-tools/repro_gloo_preamble.py, taxonomy: docs/TEST_DEBT.md). That is an
+tools/repro_gloo_preamble.py). That is an
 upstream transport crash, not a parity property of this repo, so each
 scenario runs as its OWN 2-process group and retries ON THAT SIGNATURE
 ONLY: a crash re-runs one short scenario instead of the whole sequence,
@@ -113,7 +113,7 @@ def _run_scenario(tmp_path, scen):
             f"{outs[0][-2000:]}\n{outs[1][-2000:]}")
         assert attempt < _GROUP_ATTEMPTS, (
             f"upstream gloo transport crash on all {_GROUP_ATTEMPTS} "
-            f"attempts of scenario {scen} (docs/TEST_DEBT.md):\n"
+            f"attempts of scenario {scen} (tools/repro_gloo_preamble.py):\n"
             f"{outs[0][-2000:]}")
         print(f"gloo transport crash in {scen} (upstream, attempt "
               f"{attempt}) — relaunching the group")
@@ -224,7 +224,7 @@ def test_two_process_training_matches_single_process(tmp_path):
     # and cross-host ring attention (~4/5 of isolated launches) crash in
     # the upstream gloo TCP transport (`op.preamble.length <= op.nbytes`).
     # Pinned repro: tools/repro_gloo_preamble.py (exit 2 there = restore
-    # the scenarios here); docs/TEST_DEBT.md has the taxonomy. Both
+    # the scenarios here). Both
     # programs are verified single-process (tests/test_longcontext.py
     # runs the ring on the same data=1 x seq=8 mesh; tests/test_tp_hlo.py
     # the TP specs) — only their cross-host transport leg is pinned.

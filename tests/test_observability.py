@@ -297,7 +297,7 @@ class TestSnapshotRoundTrip:
         snap = obs.snapshot()
         assert set(snap) == {"metrics", "spans", "events", "bucketing",
                              "profile"}
-        assert set(snap["profile"]) == {"roofline", "sites", "utilization"}
+        assert set(snap["profile"]) == {"sites"}
         assert snap["bucketing"]["real_examples"] == 30
         assert snap["events"]["snap_check"] == 1
         assert snap["spans"]["unit"]["count"] == 1
@@ -333,7 +333,7 @@ class TestSnapshotRoundTrip:
 
 
 # ---------------------------------------------------------------------------
-# profiling: XLA cost models + roofline utilization (obs/profile.py)
+# profiling: XLA static cost models (obs/profile.py)
 # ---------------------------------------------------------------------------
 
 
@@ -384,23 +384,6 @@ class TestCostModels:
         # CPU backend provides memory_analysis: peak-HBM style fields ride
         if "argument_bytes" in entry:
             assert entry["argument_bytes"] > 0
-
-    def test_roofline_env_override_yields_mfu(self, monkeypatch):
-        monkeypatch.setenv("DL4J_TPU_PEAK_FLOPS", "1e12")
-        monkeypatch.setenv("DL4J_TPU_HBM_GBPS", "100")
-        monkeypatch.setenv("DL4J_TPU_CHAIN_STEPS", "0")
-        x, y = _toy_data()
-        model = MultiLayerNetwork(_mlp_conf()).init()
-        model.fit((x, y), epochs=1)
-        rep = obs.cost_report()
-        assert rep["roofline"]["source"] == "env"
-        assert rep["roofline"]["peak_bf16_flops"] == 1e12
-        util = rep["utilization"]["mln.step"]
-        assert util["span"] == "mln.fit_batch"
-        assert 0 < util["mfu"] < 1
-        assert util["membw_util"] > 0
-        mfu = obs.snapshot()["metrics"]["dl4j_mfu"]
-        assert any("site=mln.step" in k for k in mfu)
 
     def test_cost_report_survives_model_collection(self, monkeypatch):
         # exemplars weakref their jit: resolving after the model is gone

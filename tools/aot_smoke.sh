@@ -1,5 +1,5 @@
 #!/usr/bin/env bash
-# AOT cold-start smoke (docs/PERF.md): proves end to end, in one fresh
+# AOT cold-start smoke (nn/aot.py): proves end to end, in one fresh
 # process per phase (cold start IS a fresh process), that
 #   1. the executable-persistence re-validation harness passes on this
 #      backend (serialize -> deserialize -> execute, bitwise parity, run

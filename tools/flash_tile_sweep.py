@@ -21,8 +21,8 @@ what Mosaic allows at the same head width, not to adopt it.
 
 All variants of a shape run three times inside one trace, each call under a
 host annotation of its own; the device operations that start inside it are
-the call's (a stand-alone call is dispatch-bound on the host's clock,
-docs/PERF.md), least of three. ``--compile-only`` compiles every variant for
+the call's (a stand-alone call is dispatch-bound on the host's clock),
+least of three. ``--compile-only`` compiles every variant for
 a described v5e without a chip and prints which ones Mosaic refuses: no time
 comes out of that.
 """

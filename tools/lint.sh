@@ -9,8 +9,7 @@
 #   tools/lint.sh --changed               # pre-commit: changed files only
 #   tools/lint.sh --sarif out.sarif       # SARIF 2.1.0 log for CI upload
 #
-# Exit-code contract (asserted by tools/bench_smoke.sh, documented in
-# docs/LINT.md): 0 clean vs baseline, 1 new findings, 2 usage/parse/git
+# Exit-code contract (documented in docs/LINT.md): 0 clean vs baseline, 1 new findings, 2 usage/parse/git
 # error. Wire the pre-commit path with tools/pre-commit.sh.
 set -u
 cd "$(dirname "$0")/.."

@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Standalone repro: gloo TCP transport crash under multi-host
-collective-dense programs (docs/TEST_DEBT.md; quarantined out of
+collective-dense programs (quarantined out of
 tests/_multihost_worker.py scenarios 3 and 4).
 
 The bug: a 2-process CPU cluster (4 virtual devices each, gloo transport)
@@ -33,7 +33,7 @@ Exit codes:
   0  crash REPRODUCED in at least one scenario — the quarantines in
      tests/_multihost_worker.py must stay
   2  NOT reproduced (all scenarios finished with finite losses) — retire
-     the quarantines per the docs/TEST_DEBT.md entry
+     the quarantines in tests/test_multihost.py
   1  the probe itself failed (port/bootstrap trouble, not a verdict)
 
 Run on any host:
@@ -168,10 +168,10 @@ def main() -> int:
               "tests/_multihost_worker.py must stay. (The ring flavor is "
               "intermittent — a single completed launch does not retire "
               "it; only an all-scenarios-complete run exits 2, and "
-              "docs/TEST_DEBT.md asks for ~10 such runs.)")
+              "confirm over ~10 such runs.)")
         return 0
     print("\nNOT reproduced: every scenario completed. Retire the "
-          "quarantines per the docs/TEST_DEBT.md entry (confirm over "
+          "quarantines in tests/test_multihost.py (confirm over "
           "~10 consecutive runs first — the ring flavor is "
           "intermittent).")
     return 2

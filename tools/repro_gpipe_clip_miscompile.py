@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Standalone repro: GSPMD miscompiles gradient clipping fused into the
-gpipe step (docs/TEST_DEBT.md; workaround in parallel/gpipe.py
+gpipe step (workaround in parallel/gpipe.py
 make_train_step).
 
 The bug: when the nonlinear clip/renorm (gradient normalization) is traced
@@ -26,7 +26,7 @@ Exit codes:
   0  miscompile REPRODUCED (fused update inflated ~data*seq) — the
      eager-clip split in parallel/gpipe.py must stay
   2  NOT reproduced (updates match) — this XLA resolves the clip
-     correctly; retire the split per the TEST_DEBT.md entry
+     correctly; retire the split in parallel/gpipe.py
   1  the probe itself failed
 
 Run on any host (forces an 8-virtual-CPU-device mesh):
@@ -119,7 +119,7 @@ def main():
         return 0
     print("\nNOT reproduced: fused and split updates match — this XLA "
           "resolves the fused clip correctly. Retire the eager-clip split "
-          "per the docs/TEST_DEBT.md entry.")
+          "in parallel/gpipe.py make_train_step.")
     return 2
 
 

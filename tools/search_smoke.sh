@@ -15,7 +15,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
-export DL4J_TPU_AOT_BUNDLE=1   # CPU: persistence is opt-in (docs/PERF.md)
+export DL4J_TPU_AOT_BUNDLE=1   # CPU: persistence is opt-in (nn/aot.py)
 workdir=$(mktemp -d)
 trap 'rm -rf "$workdir"' EXIT
 

@@ -6,8 +6,8 @@
 #      an .aotbundle next to nothing-in-particular (a temp dir);
 #   2. a COLD process restores the bundle through the same registry.load
 #      call, serves a concurrent HTTP burst with ZERO request-path
-#      compiles, answers bit-exactly whether requests are coalesced or
-#      served one at a time, and under forced overload SHEDS (429/503 +
+#      compiles, answers each row as the warm process did (bit for bit)
+#      and, coalesced, as it does alone (to 1e-6: another bucket), and under forced overload SHEDS (429/503 +
 #      dl4j_shed_total) instead of queueing without bound.
 # The same two phases also carry the GENERATIVE tier: phase 1 warms the
 # bucketed KV-cache decode engine (decode.step executable set) for a
@@ -18,7 +18,7 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export JAX_PLATFORMS=${JAX_PLATFORMS:-cpu}
-export DL4J_TPU_AOT_BUNDLE=1   # CPU: persistence is opt-in (docs/PERF.md)
+export DL4J_TPU_AOT_BUNDLE=1   # CPU: persistence is opt-in (nn/aot.py)
 workdir=$(mktemp -d)
 trap 'rm -rf "$workdir"' EXIT
 
@@ -64,7 +64,9 @@ w = reg.load("cnn", FIXTURE, bundle=bundle)
 meta = reg.describe()[0]
 assert meta["warmed"] > 0, meta
 assert os.path.exists(bundle), "bundle not persisted"
-ref = np.asarray(w.submit(x))
+# row by row, as phase 2 serves them: the same bucket's executable on both
+# sides (another bucket's differs in the last bit on XLA:CPU)
+ref = np.concatenate([np.asarray(w.submit(x[i:i + 1])) for i in range(len(x))])
 np.save(os.path.join(os.path.dirname(bundle), "reference.npy"), ref)
 
 # generative tier: warm the decode executable set, persist, stream once
@@ -120,7 +122,9 @@ threads = [threading.Thread(target=burst, args=(i,)) for i in range(len(x))]
 for t in threads: t.start()
 for t in threads: t.join()
 for i in range(len(x)):
-    assert np.array_equal(outs[i][0], solo[i][0]), \
+    # a coalesced row ran in a wider bucket's executable: XLA:CPU's
+    # convolution there differs from the one-row one in the last bit
+    assert np.allclose(outs[i][0], solo[i][0], rtol=0, atol=1e-6), \
         f"row {i}: coalesced != individually served"
     assert np.array_equal(solo[i][0], ref[i]), \
         f"row {i}: cold restore != warm process"
@@ -182,8 +186,8 @@ assert gen_compiles == 0, \
 
 srv.stop()
 print(f"restored {meta['restored']} predict + {gmeta['restored']} decode "
-      f"executables; {len(x)} coalesced HTTP requests bit-exact vs solo "
-      f"and warm process; streaming generate bit-exact vs warm process; "
+      f"executables; {len(x)} coalesced HTTP requests equal to 1e-6 vs solo, "
+      f"solo bit-exact vs warm process; streaming generate bit-exact vs warm process; "
       f"0 request-path compiles (predict AND decode); overload shed "
       f"{shed_total} (burn rate {burn})")
 EOF
