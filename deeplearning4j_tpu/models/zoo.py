@@ -180,3 +180,53 @@ def HybridLM(pattern: str, vocab_size: int, d_model: int, max_len: int = 4096,
         seed=seed,
         dtype=dtype,
     )
+
+
+def LatentAttentionLM(vocab_size: int, d_model: int, n_layers: int,
+                      n_dense: int = 1, attention: dict = None,
+                      dense_width: int = 0,
+                      moe: dict = None, mtp_layers: int = 0,
+                      mtp_weight: float = 0.3, eps: float = 1e-6,
+                      remat: bool = False, updater=None, seed: int = 12345,
+                      dtype: str = "float32") -> MultiLayerConfiguration:
+    """A latent-attention sparse-expert language model of the DeepSeek-V2/V3
+    kind: an embedding, ``n_layers`` layers of two ``ResidualBlock``s each
+    (``x <- x + mixer(RMSNorm(x))``: a ``MultiHeadLatentAttention`` built from
+    ``attention``, rotary positions inside it, then a feed-forward: a
+    ``GatedMLP`` of ``dense_width`` in the first ``n_dense`` layers, a gated
+    ``SparseMoE`` from ``moe`` after them, which says which experts this
+    model holds: ``held_start`` / ``n_held``), and an ``MTPOutputLayer``: the
+    final RMSNorm, an untied bias-free head and, with ``mtp_layers = 1``, one
+    MTP module (a block of the expert kind) whose loss joins the step's at
+    ``mtp_weight``. ``remat`` recomputes each block, and each head's logits,
+    in the backward pass."""
+    from deeplearning4j_tpu.nn.layers import (
+        EmbeddingSequence,
+        GatedMLP,
+        MTPOutputLayer,
+        MultiHeadLatentAttention,
+        ResidualBlock,
+        SparseMoE,
+    )
+
+    if not 0 <= n_dense <= n_layers:
+        raise ValueError(f"n_dense={n_dense} of n_layers={n_layers}")
+    mla = lambda: MultiHeadLatentAttention(eps=eps, **(attention or {}))  # noqa: E731
+    experts = lambda: SparseMoE(gated=True, **(moe or {}))       # noqa: E731
+    block = lambda mixer: ResidualBlock(mixer=mixer, eps=eps, remat=remat)  # noqa: E731
+    layers = [EmbeddingSequence(n_in=vocab_size, n_out=d_model)]
+    for i in range(n_layers):
+        layers += [block(mla()), block(GatedMLP(width=dense_width)
+                                       if i < n_dense else experts())]
+    layers.append(MTPOutputLayer(
+        n_out=vocab_size, activation="softmax", loss="mcxent", eps=eps,
+        mtp_layers=mtp_layers, mtp_weight=mtp_weight, remat=remat,
+        attention=mla() if mtp_layers else None,
+        ffn=experts() if mtp_layers else None))
+    return MultiLayerConfiguration(
+        layers=tuple(layers),
+        input_type=InputType.recurrent(vocab_size),
+        updater=updater or {"type": "adam", "lr": 3e-4},
+        seed=seed,
+        dtype=dtype,
+    )

@@ -44,12 +44,14 @@ from deeplearning4j_tpu.nn.layers.convolution import (
 from deeplearning4j_tpu.nn.layers.normalization import BatchNorm, LayerNorm, LocalResponseNormalization, RMSNorm
 from deeplearning4j_tpu.nn.layers.attention import (
     GroupedQueryAttention,
+    MultiHeadLatentAttention,
     MultiHeadAttention,
     PositionalEmbedding,
     TransformerBlock,
 )
-from deeplearning4j_tpu.nn.layers.moe import MixtureOfExperts, SparseMoE
+from deeplearning4j_tpu.nn.layers.moe import GatedMLP, MixtureOfExperts, SparseMoE
 from deeplearning4j_tpu.nn.layers.residual import ResidualBlock
+from deeplearning4j_tpu.nn.layers.mtp import MTPOutputLayer
 from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer
 from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.objdetect import (
@@ -109,6 +111,9 @@ __all__ = [
     "TransformerBlock",
     "MixtureOfExperts",
     "SparseMoE",
+    "GatedMLP",
+    "MultiHeadLatentAttention",
+    "MTPOutputLayer",
     "ResidualBlock",
     "Mamba2Mixer",
     "GroupedQueryAttention",

@@ -27,6 +27,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deeplearning4j_tpu.nn import initializers
 from deeplearning4j_tpu.nn.config import LayerConfig, register_layer
@@ -365,3 +366,128 @@ class GroupedQueryAttention(LayerConfig):
                                       use_flash=self.use_flash)
             out = core._attend(q, k, v, kmask)               # [B, T, H, D]
             return out.reshape(B, T, H * D) @ params["Wo"], state
+
+
+def rotary(x, positions, *, width: int, theta: float = 10000.0):
+    """Rotary position embedding over the lanes of ``x`` [..., T, n*width]:
+    each run of ``width`` lanes (a head) is rotated pair by pair, the
+    adjacent lanes ``(2i, 2i+1)`` of position ``t`` by the angle ``t *
+    theta^(-2i/width)`` (GPT-J, DeepSeek's ``rope_interleave``).
+    ``positions`` [T] or [..., T]. A partial rotation is the caller's slice:
+    hand over the lanes that turn.
+
+    Written over whole lanes: a lane's partner is a roll by one lane chosen
+    by a mask, so no ``[.., width/2, 2]`` view with a minor dimension of 2 is
+    made."""
+    lane, heads = np.arange(width), x.shape[-1] // width
+    inv = np.power(float(theta), -np.arange(0, width, 2) / width).astype(
+        np.float32)
+    first = lane % 2 == 0
+    # one head's angles [.., T, width], repeated over the heads' lanes
+    ang = positions.astype(jnp.float32)[..., None] * inv[lane // 2]
+    sign = np.where(first, -1.0, 1.0).astype(np.float32)
+    reps = (1,) * (ang.ndim - 1) + (heads,)
+    cos, sin = jnp.tile(jnp.cos(ang), reps), jnp.tile(jnp.sin(ang) * sign, reps)
+    xf = x.astype(jnp.float32)
+    partner = jnp.where(np.tile(first, heads), jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
+@register_layer("latent_attention")
+@dataclass
+class MultiHeadLatentAttention(LayerConfig):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section
+    2.1) over [B, T, C], causal, bias-free; no residual and no pre-norm of
+    its own: wrap it in a ``ResidualBlock``.
+
+    Queries and key-values pass through low-rank latents, each with an
+    RMSNorm: ``c_q = RMSNorm(x W_dq)`` (``q_rank``), ``[c_kv | k_r] = x W_dkv``
+    (``kv_rank`` and one rotary key of ``rope_dim`` a token, shared by every
+    head), ``c_kv <- RMSNorm(c_kv)``.
+    A head has a key part of ``nope_dim`` without position and a rotary part
+    of ``rope_dim``; values are ``v_dim`` wide; scores are ``(q_nope . k_nope
+    + q_rope . k_rope) / sqrt(nope_dim + rope_dim)``.
+
+    Parameters as the kernels read their products: ``Wuq_n`` [q_rank,
+    H*nope_dim] and ``Wuq_r`` [q_rank, H*rope_dim] are the query
+    up-projection's columns by part (a published ``[H, nope | rope]`` matrix
+    is split by columns); ``Wukv`` [kv_rank, H*(nope_dim + v_dim)] keeps head
+    ``h``'s ``[k_nope | v]`` together, as published. On the TPU the core is
+    ops/flash_mla.py's kernels where the widths fill lane blocks
+    (``heads_per_program``), else and elsewhere plain XLA over the full
+    score square."""
+
+    n_heads: int = 8
+    q_rank: int = 96
+    kv_rank: int = 64
+    nope_dim: int = 32
+    rope_dim: int = 16
+    v_dim: int = 32
+    rope_theta: float = 10000.0
+    eps: float = 1e-6
+    weight_init: Any = "xavier"
+    use_flash: Any = "auto"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        C, H = input_type.size, self.n_heads
+        ks = jax.random.split(key, 6)
+        init = lambda k, fi, fo: initializers.initialize(   # noqa: E731
+            self.weight_init, k, (fi, fo), fi, fo, dtype)
+        return {
+            "Wdq": init(ks[0], C, self.q_rank),
+            "q_norm": jnp.ones((self.q_rank,), dtype),
+            "Wuq_n": init(ks[1], self.q_rank, H * self.nope_dim),
+            "Wuq_r": init(ks[2], self.q_rank, H * self.rope_dim),
+            "Wdkv": init(ks[3], C, self.kv_rank + self.rope_dim),
+            "kv_norm": jnp.ones((self.kv_rank,), dtype),
+            "Wukv": init(ks[4], self.kv_rank, H * (self.nope_dim + self.v_dim)),
+            "Wo": init(ks[5], H * self.v_dim, C)}
+
+    def _core(self):
+        """The attention core for the (qn, qr, kv, kr) operands of
+        ops/flash_mla.py: the kernels on the TPU where the widths suit them
+        and no mesh partitions the step (``use_flash=True``: anywhere, in
+        the interpreter), else the XLA form."""
+        from deeplearning4j_tpu.ops import flash_mla
+        from deeplearning4j_tpu.parallel.context import partitioning_mesh
+
+        kw = dict(n_heads=self.n_heads,
+                  scale=1.0 / (self.nope_dim + self.rope_dim) ** 0.5)
+        on_tpu = jax.default_backend() == "tpu"
+        fits = flash_mla.heads_per_program(
+            self.n_heads, self.nope_dim, self.rope_dim, self.v_dim)
+        if (fits and partitioning_mesh() is None and (
+                self.use_flash is True or (self.use_flash == "auto" and on_tpu))):
+            return functools.partial(flash_mla.flash_mla, interpret=not on_tpu,
+                                     **kw)
+        return functools.partial(flash_mla.mla_attention_xla, **kw)
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+
+        x = self.maybe_dropout_input(x, train, rng)
+        B, T, _ = x.shape
+        turn = functools.partial(
+            rotary, positions=jnp.arange(T), width=self.rope_dim,
+            theta=self.rope_theta)
+        with jax.named_scope("mla"):
+            with jax.named_scope("q"):
+                c_q = rms_norm(x @ params["Wdq"], params["q_norm"], self.eps)
+                qn, qr = c_q @ params["Wuq_n"], c_q @ params["Wuq_r"]
+            with jax.named_scope("kv"):
+                down = x @ params["Wdkv"]
+                c_kv = rms_norm(down[..., :self.kv_rank], params["kv_norm"],
+                                self.eps)
+                kv = c_kv @ params["Wukv"]
+            with jax.named_scope("rope"):
+                qr, kr = turn(qr), turn(down[..., self.kv_rank:])
+            with jax.named_scope("core"):
+                kmask = (mask.reshape(B, T)
+                         if mask is not None and mask.ndim >= 2 else None)
+                o = self._core()(qn, qr, kv, kr, kmask=kmask)
+            with jax.named_scope("out"):
+                return o @ params["Wo"], state

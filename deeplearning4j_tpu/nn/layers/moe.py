@@ -109,6 +109,39 @@ class MixtureOfExperts(LayerConfig):
         return self.n_experts * jnp.sum(frac * prob)
 
 
+def gated_silu(a):
+    """``silu(gate) * up`` of ``a = [gate | up]``, halves of the last axis."""
+    F = a.shape[-1] // 2
+    return jax.nn.silu(a[..., :F]) * a[..., F:]
+
+
+@register_layer("gated_mlp")
+@dataclass
+class GatedMLP(LayerConfig):
+    """The gated feed-forward of the Llama/DeepSeek kind over [B, T, C],
+    bias-free: ``(silu(x W_g) * (x W_u)) W_o`` with ``Wi = [W_g | W_u]`` side
+    by side, one product (no residual, no pre-norm: wrap it in a
+    ``ResidualBlock``)."""
+
+    width: int = 0
+    weight_init: Any = "xavier"
+
+    def output_type(self, input_type: InputType) -> InputType:
+        return input_type
+
+    def init(self, key, input_type, dtype=jnp.float32):
+        C, F = input_type.size, self.width
+        ki, ko = jax.random.split(key)
+        init = lambda k, fi, fo: initializers.initialize(    # noqa: E731
+            self.weight_init, k, (fi, fo), fi, fo, dtype)
+        return {"Wi": init(ki, C, 2 * F), "Wo": init(ko, F, C)}
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        x = self.maybe_dropout_input(x, train, rng)
+        with jax.named_scope("mlp"):
+            return gated_silu(x @ params["Wi"]) @ params["Wo"], state
+
+
 # a step's counters of a SparseMoE layer, kept in its state under "stats" as
 # one float32 vector in this order (one transfer a layer when the host
 # fetches them) and added up by publish_stats as dl4j_moe_<key>_total{layer}
@@ -163,7 +196,9 @@ class SparseMoE(LayerConfig):
     buffer in the layer's state that no gradient moves), and weighs each by
     ``s_e / sum_chosen s`` (``norm_topk``) times ``routed_scaling``. An
     expert is ``relu(u W1_e)^2 W2_e``; the shared expert has the same form
-    and sees every token.
+    and sees every token. ``gated`` makes both ``(silu(u W_g) * (u W_u))
+    W2``, with ``W1 = [W_g | W_u]`` side by side (twice ``expert_width``
+    columns, one product).
 
     The layer holds experts ``held_start .. held_start + n_held - 1`` only
     (``n_held = 0`` means all of them): it routes over all ``n_experts`` and
@@ -194,10 +229,15 @@ class SparseMoE(LayerConfig):
     n_held: int = 0                 # 0: all of them
     routed_scaling: float = 1.0
     norm_topk: bool = True
+    gated: bool = False
     weight_init: Any = "xavier"
 
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
+
+    def _act(self, a):
+        """The expert's nonlinearity over its first product."""
+        return gated_silu(a) if self.gated else jnp.square(jax.nn.relu(a))
 
     def _held(self) -> int:
         return self.n_held or self.n_experts
@@ -211,10 +251,11 @@ class SparseMoE(LayerConfig):
         kr, k1, k2, ks1, ks2 = jax.random.split(key, 5)
         init = lambda k, shape: initializers.initialize(    # noqa: E731
             self.weight_init, k, shape, shape[-2], shape[-1], dtype)
+        up = 2 if self.gated else 1
         p = {"Wr": init(kr, (C, self.n_experts)),
-             "W1": init(k1, (E, C, F)), "W2": init(k2, (E, F, C))}
+             "W1": init(k1, (E, C, up * F)), "W2": init(k2, (E, F, C))}
         if self.shared_width:
-            p["Ws1"] = init(ks1, (C, self.shared_width))
+            p["Ws1"] = init(ks1, (C, up * self.shared_width))
             p["Ws2"] = init(ks2, (self.shared_width, C))
         return p
 
@@ -274,7 +315,7 @@ class SparseMoE(LayerConfig):
         tok = jnp.where(valid, pair // k, 0)
         w = jnp.where(valid, jnp.take(wflat, pair), 0.0).astype(u.dtype)
         xs = jnp.take(u, tok, axis=0)
-        h = jnp.square(jax.nn.relu(grouped_matmul(xs, w1, counts, impl=impl)))
+        h = self._act(grouped_matmul(xs, w1, counts, impl=impl))
         y = grouped_matmul(h, w2, counts, impl=impl) * w[:, None]
         out = jnp.zeros_like(u).at[tok].add(y)
         return (out, jnp.sum(valid).astype(jnp.float32),
@@ -315,7 +356,7 @@ class SparseMoE(LayerConfig):
                         (order, counts))
             if self.shared_width:
                 with jax.named_scope("shared"):
-                    h = jnp.square(jax.nn.relu(u @ params["Ws1"]))
+                    h = self._act(u @ params["Ws1"])
                     y = y + h @ params["Ws2"]
             pairs = jnp.sum(counts).astype(jnp.float32)
             stats = jnp.stack([pairs, jnp.max(counts).astype(jnp.float32),

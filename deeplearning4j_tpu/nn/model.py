@@ -442,8 +442,20 @@ class MultiLayerNetwork:
         out_layer = self.layers[-1]
         out_mask = lmask if lmask is not None else prop_mask
         with jax.named_scope("loss"):
+            shared = getattr(out_layer, "shared_params", dict)()
             with layer_scope(out_layer, len(self.layers) - 1):
-                loss = out_layer.score(params[-1], a, y, mask=out_mask, average=True)
+                if shared:
+                    # an output layer that reads other layers' parameters (a
+                    # second head over the embedding) declares them and is
+                    # handed them by reference: jax.grad sums the uses
+                    loss, out_state = out_layer.score_shared(
+                        params[-1], state[-1], a, y,
+                        shared={k: params[i] for k, i in shared.items()},
+                        mask=out_mask, train=train,
+                        rng=rngs[-1] if rngs is not None else None)
+                    new_state = new_state[:-1] + (out_state,)
+                else:
+                    loss = out_layer.score(params[-1], a, y, mask=out_mask, average=True)
             # Unconditional: wrapper layers (Bidirectional etc.) delegate to their
             # inner layer's l1/l2 even when the wrapper's own are zero.
             reg = sum(l.regularization_penalty(p) for l, p in zip(self.layers, params))

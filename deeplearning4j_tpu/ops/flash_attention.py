@@ -419,7 +419,7 @@ def _padded(T: int) -> int:
 
 
 def choose_blocks(kernel: str, Tq: int, Tk: int, D: int, item: int,
-                  has_kmask: bool = False, heads: int = 1):
+                  has_kmask: bool = False, heads: int = 1, working_set=None):
     """(bq, bk) for one of the three kernels, from what the call can see
     (``heads``: how many share its lane blocks, ``heads_per_block``).
     The grid side (q for forward and dq, k for dk/dv) is the whole padded
@@ -429,8 +429,12 @@ def choose_blocks(kernel: str, Tq: int, Tk: int, D: int, item: int,
     more than a smaller block (dk/dv at T = 8192: 7.4 ms at 512 x 512 under
     a raised limit, 11.7 ms at the 128 x 512 that fits the default), so
     blocks shrink only where the estimate passes ``_VMEM_MAX``, the loop
-    side first, since the grid side sets the number of programs."""
+    side first, since the grid side sets the number of programs.
+    ``working_set(kernel, bq, bk, q_pad, k_pad)``: another kernel family's
+    estimate in place of :func:`_working_set` (ops/flash_mla.py)."""
     q_pad, k_pad = _padded(Tq), _padded(Tk)
+    need = working_set or functools.partial(
+        _working_set, D=D, item=item, has_kmask=has_kmask, heads=heads)
 
     def sizes(n, whole):
         if n <= whole:
@@ -442,25 +446,25 @@ def choose_blocks(kernel: str, Tq: int, Tk: int, D: int, item: int,
              for bk in sizes(k_pad, 128 if on_q else _MAX_WHOLE)]
     cands.sort(key=lambda c: c if on_q else c[::-1], reverse=True)
     for bq, bk in cands:
-        if _VMEM_HEADROOM * _working_set(kernel, bq, bk, q_pad, k_pad, D,
-                                         item, has_kmask, heads) <= _VMEM_MAX:
+        if _VMEM_HEADROOM * need(kernel, bq, bk, q_pad, k_pad) <= _VMEM_MAX:
             return bq, bk
     return cands[-1]
 
 
 def _plan(kernels, Tq, Tk, D, item, has_kmask, block_q, block_k,
-          same_len=False, heads=1):
+          same_len=False, heads=1, working_set=None):
     """``({kernel: (bq, bk)}, q_pad, k_pad)`` for the kernels of one call.
     With no blocks given each kernel's are chosen from the shapes and the
     lengths are padded to what the chooser tiled; explicit blocks (both, or
     neither) are honoured as given, clamped to the length, and shared by
     the kernels. ``same_len``: self-attention, one padded length that both
-    blocks divide."""
+    blocks divide. ``working_set``: as :func:`choose_blocks` takes it."""
     if (block_q is None) != (block_k is None):
         raise ValueError("give both block_q and block_k, or neither "
                          f"(got block_q={block_q!r}, block_k={block_k!r})")
     if block_q is None:
-        blocks = {kn: choose_blocks(kn, Tq, Tk, D, item, has_kmask, heads)
+        blocks = {kn: choose_blocks(kn, Tq, Tk, D, item, has_kmask, heads,
+                                    working_set)
                   for kn in kernels}
         return blocks, _padded(Tq), _padded(Tk)
     bq, bk = min(block_q, max(Tq, 1)), min(block_k, max(Tk, 1))
@@ -476,8 +480,13 @@ def _compiler_params(kernel, bq, bk, q_pad, k_pad, D, item, has_kmask,
     with its headroom as ``vmem_limit_bytes``. Beyond ``_VMEM_MAX``
     (whole-K residency of T around 90K at D = 64 bfloat16) shard the
     sequence instead (ring attention, parallel/ring.py)."""
-    need = _working_set(kernel, bq, bk, q_pad, k_pad, D, item, has_kmask,
-                        heads)
+    return _vmem_params(_working_set(kernel, bq, bk, q_pad, k_pad, D, item,
+                                     has_kmask, heads))
+
+
+def _vmem_params(need: int) -> dict:
+    """The ``pallas_call`` keyword that raises Mosaic's VMEM limit for a
+    working set of ``need`` bytes; nothing while the default holds it."""
     if need <= _VMEM_SHARE * _VMEM_DEFAULT:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
@@ -870,11 +879,12 @@ def _flash_bwd_pallas(q, k, v, kmask, o, lse, g, H, causal: bool, block_q,
     return grads
 
 
-def _reference(q, k, v, causal: bool, kmask=None):
+def _reference(q, k, v, causal: bool, kmask=None, scale=None):
     """The same math in plain XLA ops — used by the equivalence tests.
     Matches parallel/ring.py local_attention semantics incl. the
-    fully-masked-row clamp."""
-    scale = 1.0 / (q.shape[-1] ** 0.5)
+    fully-masked-row clamp. ``v`` may have a width of its own; ``scale``
+    defaults to ``1 / sqrt(q's width)``."""
+    scale = 1.0 / (q.shape[-1] ** 0.5) if scale is None else scale
     s = jnp.einsum("bqhd,bkhd->bhqk",
                    q.astype(jnp.float32), k.astype(jnp.float32)) * scale
     if causal:

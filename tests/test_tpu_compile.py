@@ -396,3 +396,109 @@ def test_grouped_matmul_compiles_at_published_widths():
         # dw of the stack as the kernels take it: [8 * 1856, 2688] for both
         assert "f32[14848,2688]" in hlo
         assert not re.search(r"= f32\[8,\d+,\d+\]\S* copy\(", hlo)
+
+
+# ---------------------------------------------------------------------------
+# latent-attention flash kernels (ops/flash_mla.py)
+# ---------------------------------------------------------------------------
+
+
+def _custom_calls(hlo: str) -> list:
+    """``<instruction name> tpu_custom_call <result types>`` of every Mosaic
+    call, as benchmark/harness/trace.py names a trace's events."""
+    import re
+
+    out = []
+    for l in hlo.splitlines():
+        if " custom-call(" in l and "tpu_custom_call" in l:
+            name, types = l.split(" custom-call(")[0].strip().lstrip(
+                "%").split(" = ")
+            out.append(f"{name} tpu_custom_call "
+                       + re.sub(r"\{[^{}]*\}", "", types))
+    return out
+
+
+def _roofline_patterns() -> dict:
+    import json
+    import os
+
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics")
+    return {f[:-5]: json.load(open(os.path.join(metrics, f)))["patterns"]
+            for f in sorted(os.listdir(metrics)) if f.endswith("_roofline.json")}
+
+
+def test_mla_flash_compiles_at_published_widths_and_is_found_by_name():
+    """Forward, dq and dk/dv at 32 heads of 128 | 64 score lanes and 128
+    value lanes, T = 8192 float32 (the benchmark cell's call), compile for
+    v5e under their chosen blocks and a raised VMEM limit; each call's event
+    name is found by the new roofline file meant for it and by no other
+    start-anchored pattern."""
+    import re
+
+    from deeplearning4j_tpu.ops.flash_mla import flash_mla
+
+    H, T = 32, 8192
+
+    def loss(qn, qr, kv, kr):
+        return _f32sum(flash_mla(qn, qr, kv, kr, n_heads=H, scale=192 ** -0.5))
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                   _sds((1, T, H * 128), jnp.float32),
+                   _sds((1, T, H * 64), jnp.float32),
+                   _sds((1, T, H * 256), jnp.float32),
+                   _sds((1, T, 64), jnp.float32))
+    calls = _custom_calls(hlo)
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "mla_flash_bwd_dkv_h2_q512_k512", "mla_flash_bwd_dq_h2_q512_k512",
+        "mla_flash_fwd_h2_q512_k512"], calls
+    anchored = {k: [p for p in v if p.startswith("^")]
+                for k, v in _roofline_patterns().items()}
+    assert {k for k, v in anchored.items() if v} == {
+        "mla_flash_fwd_roofline", "mla_flash_bwd_roofline"}
+    for c in calls:
+        hit = {k for k, v in anchored.items()
+               if any(re.search(p, c) for p in v)}
+        want = ("mla_flash_fwd_roofline" if c.startswith("mla_flash_fwd")
+                else "mla_flash_bwd_roofline")
+        assert hit == {want}, (c, hit)
+    # no operand is padded or repeated on its way in: the step's only
+    # arrays of the rotary key's or a query part's shape are the arguments
+    # and the gradients
+    assert "f32[1,8192,32,64]" not in hlo and "f32[1,8192,6144]" not in hlo
+
+
+FLASH_NAMES = [
+    pytest.param("pairs", (8, 1024, 16, 64), "_h2_q1024_k512", "_h2_q512_k1024",
+                 id="D64-pairs"),
+    pytest.param("heads", (1, 4096, 32, 128), "_h1_q512_k512", "_h1_q512_k512",
+                 id="D128"),
+    pytest.param("fused", (8, 1024, 16, 64), "_h2_q1024_k512", "_h2_q512_k1024",
+                 id="fused-qkv"),
+]
+
+
+@pytest.mark.parametrize("kind,shape,on_q,on_k", FLASH_NAMES)
+def test_existing_flash_calls_keep_their_kernel_names(kind, shape, on_q, on_k):
+    """The calls the three accepted cells make (two heads of 64 a lane block,
+    one head of 128, the fused qkv projection) lower to the kernels, heads a
+    block and blocks they had before ops/flash_mla.py came, and no new
+    pattern file finds them."""
+    import re
+
+    from deeplearning4j_tpu.ops.flash_attention import flash_attention_qkv
+
+    B, T, H, D = shape
+    if kind == "fused":
+        hlo = _compile(jax.grad(lambda x: _f32sum(flash_attention_qkv(
+            x, H, causal=True))), _sds((B, T, 3 * H * D), jnp.float32))
+    else:
+        hlo = _compile(jax.grad(lambda q, k, v: _f32sum(flash_attention(
+            q, k, v, causal=True)), argnums=(0, 1, 2)),
+            *[_sds(shape, jnp.float32)] * 3)
+    calls = _custom_calls(hlo)
+    assert sorted(c.split(".")[0] for c in calls) == sorted([
+        "flash_fwd" + on_q, "flash_bwd_dq" + on_q, "flash_bwd_dkv" + on_k]), calls
+    new = [p for k, v in _roofline_patterns().items() if k.startswith("mla_")
+           for p in v]
+    assert new and not [c for c in calls for p in new if re.search(p, c)]
