@@ -13,15 +13,25 @@ import jax.numpy as jnp
 from deeplearning4j_tpu.nn.config import LayerConfig, register_layer
 from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+from deeplearning4j_tpu.ops.flash_attention import ATTN_LSE, ATTN_OUT
 
 
 @register_layer("residual_block")
 @dataclass
 class ResidualBlock(LayerConfig):
-    """``x + mixer(RMSNorm(x))``. ``remat=True`` recomputes the whole layer
-    in the backward pass (``jax.checkpoint``): only the layer's input is kept
-    between the passes. The mixer's state (an expert layer's routing bias and
-    load counters) is this layer's state."""
+    """``x + mixer(RMSNorm(x))``. ``remat=True`` recomputes the layer in the
+    backward pass (``jax.checkpoint``): between the passes it keeps the
+    layer's input and, where the mixer runs a flash attention kernel
+    (``ops/flash_attention.py``, ``ops/flash_mla.py``), the kernel's result
+    and its row statistic, which the kernel's backward reads, so the kernel
+    runs once a step and not twice. A mixer that calls neither kernel (a
+    state-space mixer, an expert layer, a gated MLP, attention on its XLA
+    path) has nothing named and keeps its input alone. Keeping the result
+    costs no device time, only bytes between the passes: where ``H * Dv``
+    equals the model width an attention block keeps twice its input where it
+    kept once, and where ``H * Dv`` is twice the width (JoyAI-LLM-Flash)
+    three times. The mixer's state (an expert layer's routing bias and load
+    counters) is this layer's state."""
 
     mixer: Any = None           # a LayerConfig
     eps: float = 1e-5
@@ -55,5 +65,7 @@ class ResidualBlock(LayerConfig):
             return xx + y, new_st
 
         if self.remat:
-            body = jax.checkpoint(body)
+            body = jax.checkpoint(
+                body, policy=jax.checkpoint_policies.save_only_these_names(
+                    ATTN_OUT, ATTN_LSE))
         return body(params, state, x, rng, mask)

@@ -62,6 +62,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
@@ -80,6 +81,12 @@ _VMEM_MAX = 100 << 20
 _NAMES = types.MappingProxyType(    # read under jit: not to be mutated
     {"fwd": "flash_fwd", "dq": "flash_bwd_dq", "dkv": "flash_bwd_dkv"})
 _STRAIGHT = 2       # tiles of a statically known count emitted without a loop
+# What a forward rule calls the two results its backward reads again, here and
+# in ops/flash_mla.py: a ``jax.checkpoint`` whose policy saves these names
+# (``ResidualBlock.remat``) keeps them and does not run the kernel a second
+# time. Outside a checkpoint the name is the identity and lowers to nothing.
+ATTN_OUT = "attn_out"
+ATTN_LSE = "attn_lse"
 
 
 def _cdiv(a, b):
@@ -939,6 +946,8 @@ def _flash_fwd(q, k, v, kmask, H, causal, block_q, block_k, interpret, bwd):
     if bwd == "pallas":
         out, lse = _flash_raw(q, k, v, kmask, H, causal, block_q, block_k,
                               interpret, with_lse=True)
+        out = checkpoint_name(out, ATTN_OUT)
+        lse = checkpoint_name(lse, ATTN_LSE)
         return out, (q, k, v, kmask, out, lse)
     # the xla fallback exists for memory-constrained cases: don't burden it
     # with the out/lse residuals it never reads

@@ -41,12 +41,14 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.flash_attention import (
-    _NEG_BIG, _aligned, _and, _delta_rows, _dot_nt, _key_ranges, _lane,
-    _pad_km, _pad_rows, _query_ranges, _tiles, _vmem_params)
+    ATTN_LSE, ATTN_OUT, _NEG_BIG, _aligned, _and, _delta_rows, _dot_nt,
+    _key_ranges, _lane, _pad_km, _pad_rows, _query_ranges, _tiles,
+    _vmem_params)
 from deeplearning4j_tpu.ops.flash_attention import _plan as _fa_plan
 
 _NAMES = types.MappingProxyType(
@@ -427,6 +429,7 @@ def _flash_mla_fwd(qn, qr, kv, kr, kmask, m, causal, scale, block_q, block_k,
                    interpret):
     o, lse = _fwd(qn, qr, kv, kr, kmask, m, causal, scale, block_q, block_k,
                   interpret)
+    o, lse = checkpoint_name(o, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     return o, (qn, qr, kv, kr, kmask, o, lse)
 
 

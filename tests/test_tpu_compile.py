@@ -302,7 +302,8 @@ def test_hybrid_lm_step_compiles_at_published_widths(monkeypatch):
     """One layer of each kind of the hybrid stack (``M``, ``E``, ``*``) at
     the benchmark configuration's widths, T = 4096, per-layer recomputation
     on: the train step compiles for v5e; the attention layer takes the three
-    flash kernels (the forward twice, once recomputed), the expert layer the
+    flash kernels (each once: the recomputed layer keeps the forward kernel's
+    result and does not run it again), the expert layer the
     grouped-matmul kernels (two products forward, two recomputed, two ``dx``
     and two ``dw``, in each of its two buffer sizes), and they are the
     step's only custom calls. None of the ``moe_gmm_*`` calls has a result
@@ -343,10 +344,10 @@ def test_hybrid_lm_step_compiles_at_published_widths(monkeypatch):
     (moe,) = [l.mixer for l in model.layers
               if type(getattr(l, "mixer", None)).__name__ == "SparseMoE"]
     sizes = len(moe.row_caps(4096))
-    assert len(flash) == 4 and sizes == 2
+    assert len(flash) == 3 and sizes == 2
     assert [len(gmm[k]) for k in ("fwd", "dx", "dw")] == [
         4 * sizes, 2 * sizes, 2 * sizes]
-    assert len(calls) == 4 + 8 * sizes
+    assert len(calls) == 3 + 8 * sizes
     lone_rank3 = [c for c in calls if re.search(
         r"= \w+\[\d+,\d+,\d+\](\{[^{}]*\})?$", c.strip())]
     assert len(lone_rank3) == 1 and "flash_bwd_dq" in lone_rank3[0], lone_rank3
@@ -466,6 +467,34 @@ def test_mla_flash_compiles_at_published_widths_and_is_found_by_name():
     # arrays of the rotary key's or a query part's shape are the arguments
     # and the gradients
     assert "f32[1,8192,32,64]" not in hlo and "f32[1,8192,6144]" not in hlo
+
+
+def test_a_recomputed_latent_attention_block_runs_each_kernel_once(monkeypatch):
+    """One ``ResidualBlock`` around latent attention at the benchmark
+    configuration's widths, T = 8192 float32, ``remat=True``, forward and
+    backward for v5e: the recomputed layer keeps the forward kernel's result
+    and its row statistic, so the program holds each of the three kernels
+    once."""
+    from deeplearning4j_tpu.nn.input_type import InputType
+    from deeplearning4j_tpu.nn.layers import (
+        MultiHeadLatentAttention, ResidualBlock)
+
+    T, C = 8192, 2048
+    block = ResidualBlock(remat=True, eps=1e-6, mixer=MultiHeadLatentAttention(
+        n_heads=32, q_rank=1536, kv_rank=512, nope_dim=128, rope_dim=64,
+        v_dim=128, rope_theta=32e6))
+    params = jax.eval_shape(lambda: block.init(
+        jax.random.PRNGKey(0), InputType.recurrent(C, T)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def loss(p, x):
+        return _f32sum(block.apply(p, {}, x, train=True)[0])
+
+    hlo = _compile(jax.grad(loss, argnums=(0, 1)), jax.tree_util.tree_map(
+        lambda a: _sds(a.shape, a.dtype), params), _sds((1, T, C), jnp.float32))
+    assert sorted(c.split(".")[0].split(" ")[0] for c in _custom_calls(hlo)) == [
+        "mla_flash_bwd_dkv_h2_q512_k512", "mla_flash_bwd_dq_h2_q512_k512",
+        "mla_flash_fwd_h2_q512_k512"]
 
 
 FLASH_NAMES = [
