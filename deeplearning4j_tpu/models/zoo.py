@@ -230,3 +230,57 @@ def LatentAttentionLM(vocab_size: int, d_model: int, n_layers: int,
         seed=seed,
         dtype=dtype,
     )
+
+
+def ShortConvLM(layer_types, vocab_size: int, d_model: int, n_dense: int = 0,
+                attention: dict = None, conv: dict = None,
+                dense_width: int = 0, moe: dict = None, eps: float = 1e-5,
+                remat: bool = False, updater=None, seed: int = 12345,
+                dtype: str = "float32") -> MultiLayerConfiguration:
+    """A gated-short-convolution / attention sparse-expert language model of
+    the LFM2 kind: an embedding, one layer per entry of ``layer_types`` made
+    of two ``ResidualBlock``s (``x <- x + mixer(RMSNorm(x))``: an operator,
+    ``"conv"`` a ``ShortConvMixer`` built from ``conv``, ``"full_attention"``
+    a ``GroupedQueryAttention`` from ``attention`` (which says whether q and
+    k are normed and turned by their positions); then a feed-forward, a
+    ``GatedMLP`` of ``dense_width`` in the first ``n_dense`` layers, a gated
+    ``SparseMoE`` from ``moe`` after them, which says which experts this
+    model holds), and an ``MTPOutputLayer`` without an MTP module: the final
+    RMSNorm and a bias-free head tied to the embedding. ``remat`` recomputes
+    each block, and the head's logits, in the backward pass."""
+    from deeplearning4j_tpu.nn.layers import (
+        EmbeddingSequence,
+        GatedMLP,
+        GroupedQueryAttention,
+        MTPOutputLayer,
+        ResidualBlock,
+        ShortConvMixer,
+        SparseMoE,
+    )
+
+    operators = {
+        "conv": lambda: ShortConvMixer(**(conv or {})),
+        "full_attention": lambda: GroupedQueryAttention(eps=eps, **(attention or {}))}
+    layer_types = tuple(layer_types)
+    unknown = set(layer_types) - set(operators)
+    if unknown or not layer_types:
+        raise ValueError(f"layer_types {layer_types!r}: entries are "
+                         f"{sorted(operators)}")
+    if not 0 <= n_dense <= len(layer_types):
+        raise ValueError(f"n_dense={n_dense} of {len(layer_types)} layers")
+    block = lambda mixer: ResidualBlock(mixer=mixer, eps=eps, remat=remat)  # noqa: E731
+    layers = [EmbeddingSequence(n_in=vocab_size, n_out=d_model)]
+    for i, kind in enumerate(layer_types):
+        layers += [block(operators[kind]()),
+                   block(GatedMLP(width=dense_width) if i < n_dense
+                         else SparseMoE(gated=True, **(moe or {})))]
+    layers.append(MTPOutputLayer(
+        n_out=vocab_size, activation="softmax", loss="mcxent", eps=eps,
+        remat=remat, tied=True))
+    return MultiLayerConfiguration(
+        layers=tuple(layers),
+        input_type=InputType.recurrent(vocab_size),
+        updater=updater or {"type": "adam", "lr": 3e-4},
+        seed=seed,
+        dtype=dtype,
+    )

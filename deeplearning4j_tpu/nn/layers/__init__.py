@@ -52,6 +52,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
 from deeplearning4j_tpu.nn.layers.moe import GatedMLP, MixtureOfExperts, SparseMoE
 from deeplearning4j_tpu.nn.layers.residual import ResidualBlock
 from deeplearning4j_tpu.nn.layers.mtp import MTPOutputLayer
+from deeplearning4j_tpu.nn.layers.shortconv import ShortConvMixer
 from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer
 from deeplearning4j_tpu.nn.layers.variational import VariationalAutoencoder
 from deeplearning4j_tpu.nn.layers.objdetect import (
@@ -116,6 +117,7 @@ __all__ = [
     "MTPOutputLayer",
     "ResidualBlock",
     "Mamba2Mixer",
+    "ShortConvMixer",
     "GroupedQueryAttention",
     "RMSNorm",
     "VariationalAutoencoder",

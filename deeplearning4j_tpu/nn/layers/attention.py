@@ -324,8 +324,12 @@ class GroupedQueryAttention(LayerConfig):
     """Causal self-attention over [B, T, C] with ``n_heads`` query heads of
     ``head_dim`` that share ``n_kv_heads`` key/value heads (each serves
     ``n_heads / n_kv_heads`` query heads), bias-free projections and a head
-    width of its own (``n_heads * head_dim`` need not be ``C``). No
-    positional encoding is applied here. The attention core is
+    width of its own (``n_heads * head_dim`` need not be ``C``).
+    ``qk_norm`` puts an RMSNorm over each head's lanes of q and of k (one
+    gain vector of ``head_dim`` each, shared by the heads; ``eps``);
+    ``rope_theta`` above 0 then turns all lanes of every q and k head by
+    their position (``rotary``, ``rope_pairing``). Both are off by default:
+    no norm, no positional encoding. The attention core is
     ``MultiHeadAttention``'s (flash kernels on the TPU, the XLA path
     elsewhere); keys and values are repeated to the query heads for it, and
     autodiff sums their gradients back over the repeat."""
@@ -334,6 +338,10 @@ class GroupedQueryAttention(LayerConfig):
     n_kv_heads: int = 1
     head_dim: int = 64
     causal: bool = True
+    qk_norm: bool = False
+    eps: float = 1e-5
+    rope_theta: float = 0.0         # 0: no rotary positions
+    rope_pairing: str = "half"
     weight_init: Any = "xavier"
     use_flash: Any = "auto"
 
@@ -349,10 +357,16 @@ class GroupedQueryAttention(LayerConfig):
         kq, kk, kv_, ko = jax.random.split(key, 4)
         init = lambda k, fi, fo: initializers.initialize(   # noqa: E731
             self.weight_init, k, (fi, fo), fi, fo, dtype)
-        return {"Wq": init(kq, C, q), "Wk": init(kk, C, kv),
-                "Wv": init(kv_, C, kv), "Wo": init(ko, q, C)}
+        p = {"Wq": init(kq, C, q), "Wk": init(kk, C, kv),
+             "Wv": init(kv_, C, kv), "Wo": init(ko, q, C)}
+        if self.qk_norm:
+            p["q_norm"] = jnp.ones((self.head_dim,), dtype)
+            p["k_norm"] = jnp.ones((self.head_dim,), dtype)
+        return p
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
+        from deeplearning4j_tpu.nn.layers.normalization import rms_norm
+
         x = self.maybe_dropout_input(x, train, rng)
         B, T, _ = x.shape
         H, Hkv, D = self.n_heads, self.n_kv_heads, self.head_dim
@@ -360,6 +374,17 @@ class GroupedQueryAttention(LayerConfig):
             q = (x @ params["Wq"]).reshape(B, T, H, D)
             k = (x @ params["Wk"]).reshape(B, T, Hkv, D)
             v = (x @ params["Wv"]).reshape(B, T, Hkv, D)
+            if self.qk_norm:
+                with jax.named_scope("qk_norm"):
+                    q = rms_norm(q, params["q_norm"], self.eps)
+                    k = rms_norm(k, params["k_norm"], self.eps)
+            if self.rope_theta:
+                with jax.named_scope("rope"):
+                    turn = lambda t: rotary(                     # noqa: E731
+                        t.reshape(B, T, -1), jnp.arange(T), width=D,
+                        theta=self.rope_theta, pairing=self.rope_pairing
+                    ).reshape(t.shape)
+                    q, k = turn(q), turn(k)
             k, v = (jnp.repeat(t, H // Hkv, axis=2) for t in (k, v))
             kmask = mask.reshape(B, T) if mask is not None and mask.ndim >= 2 else None
             core = MultiHeadAttention(n_heads=H, causal=self.causal,
@@ -368,29 +393,36 @@ class GroupedQueryAttention(LayerConfig):
             return out.reshape(B, T, H * D) @ params["Wo"], state
 
 
-def rotary(x, positions, *, width: int, theta: float = 10000.0):
+def rotary(x, positions, *, width: int, theta: float = 10000.0,
+           pairing: str = "adjacent"):
     """Rotary position embedding over the lanes of ``x`` [..., T, n*width]:
-    each run of ``width`` lanes (a head) is rotated pair by pair, the
-    adjacent lanes ``(2i, 2i+1)`` of position ``t`` by the angle ``t *
-    theta^(-2i/width)`` (GPT-J, DeepSeek's ``rope_interleave``).
-    ``positions`` [T] or [..., T]. A partial rotation is the caller's slice:
-    hand over the lanes that turn.
+    each run of ``width`` lanes (a head) is rotated pair by pair, pair ``i``
+    of position ``t`` by the angle ``t * theta^(-2i/width)``. ``pairing``
+    says which lanes make pair ``i``: ``"adjacent"`` the lanes ``(2i, 2i+1)``
+    (GPT-J, DeepSeek's ``rope_interleave``), ``"half"`` the lanes ``(i, i +
+    width/2)`` (GPT-NeoX's "rotate half"). ``positions`` [T] or [..., T]. A
+    partial rotation is the caller's slice: hand over the lanes that turn.
 
-    Written over whole lanes: a lane's partner is a roll by one lane chosen
-    by a mask, so no ``[.., width/2, 2]`` view with a minor dimension of 2 is
-    made."""
+    Written over whole lanes: a lane's partner is a roll (by one lane, or by
+    half a head) chosen by a mask, so no ``[.., width/2, 2]`` view with a
+    minor dimension of 2 is made."""
     lane, heads = np.arange(width), x.shape[-1] // width
     inv = np.power(float(theta), -np.arange(0, width, 2) / width).astype(
         np.float32)
-    first = lane % 2 == 0
+    if pairing == "adjacent":
+        first, pair, step = lane % 2 == 0, lane // 2, 1
+    elif pairing == "half":
+        first, pair, step = lane < width // 2, lane % (width // 2), width // 2
+    else:
+        raise ValueError(f"pairing {pairing!r}: 'adjacent' or 'half'")
     # one head's angles [.., T, width], repeated over the heads' lanes
-    ang = positions.astype(jnp.float32)[..., None] * inv[lane // 2]
+    ang = positions.astype(jnp.float32)[..., None] * inv[pair]
     sign = np.where(first, -1.0, 1.0).astype(np.float32)
     reps = (1,) * (ang.ndim - 1) + (heads,)
     cos, sin = jnp.tile(jnp.cos(ang), reps), jnp.tile(jnp.sin(ang) * sign, reps)
     xf = x.astype(jnp.float32)
-    partner = jnp.where(np.tile(first, heads), jnp.roll(xf, -1, axis=-1),
-                        jnp.roll(xf, 1, axis=-1))
+    partner = jnp.where(np.tile(first, heads), jnp.roll(xf, -step, axis=-1),
+                        jnp.roll(xf, step, axis=-1))
     return (xf * cos + partner * sin).astype(x.dtype)
 
 

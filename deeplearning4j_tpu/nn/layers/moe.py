@@ -194,7 +194,8 @@ class SparseMoE(LayerConfig):
     The router scores all ``n_experts`` in float32, ``s = sigmoid(u W_r)``,
     takes the ``top_k`` experts with the largest ``s + bias`` (``bias`` is a
     buffer in the layer's state that no gradient moves), and weighs each by
-    ``s_e / sum_chosen s`` (``norm_topk``) times ``routed_scaling``. An
+    ``s_e / (sum_chosen s + norm_topk_eps)`` (``norm_topk``) times
+    ``routed_scaling``. An
     expert is ``relu(u W1_e)^2 W2_e``; the shared expert has the same form
     and sees every token. ``gated`` makes both ``(silu(u W_g) * (u W_u))
     W2``, with ``W1 = [W_g | W_u]`` side by side (twice ``expert_width``
@@ -229,6 +230,7 @@ class SparseMoE(LayerConfig):
     n_held: int = 0                 # 0: all of them
     routed_scaling: float = 1.0
     norm_topk: bool = True
+    norm_topk_eps: float = 0.0      # added to the chosen scores' sum
     gated: bool = False
     weight_init: Any = "xavier"
 
@@ -297,7 +299,10 @@ class SparseMoE(LayerConfig):
         _, eid = jax.lax.top_k(s + bias, self.top_k)              # [N, k]
         w = jnp.take_along_axis(s, eid, axis=-1)
         if self.norm_topk:
-            w = w / jnp.sum(w, axis=-1, keepdims=True)
+            total = jnp.sum(w, axis=-1, keepdims=True)
+            if self.norm_topk_eps:
+                total = total + self.norm_topk_eps
+            w = w / total
         return eid, w * self.routed_scaling
 
     def _experts(self, w1, w2, u, wflat, order, counts, *, cap: int, impl: str):

@@ -1,7 +1,9 @@
-"""The output layer of a language model with a multi-token-prediction head
-(DeepSeek-V3, arXiv:2412.19437 section 2.2): the final RMSNorm and the main
-head, and behind them one MTP module that predicts the token after next from
-the trunk's last hidden state and the next token's embedding."""
+"""The output layer of a language model whose head reads other layers'
+parameters: the final RMSNorm and the main head, which may be tied to the
+embedding (its matrix is the embedding's, transposed), and behind them an
+optional multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437
+section 2.2) that predicts the token after next from the trunk's last hidden
+state and the next token's embedding."""
 
 from __future__ import annotations
 
@@ -52,7 +54,12 @@ class MTPOutputLayer(RnnOutputLayer):
     loss terms, then that feed-forward layer's counters; ``fit()`` fetches it
     with the loss and ``publish_stats`` adds it up. With ``mtp_layers = 0``
     there is no second head, no state, and the layer scores as an
-    ``RnnOutputLayer`` behind an ``RMSNorm`` does."""
+    ``RnnOutputLayer`` behind an ``RMSNorm`` does.
+
+    ``tied`` gives the layer no matrix of its own: the logits are ``RMSNorm(h)
+    Emb^T`` with ``Emb`` [vocabulary, C] the embedding layer's matrix, handed
+    in by reference as for the MTP module, so the one array has one gradient
+    (the sum of the look-up's and the head's) and one updater state."""
 
     has_bias: bool = False
     eps: float = 1e-6
@@ -61,6 +68,7 @@ class MTPOutputLayer(RnnOutputLayer):
     attention: Any = None       # a LayerConfig: the MTP block's attention mixer
     ffn: Any = None             # a LayerConfig: its feed-forward mixer
     remat: bool = False
+    tied: bool = False          # the head's matrix is the embedding's
 
     def _blocks(self):
         return tuple(ResidualBlock(mixer=m, eps=self.eps, remat=self.remat)
@@ -69,15 +77,18 @@ class MTPOutputLayer(RnnOutputLayer):
     def shared_params(self) -> dict:
         """name -> index of the layer whose parameters ``score_shared`` is
         handed under that name; empty where ``score`` is enough."""
-        return {"embedding": 0} if self.mtp_layers else {}
+        return {"embedding": 0} if self.mtp_layers or self.tied else {}
 
     def init(self, key, input_type, dtype=jnp.float32):
         if self.mtp_layers not in (0, 1):
             raise ValueError("mtp_layers is 0 or 1: one MTP module")
+        if self.tied and self.has_bias:
+            raise ValueError("a head tied to the embedding has no bias")
         d = input_type.size
         kh, ke, ka, kf = jax.random.split(key, 4)
         gain = lambda: {"gamma": jnp.ones((d,), dtype)}          # noqa: E731
-        p = dict(super().init(kh, input_type, dtype), norm=gain())
+        p = {} if self.tied else super().init(kh, input_type, dtype)
+        p = dict(p, norm=gain())
         if self.mtp_layers:
             attn, ffn = self._blocks()
             p["mtp"] = {
@@ -105,30 +116,49 @@ class MTPOutputLayer(RnnOutputLayer):
         if len(stats) > len(_TERMS):
             self.ffn.publish_stats(index, stats[len(_TERMS):])
 
-    def preactivation(self, params, x):
-        return super().preactivation(
-            params, rms_norm(x, params["norm"]["gamma"], self.eps))
+    def _matrix(self, params, shared):
+        """The head's matrix as it is stored: this layer's [C, vocabulary],
+        or the embedding's [vocabulary, C] where the head is tied to it."""
+        return shared["embedding"]["W"] if self.tied else params["W"]
+
+    def _logits(self, x, W):
+        return x @ (W.T if self.tied else W)
+
+    def preactivation(self, params, x, shared=None):
+        x = rms_norm(x, params["norm"]["gamma"], self.eps)
+        if self.tied:
+            return self._logits(x, self._matrix(params, shared))
+        return super().preactivation(params, x)
+
+    def apply(self, params, state, x, *, train=False, rng=None, mask=None,
+              shared=None):
+        x = self.maybe_dropout_input(x, train, rng)
+        y = self.activation_fn()(self.preactivation(params, x, shared))
+        return (y if mask is None else y * mask[..., None]), state
 
     def score_shared(self, params, state, h, labels, *, shared, mask=None,
                      train=True, rng=None):
-        """``(loss, new state)`` for the trunk's ``h`` [B, T, C] and sparse
-        ``labels`` [B, T]; ``shared["embedding"]`` is the embedding layer's
-        parameters."""
-        if labels.ndim != 2:
-            raise ValueError("the MTP module reads the next tokens' ids: "
-                             "labels must be sparse [B, T]")
-        ids = labels.astype(jnp.int32)
-
+        """``(loss, new state)`` for the trunk's ``h`` [B, T, C] and
+        ``labels`` (sparse [B, T] where there is an MTP module);
+        ``shared["embedding"]`` is the embedding layer's parameters."""
         def head(x, gamma, W, y):
-            z = rms_norm(x, gamma, self.eps) @ W
+            z = self._logits(rms_norm(x, gamma, self.eps), W)
             return losses.average_score(self.loss, y, z, self.activation, mask)
 
         if self.remat:          # a head's logits are not kept for its backward
             head = jax.checkpoint(head)
+        W = self._matrix(params, shared)
+        if not self.mtp_layers:
+            with jax.named_scope("head"):
+                return head(h, params["norm"]["gamma"], W, labels), state
+        if labels.ndim != 2:
+            raise ValueError("the MTP module reads the next tokens' ids: "
+                             "labels must be sparse [B, T]")
+        ids = labels.astype(jnp.int32)
         m = params["mtp"]
         d = h.shape[-1]
         with jax.named_scope("head"):
-            main = head(h, params["norm"]["gamma"], params["W"], ids)
+            main = head(h, params["norm"]["gamma"], W, ids)
         with jax.named_scope("mtp"):
             with jax.named_scope("merge"):
                 e = jnp.take(shared["embedding"]["W"], ids, axis=0)
@@ -140,8 +170,7 @@ class MTPOutputLayer(RnnOutputLayer):
             x, ffn_state = ffn.apply(m["ffn"], state["ffn"], x, train=train,
                                      rng=rng, mask=mask)
             with jax.named_scope("head"):
-                mtp = head(x, m["norm"]["gamma"], params["W"],
-                           jnp.roll(ids, -1, axis=1))
+                mtp = head(x, m["norm"]["gamma"], W, jnp.roll(ids, -1, axis=1))
         stats = jnp.stack([main, mtp]).astype(jnp.float32)
         if "stats" in ffn_state:
             stats = jnp.concatenate([stats, ffn_state["stats"]])

@@ -86,14 +86,15 @@ def ssd_chunked_scan(x, dt, A, Bm, Cm, chunk: int):
     return y.reshape(Bsz, nc * Q, H, P)[:, :T]
 
 
-def causal_depthwise_conv1d(x, w, b):
+def causal_depthwise_conv1d(x, w, b=None):
     """``x`` [B, T, C], ``w`` [k, C] (tap ``k-1`` multiplies the current
-    position), ``b`` [C]: out_t = b + sum_j w[j] x[t - (k-1-j)], zeros before
-    the start."""
+    position), ``b`` [C] or none: out_t = b + sum_j w[j] x[t - (k-1-j)],
+    zeros before the start."""
     k = w.shape[0]
     T = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    return b + sum(xp[:, j:j + T] * w[j] for j in range(k))
+    taps = sum(xp[:, j:j + T] * w[j] for j in range(k))
+    return taps if b is None else b + taps
 
 
 @register_layer("mamba2_mixer")

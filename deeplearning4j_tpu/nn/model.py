@@ -407,7 +407,13 @@ class MultiLayerNetwork:
                     a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng,
                                         mask=mask, ex_weight=ex_weight)
                 else:
-                    a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng, mask=mask)
+                    # a layer that reads other layers' parameters (a head tied
+                    # to the embedding) is handed them by reference, as _loss does
+                    shared = getattr(layer, "shared_params", dict)()
+                    kw = {"shared": {k: params[j] for k, j in shared.items()}} \
+                        if shared else {}
+                    a, ns = layer.apply(p_i, state[i], a, train=ltrain, rng=lrng,
+                                        mask=mask, **kw)
             new_state[i] = ns
             mask = layer.propagate_mask(mask, self.layer_input_types[i])
             if collect:
