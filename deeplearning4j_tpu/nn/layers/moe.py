@@ -18,6 +18,7 @@ shared expert, and an expert layer that holds a share of the experts.
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any
 
@@ -115,6 +116,11 @@ def gated_silu(a):
     return jax.nn.silu(a[..., :F]) * a[..., F:]
 
 
+def _expert_act(a, gated: bool):
+    """An expert's nonlinearity over its first product."""
+    return gated_silu(a) if gated else jnp.square(jax.nn.relu(a))
+
+
 @register_layer("gated_mlp")
 @dataclass
 class GatedMLP(LayerConfig):
@@ -151,38 +157,86 @@ _MOE_STATS = {
     "load_mean": "mean load of a held expert, summed over steps",
     "pairs_dropped": "token-expert pairs of held experts not computed (always 0)",
     "rows_computed": "rows of the row tiles the held experts' products ran",
+    "rows_buffer": "rows of the pair buffer the step took",
 }
 
 
-def _cond_recomputed(pred, branches, diff, ints):
-    """``lax.cond(pred, *branches, *diff, *ints)``, differentiable in
-    ``diff``, that keeps nothing of a branch for the backward pass: the
-    backward pass is a second conditional whose taken branch runs its own
-    forward again and then its backward. Differentiated as it stands, a
-    conditional hands the backward pass the residuals of all its branches,
-    and the branch that runs fills those of the others with zeros of their
-    shapes: with the buffer of every pair beside the usual one that was
-    0.3 ms of ``broadcast`` an array and a tenth of the expert layers' time
-    (PERF.md section 6, PR 31). Under a ``ResidualBlock`` that recomputes
-    its layer the forward inside the backward is the recomputation itself:
-    the recomputed layer's own call of the experts feeds nothing and goes."""
-    @jax.custom_vjp
-    def run(pred, diff, ints):
-        return jax.lax.cond(pred, *[
-            lambda d, i, b=b: b(*d, *i) for b in branches], diff, ints)
+def _branches(fn, variants):
+    """``fn`` under each variant's keywords, as functions of ``(diff, ints)``."""
+    return [lambda d, i, kw=dict(v): fn(*d, *i, **kw) for v in variants]
 
-    def fwd(pred, diff, ints):
-        return run(pred, diff, ints), (pred, diff, ints)
+
+@functools.partial(jax.jit, static_argnames=("fn", "variants"))
+def _switch(index, diff, ints, *, fn, variants):
+    return jax.lax.switch(index, _branches(fn, variants), diff, ints)
+
+
+@functools.partial(jax.jit, static_argnames=("fn", "variants"))
+def _switch_vjp(index, diff, ints, g, *, fn, variants):
+    return jax.lax.switch(index, [
+        lambda d, i, g, b=b: jax.vjp(lambda *d: b(d, i), *d)[1](g)
+        for b in _branches(fn, variants)], diff, ints, g)
+
+
+def _cond_recomputed(index, fn, variants, diff, ints):
+    """``lax.switch(index, branches, *diff, *ints)`` with branch ``j`` being
+    ``fn(*diff, *ints, **dict(variants[j]))``, differentiable in ``diff``,
+    that keeps nothing of a branch for the backward pass: the backward pass
+    is a second conditional whose taken branch runs its own forward again
+    and then its backward. Differentiated as it stands, a conditional hands
+    the backward pass the residuals of all its branches, and the branch
+    that runs fills those of the others with zeros of their shapes: with
+    the buffer of every pair beside the usual one that was 0.3 ms of
+    ``broadcast`` an array and a tenth of the expert layers' time (PERF.md
+    section 6, PR 31). Here a branch that is not taken costs no device
+    time, however many there are. Under a ``ResidualBlock`` that recomputes
+    its layer the forward inside the backward is the recomputation itself:
+    the recomputed layer's own call of the experts feeds nothing and goes.
+
+    Both conditionals are jitted on ``fn`` and ``variants`` (a module-level
+    function and a tuple of tuples of keyword pairs: hashable), so layers
+    of one shape share one trace of every branch and of its backward: a
+    process traces its step before the first one whether or not the
+    compiled program is cached, and a branch is its layer's whole expert
+    path to trace (PERF.md section 6, PR 35)."""
+    @jax.custom_vjp
+    def run(index, diff, ints):
+        return _switch(index, diff, ints, fn=fn, variants=variants)
+
+    def fwd(index, diff, ints):
+        return run(index, diff, ints), (index, diff, ints)
 
     def bwd(res, g):
-        pred, diff, ints = res
-        grads = jax.lax.cond(pred, *[
-            lambda d, i, g, b=b: jax.vjp(lambda *d: b(*d, *i), *d)[1](g)
-            for b in branches], diff, ints, g)
-        return no_cotangent(pred), grads, no_cotangent(ints)
+        index, diff, ints = res
+        grads = _switch_vjp(index, diff, ints, g, fn=fn, variants=variants)
+        return no_cotangent(index), grads, no_cotangent(ints)
 
     run.defvjp(fwd, bwd)
-    return run(pred, diff, ints)
+    return run(index, diff, ints)
+
+
+def _experts(w1, w2, u, wflat, order, counts, *, cap: int, k: int,
+             gated: bool, impl: str):
+    """The held experts over a buffer that holds ``cap`` pairs in the
+    tile-aligned layout (ops/grouped_matmul.py ``tile_layout``): buffer row
+    ``starts[g] + p`` is the ``p``-th pair sorted to expert ``g``; the rows
+    between a group's count and its next tile gather token 0 under weight 0,
+    and the products give them exact zeros. Returns the tokens' sums, the
+    pairs computed, the rows the products ran and the rows the buffer has."""
+    n_tiles = tiles_needed(cap, counts.shape[0])
+    lay = tile_layout(counts, n_tiles)
+    gid, pos, valid = row_layout(lay)
+    first = jnp.cumsum(counts) - counts         # a group's first sorted pair
+    pair = jnp.take(order, jnp.where(valid, jnp.take(first, gid) + pos, 0))
+    tok = jnp.where(valid, pair // k, 0)
+    w = jnp.where(valid, jnp.take(wflat, pair), 0.0).astype(u.dtype)
+    xs = jnp.take(u, tok, axis=0)
+    h = _expert_act(grouped_matmul(xs, w1, counts, impl=impl), gated)
+    y = grouped_matmul(h, w2, counts, impl=impl) * w[:, None]
+    out = jnp.zeros_like(u).at[tok].add(y)
+    return (out, jnp.sum(valid).astype(jnp.float32),
+            (lay.n_occ[0] * ROW_TILE).astype(jnp.float32),
+            jnp.float32(n_tiles * ROW_TILE))
 
 
 @register_layer("sparse_moe")
@@ -212,14 +266,16 @@ class SparseMoE(LayerConfig):
     expert products run over it as grouped matmuls
     (ops/grouped_matmul.py: Pallas kernels on the TPU, whose cost follows
     the occupied tiles, not the buffer), and the results are scatter-added
-    back to their tokens. The buffer has one of two static sizes
-    (``row_caps``): twice the even share of the pairs, which the usual step
-    takes, or every pair, which nothing can pass; only the gather and the
-    scatter-add pay for the buffer's rows, and how the load is spread over
-    the held experts changes no shape. The choice is a conditional that
-    keeps nothing for the backward pass (``_cond_recomputed``). Under an
-    active mesh the products are the kernels' plain XLA form (the layer has
-    no mesh path of its own yet).
+    back to their tokens. The buffer has one of up to four static sizes
+    (``row_caps``): the even share of the pairs, twice it, the geometric
+    mean of that and every pair, and every pair, which nothing can pass. A
+    step takes the smallest that holds the pairs its own router sent here:
+    the gathers, the activation and the scatter-add pay for the buffer's
+    rows, and how the load is spread over the held experts changes no
+    shape. The choice is a conditional that keeps nothing for the backward
+    pass (``_cond_recomputed``), so a size that is not taken costs no device
+    time. Under an active mesh the products are the kernels' plain XLA form
+    (the layer has no mesh path of its own yet) over the same sizes.
     """
 
     n_experts: int = 8
@@ -236,10 +292,6 @@ class SparseMoE(LayerConfig):
 
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
-
-    def _act(self, a):
-        """The expert's nonlinearity over its first product."""
-        return gated_silu(a) if self.gated else jnp.square(jax.nn.relu(a))
 
     def _held(self) -> int:
         return self.n_held or self.n_experts
@@ -284,13 +336,20 @@ class SparseMoE(LayerConfig):
 
     def row_caps(self, n_tokens: int) -> tuple:
         """The sizes of the pair buffer (token-expert pairs one step can
-        hold): twice what an even router sends here, then every pair. The
-        products cost what the occupied row tiles cost in either; only the
-        gather of the rows and the combine pay for the buffer."""
-        pairs = n_tokens * self.top_k
-        twice_even = -(-2 * pairs * self._held() // self.n_experts)
-        usual = -(-twice_even // ROW_TILE) * ROW_TILE       # whole row tiles
-        return tuple(sorted({min(pairs, usual), pairs}))
+        hold), ascending: what an even router sends here, twice that, the
+        geometric mean of twice that and every pair, and every pair, each in
+        whole row tiles and none over every pair (one size where the layer
+        holds every expert). The products cost what the occupied row tiles
+        cost in any of them; the gathers of the rows, the activation and
+        the combine pay for the buffer, so a step takes the smallest that
+        holds its pairs."""
+        pairs, held = n_tokens * self.top_k, self._held()
+        even = -(-pairs * held // self.n_experts)
+        twice = -(-2 * pairs * held // self.n_experts)
+        between = math.isqrt(max(twice * pairs - 1, 0)) + 1     # sqrt, up
+        return tuple(sorted({
+            min(pairs, -(-rows // ROW_TILE) * ROW_TILE)         # whole tiles
+            for rows in (even, twice, between, pairs)}))
 
     def _route(self, params, bias, u):
         s = jax.nn.sigmoid(jnp.matmul(
@@ -304,27 +363,6 @@ class SparseMoE(LayerConfig):
                 total = total + self.norm_topk_eps
             w = w / total
         return eid, w * self.routed_scaling
-
-    def _experts(self, w1, w2, u, wflat, order, counts, *, cap: int, impl: str):
-        """The held experts over a buffer that holds ``cap`` pairs in the
-        tile-aligned layout (ops/grouped_matmul.py ``tile_layout``): buffer
-        row ``starts[g] + p`` is the ``p``-th pair sorted to expert ``g``;
-        the rows between a group's count and its next tile gather token 0
-        under weight 0, and the products give them exact zeros. Returns the
-        tokens' sums, the pairs computed and the rows the products ran."""
-        k, E = self.top_k, counts.shape[0]
-        lay = tile_layout(counts, tiles_needed(cap, E))
-        gid, pos, valid = row_layout(lay)
-        first = jnp.cumsum(counts) - counts         # a group's first sorted pair
-        pair = jnp.take(order, jnp.where(valid, jnp.take(first, gid) + pos, 0))
-        tok = jnp.where(valid, pair // k, 0)
-        w = jnp.where(valid, jnp.take(wflat, pair), 0.0).astype(u.dtype)
-        xs = jnp.take(u, tok, axis=0)
-        h = self._act(grouped_matmul(xs, w1, counts, impl=impl))
-        y = grouped_matmul(h, w2, counts, impl=impl) * w[:, None]
-        out = jnp.zeros_like(u).at[tok].add(y)
-        return (out, jnp.sum(valid).astype(jnp.float32),
-                (lay.n_occ[0] * ROW_TILE).astype(jnp.float32))
 
     def apply(self, params, state, x, *, train=False, rng=None, mask=None):
         from deeplearning4j_tpu.parallel.context import partitioning_mesh
@@ -348,23 +386,30 @@ class SparseMoE(LayerConfig):
                 # no SparseMoE mesh path yet (ROADMAP R2): under an active
                 # mesh the products are plain XLA, so that no Mosaic call
                 # meets GSPMD's partitioner
-                impl = "auto" if partitioning_mesh() is None else "plain"
+                kernels = (partitioning_mesh() is None
+                           and jax.default_backend() == "tpu")
+                common = (("k", k), ("gated", self.gated),
+                          ("impl", "pallas" if kernels else "plain"))
                 caps = self.row_caps(N)
-                sizes = [functools.partial(self._experts, cap=c, impl=impl)
-                         for c in caps]
+                sizes = tuple((("cap", c),) + common for c in caps)
                 diff = (params["W1"], params["W2"], u, w.reshape(N * k))
-                if len(sizes) == 1:
-                    y, done, rows = sizes[0](*diff, order, counts)
+                if len(caps) == 1:
+                    y, done, rows, buffer = _experts(
+                        *diff, order, counts, **dict(sizes[0]))
                 else:
-                    y, done, rows = _cond_recomputed(
-                        jnp.sum(counts) <= caps[0], sizes, diff,
-                        (order, counts))
+                    # the smallest buffer that holds the step's pairs: the
+                    # sizes they exceed come before it
+                    taken = jnp.sum(jnp.sum(counts) > jnp.array(caps[:-1]),
+                                    dtype=jnp.int32)
+                    y, done, rows, buffer = _cond_recomputed(
+                        taken, _experts, sizes, diff, (order, counts))
             if self.shared_width:
                 with jax.named_scope("shared"):
-                    h = self._act(u @ params["Ws1"])
+                    h = _expert_act(u @ params["Ws1"], self.gated)
                     y = y + h @ params["Ws2"]
             pairs = jnp.sum(counts).astype(jnp.float32)
             stats = jnp.stack([pairs, jnp.max(counts).astype(jnp.float32),
-                               pairs / E, pairs - done, rows])   # _MOE_STATS' order
+                               pairs / E, pairs - done, rows,
+                               buffer])                    # _MOE_STATS' order
         return y.reshape(B, T, C), {"bias": state["bias"],
                                     "stats": jax.lax.stop_gradient(stats)}

@@ -9,6 +9,7 @@ planted router that sends every token to one held expert, nothing dropped.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 import sys
 
@@ -27,6 +28,7 @@ from deeplearning4j_tpu.nn.layers import (  # noqa: E402
     GroupedQueryAttention, Mamba2Mixer, ResidualBlock, RMSNorm, SparseMoE)
 from deeplearning4j_tpu.nn.layers.ssm import (  # noqa: E402
     causal_depthwise_conv1d, ssd_chunked_scan)
+from deeplearning4j_tpu.ops.grouped_matmul import tiles_needed  # noqa: E402
 
 CFG = {
     "hybrid_override_pattern": "M*E", "hidden_size": 32, "vocab_size": 50,
@@ -162,11 +164,11 @@ def test_grouped_query_attention_is_the_full_masked_square():
 
 
 def test_sparse_moe_forward_and_gradients():
-    """At 42 tokens one buffer holds every pair; at 256 the usual buffer
-    size is under the pairs' number (the step's own pairs say which runs)."""
+    """At 42 tokens one buffer holds every pair; at 256 three buffer sizes
+    are under the pairs' number (the step's own pairs say which runs)."""
     _fwd_and_grads(_moe_layer(), E_NAMES, ref.experts, 2)
     assert _moe_layer().row_caps(42) == (126,)
-    assert _moe_layer().row_caps(256) == (384, 768)
+    assert _moe_layer().row_caps(256) == (256, 384, 640, 768)
     _fwd_and_grads(_moe_layer(), E_NAMES, ref.experts, 2, u=_u(8, B=4, T=64))
 
 
@@ -203,12 +205,14 @@ def test_the_shares_add_up_to_the_uncut_layer():
 def test_a_planted_router_drops_nothing_and_the_counters_say_so(planted):
     """Every token sent to one held expert (through the routing bias), then
     to two: the expert's load is all N tokens, with two the pairs that land
-    here pass the usual buffer size and the buffer of every pair takes
-    them; the result is the reference's with the same bias, and nothing is
-    dropped. The products ran the occupied row tiles and no more."""
+    here pass twice the even share and a larger buffer takes them (the
+    smallest that holds them); the result is the reference's with the same
+    bias, and nothing is dropped. The products ran the occupied row tiles
+    and no more."""
     layer, w, u = _moe_layer(), _weights(), _u(2, B=3, T=50)
     N = 150
-    assert layer.row_caps(N) == (256, 450)
+    caps = layer.row_caps(N)
+    assert caps == (128, 256, 384, 450)
     p = _prog(E_NAMES, w, 2)
     bias = jnp.zeros((16,)).at[jnp.array(planted)].set(10.0)
     st = dict(layer.init_state(IT), bias=bias)
@@ -221,6 +225,9 @@ def test_a_planted_router_drops_nothing_and_the_counters_say_so(planted):
     assert stats["load_mean"] == stats["pairs_held"] / 4
     # an expert with all N = 150 tokens has two tiles of 128, the others one
     assert stats["rows_computed"] == 128 * (4 + len(planted))
+    fits = min(c for c in caps if c >= stats["pairs_held"])
+    assert fits == (256, 384)[len(planted) - 1]
+    assert stats["rows_buffer"] == 128 * tiles_needed(fits, 4)
     np.testing.assert_array_equal(new["bias"], bias)     # a buffer: unmoved
     p_ref = dict({k: w[f"{k}.2"] for k in E_NAMES}, e_bias=bias)
     _close(y, ref.experts(CFG, None, u, p_ref))
@@ -229,21 +236,193 @@ def test_a_planted_router_drops_nothing_and_the_counters_say_so(planted):
     r = jax.grad(lambda p: jnp.sum(ref.experts(CFG, None, u, p) * probe))(p_ref)
     for theirs, mine in E_NAMES.items():
         _close(g[mine], r[theirs])
-    # and under an even router the first buffer is enough
+    # and under an even router twice the even share is enough
     _, even = layer.apply(p, layer.init_state(IT), u)
     even = SparseMoE.stats_dict(even["stats"])
     assert even["pairs_held"] <= 256 and even["pairs_dropped"] == 0.0
+    assert even["rows_buffer"] <= 128 * tiles_needed(256, 4)
 
 
 def test_buffer_sizes_at_the_published_sizes():
+    """The even share, twice it, the geometric mean of that and every pair,
+    every pair, in whole row tiles: at the three expert cells' sizes, with
+    the two sizes the layer had before among them (so that no step takes a
+    larger buffer than it took), and one size where every expert is held."""
     layer = SparseMoE(n_experts=128, top_k=6, expert_width=1856,
                       shared_width=3712, n_held=8)
-    assert layer.row_caps(4096) == (3072, 24576)
-    assert layer.row_caps(8 * 4096) == (24576, 196608)
+    assert layer.row_caps(4096) == (1536, 3072, 8704, 24576)
+    assert layer.row_caps(8 * 4096) == (12288, 24576, 69632, 196608)
     assert dataclasses.replace(layer, n_held=0).row_caps(4096) == (24576,)
+    joyai = SparseMoE(n_experts=256, top_k=8, expert_width=768, n_held=16,
+                      gated=True)
+    assert joyai.row_caps(8192) == (4096, 8192, 23296, 65536)
+    lfm2 = SparseMoE(n_experts=32, top_k=4, expert_width=1792, n_held=8,
+                     gated=True)
+    assert lfm2.row_caps(2 * 8192) == (16384, 32768, 46464, 65536)
+    for moe, n, before in ((layer, 4096, (3072, 24576)),
+                           (joyai, 8192, (8192, 65536)),
+                           (lfm2, 16384, (32768, 65536))):
+        caps = moe.row_caps(n)
+        assert set(before) <= set(caps) and caps == tuple(sorted(set(caps)))
+        assert all(c % 128 == 0 for c in caps) and caps[-1] == n * moe.top_k
+        assert [128 * tiles_needed(c, moe.n_held) for c in caps] == [
+            c + 128 * moe.n_held for c in caps]
     with pytest.raises(ValueError):
         dataclasses.replace(layer, held_start=124).init(
             jax.random.PRNGKey(0), InputType.recurrent(8, 4))
+
+
+def _planted_step(m, B=2, T=128):
+    """A 16-expert top-2 layer holding four, 256 tokens of which the first
+    ``m`` are unmasked and send both their pairs to held experts 9 and 10
+    (through the routing bias): the step holds exactly ``2 m`` pairs."""
+    layer = SparseMoE(n_experts=16, top_k=2, expert_width=12, shared_width=20,
+                      held_start=8, n_held=4, routed_scaling=2.5)
+    it = InputType.recurrent(32, T)
+    p = layer.init(jax.random.PRNGKey(0), it)
+    st = dict(layer.init_state(it),
+              bias=jnp.zeros((16,)).at[jnp.array([9, 10])].set(10.0))
+    u = jax.random.normal(jax.random.PRNGKey(1), (B, T, 32))
+    mask = (jnp.arange(B * T) < m).astype(jnp.float32).reshape(B, T)
+    return layer, p, st, u, mask
+
+
+@functools.lru_cache(maxsize=None)
+def _planted_programs():
+    """The planted layer's value, counters and gradients on the interpreted
+    kernels as compiled programs of ``(p, u, mask)``: under ``None`` the
+    layer as it stands, under each of its buffer sizes the layer with that
+    size forced (as the smaller of two, the other never taken, so that the
+    step keeps its conditional). Compiled once for all the cases."""
+    from unittest import mock
+
+    from jax.experimental.pallas import tpu as pltpu
+
+    layer, p, st, u, mask = _planted_step(0)
+    caps = layer.row_caps(u.shape[0] * u.shape[1])
+    probe = jax.random.normal(jax.random.PRNGKey(2), u.shape)
+
+    def f(p, u, mask):
+        y, new = layer.apply(p, st, u, mask=mask)
+        return jnp.sum(y * probe), (y, new["stats"])
+
+    def compiled():
+        return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True)).lower(
+            p, u, mask).compile()
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"), \
+            pltpu.force_tpu_interpret_mode():
+        programs = {None: compiled()}
+        for cap in caps:
+            with mock.patch.object(
+                    SparseMoE, "row_caps",
+                    lambda self, n, cap=cap: (cap, 2 * caps[-1])):
+                programs[cap] = compiled()
+    return caps, programs
+
+
+@pytest.mark.parametrize("pairs", [126, 128, 130, 254, 256, 258, 382, 384,
+                                   386, 512])
+def test_every_buffer_that_holds_the_pairs_gives_the_same_bits(pairs):
+    """The layer on the interpreted kernels, the step's held pairs just
+    under, on and just over each size of the buffer: the step takes the
+    smallest size that holds them, and in every other size that holds them
+    the result and every gradient are the same to the bit: the rows a
+    larger buffer adds gather token 0 under weight 0 and add exact zeros."""
+    caps, programs = _planted_programs()
+    assert caps == (128, 256, 384, 512)
+    _, p, _, u, mask = _planted_step(pairs // 2)
+
+    def run(program):
+        (_, (y, stats)), g = program(p, u, mask)
+        return y, SparseMoE.stats_dict(stats), jax.tree_util.tree_leaves(g)
+
+    y, stats, g = run(programs[None])
+    fits = [c for c in caps if c >= pairs]
+    assert stats["pairs_held"] == pairs and stats["pairs_dropped"] == 0.0
+    assert stats["rows_buffer"] == 128 * tiles_needed(fits[0], 4)
+    assert np.abs(np.asarray(g[1])).max() > 0           # W1's gradient
+    for cap in fits:
+        y1, stats1, g1 = run(programs[cap])
+        assert stats1["rows_buffer"] == 128 * tiles_needed(cap, 4)
+        assert stats1["rows_computed"] == stats["rows_computed"]
+        np.testing.assert_array_equal(y1, y)
+        for a, b in zip(g1, g):
+            np.testing.assert_array_equal(a, b)
+
+
+def test_cond_recomputed_runs_and_differentiates_the_taken_branch_alone():
+    """Four branches over buffers of four sizes, the index reckoned as the
+    layer reckons it (the sizes the count exceeds): the smallest size that
+    holds the count runs, forward and, inside the backward pass, once more
+    with its own backward; no other branch runs in either pass, and what
+    the backward pass keeps is the arguments alone."""
+    from jax._src.ad_checkpoint import saved_residuals
+
+    from deeplearning4j_tpu.nn.layers.moe import _cond_recomputed
+
+    caps, ran = (4, 8, 16, 32), []
+
+    def branch(x, n, *, j):
+        jax.debug.callback(lambda: ran.append(j))
+        buf = jnp.zeros((caps[j],), x.dtype).at[:x.shape[0]].set(x)
+        return (j + 1.0) * jnp.sum(jnp.sin(buf) ** 2), jnp.float32(caps[j])
+
+    def layer(x, n):
+        taken = jnp.sum(n > jnp.array(caps[:-1]), dtype=jnp.int32)
+        return _cond_recomputed(taken, branch,
+                                tuple((("j", j),) for j in range(4)),
+                                (x,), (n,))
+
+    x = jnp.linspace(0.1, 0.4, 4)
+    both = jax.jit(jax.value_and_grad(lambda x, n: layer(x, n)[0]))
+    for n, j in ((0, 0), (3, 0), (4, 0), (5, 1), (8, 1), (9, 2), (16, 2),
+                 (17, 3), (32, 3)):
+        ran.clear()
+        y, size = jax.jit(layer)(x, jnp.int32(n))
+        jax.effects_barrier()
+        assert ran == [j] and size == caps[j]
+        np.testing.assert_allclose(y, (j + 1) * np.sum(np.sin(x) ** 2),
+                                   rtol=1e-6)
+        ran.clear()
+        _, g = both(x, jnp.int32(n))
+        jax.effects_barrier()
+        assert ran == [j, j]
+        np.testing.assert_allclose(g, (j + 1) * np.sin(2 * x), rtol=1e-6)
+    kept = saved_residuals(lambda x: layer(x, jnp.int32(9))[0], x)
+    assert all(np.size(a) <= x.size for a, _ in kept), kept
+
+
+def test_the_counters_carry_the_buffers_rows_and_obs_adds_them_up():
+    """``state["stats"]`` names the rows of the buffer the step took beside
+    the other five counters, and ``publish_stats`` adds them to
+    ``dl4j_moe_rows_buffer_total`` under the layer's index."""
+    from deeplearning4j_tpu import obs
+    from deeplearning4j_tpu.nn.layers.moe import _MOE_STATS
+
+    def total(name, layer):
+        fam = [f for f in obs.registry().families() if f.name == name]
+        return dict(fam[0].as_dict()).get((layer,), 0.0) if fam else 0.0
+
+    assert list(_MOE_STATS)[-1] == "rows_buffer" and len(_MOE_STATS) == 6
+    layer, p, st, u, mask = _planted_step(100)
+    assert st["stats"].shape == (6,)
+    _, new = layer.apply(p, st, u, mask=mask)
+    stats = SparseMoE.stats_dict(new["stats"])
+    assert list(stats) == list(_MOE_STATS)
+    assert stats["pairs_held"] == 200
+    assert stats["rows_buffer"] == 128 * tiles_needed(256, 4) == 768
+    assert stats["rows_computed"] == 128 * 4 <= stats["rows_buffer"]
+    before = {k: total(f"dl4j_moe_{k}_total", "77") for k in _MOE_STATS}
+    layer.publish_stats(77, new["stats"])
+    layer.publish_stats(77, new["stats"])
+    for k in _MOE_STATS:
+        assert total(f"dl4j_moe_{k}_total", "77") - before[k] == 2 * stats[k]
+    whole = dataclasses.replace(layer, n_held=0, held_start=0)
+    pw = whole.init(jax.random.PRNGKey(0), InputType.recurrent(32, 128))
+    _, new = whole.apply(pw, whole.init_state(IT), u)
+    assert SparseMoE.stats_dict(new["stats"])["rows_buffer"] == \
+        128 * tiles_needed(512, 16)
 
 
 def test_masked_tokens_are_not_routed():

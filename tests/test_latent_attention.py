@@ -319,7 +319,7 @@ def test_mtp_loss_and_the_embeddings_summed_gradient_against_the_reference():
     stats = np.asarray(new_state[-1]["stats"])
     _close(stats[0], main, 1e-6)
     _close(stats[1], mtp, 1e-6)
-    assert len(stats) == 2 + 5          # the MTP expert layer's counters follow
+    assert len(stats) == 2 + 6          # the MTP expert layer's counters follow
     # one use alone gives another gradient: the trunk's ids, or the MTP's
     trunk_only = jax.grad(lambda p: ref.loss_terms(CFG, p, ids, labels)[0] / B)(w)
     gap = float(jnp.linalg.norm(trunk_only["wte"] - gref["wte"])
@@ -420,14 +420,21 @@ def test_the_builders_configuration_survives_json():
 
 
 def test_the_expert_layers_pair_buffers_are_the_layers_default():
-    """The cell sizes nothing of the pair buffer: twice the even share of
-    the pairs, then every pair, here as in the hybrid configuration."""
+    """The cell sizes nothing of the pair buffer: the even share of the
+    pairs, twice it, the geometric mean of that and every pair, then every
+    pair, here as in the hybrid configuration; the two sizes the layer had
+    before are among them, and a layer that holds every expert has one."""
+    import dataclasses
+
     from deeplearning4j_tpu.nn.layers import SparseMoE
 
     kw = dict(n_experts=256, top_k=8, expert_width=768, n_held=16)
-    assert SparseMoE(gated=True, **kw).row_caps(8192) == (8192, 65536)
+    caps = SparseMoE(gated=True, **kw).row_caps(8192)
+    assert caps == (4096, 8192, 23296, 65536) and {8192, 65536} <= set(caps)
     assert SparseMoE(n_experts=128, top_k=6, expert_width=1856,
-                     n_held=8).row_caps(4096) == (3072, 24576)
+                     n_held=8).row_caps(4096) == (1536, 3072, 8704, 24576)
+    assert dataclasses.replace(SparseMoE(gated=True, **kw),
+                               n_held=0).row_caps(8192) == (65536,)
     conf = fam.build_conf(CFG)
     for moe in (conf.layers[4].mixer, conf.layers[-1].ffn):
         assert moe.row_caps(64) == SparseMoE(

@@ -305,7 +305,7 @@ def test_hybrid_lm_step_compiles_at_published_widths(monkeypatch):
     flash kernels (each once: the recomputed layer keeps the forward kernel's
     result and does not run it again), the expert layer the
     grouped-matmul kernels (two products forward, two recomputed, two ``dx``
-    and two ``dw``, in each of its two buffer sizes), and they are the
+    and two ``dw``, in each of its four buffer sizes), and they are the
     step's only custom calls. None of the ``moe_gmm_*`` calls has a result
     by which the benchmark's accepted patterns find a flash kernel in a
     trace: the only lone rank-3 result is the flash dq kernel's."""
@@ -344,7 +344,7 @@ def test_hybrid_lm_step_compiles_at_published_widths(monkeypatch):
     (moe,) = [l.mixer for l in model.layers
               if type(getattr(l, "mixer", None)).__name__ == "SparseMoE"]
     sizes = len(moe.row_caps(4096))
-    assert len(flash) == 3 and sizes == 2
+    assert len(flash) == 3 and sizes == 4
     assert [len(gmm[k]) for k in ("fwd", "dx", "dw")] == [
         4 * sizes, 2 * sizes, 2 * sizes]
     assert len(calls) == 3 + 8 * sizes
