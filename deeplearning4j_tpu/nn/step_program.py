@@ -79,9 +79,11 @@ class StepProgram:
       configured ``hits_site``/``extra_allowed``, so callers can't forget
       the check or disagree on the budget;
     - **names**: the body runs under ``jax.named_scope(site)``, and every
-      call is one ``obs.span(site)`` over the signature lookup, the enqueue
-      and the guard check: the site's name is on the device operations and
-      on the host's timeline of any profiler trace.
+      call is one ``obs.site_span(site)`` over the signature lookup, the
+      enqueue and the guard check: the site's name is on the device
+      operations and on the host's timeline of any profiler trace, and what
+      JAX traces, lowers, compiles or reads from its cache inside a call is
+      booked to the site (``obs/compile_phases.py``).
 
     ``wrap_body`` (e.g. a ``shard_map`` closure for the explicit DP
     exchange) transforms the body before jit. Everything not implemented
@@ -99,7 +101,9 @@ class StepProgram:
                  hits_site: Optional[str] = None,
                  extra_allowed: int = 0):
         from deeplearning4j_tpu.nn import aot
+        from deeplearning4j_tpu.obs import compile_phases
 
+        compile_phases.install()
         self.site = site
         self.guard_site = guard_site or site
         self.hits_site = hits_site
@@ -123,7 +127,7 @@ class StepProgram:
 
     # -- dispatch ----------------------------------------------------------
     def __call__(self, *args, **kwargs):
-        with obs.span(self.site):
+        with obs.site_span(self.site):
             return self._run(*args, **kwargs)
 
     def _run(self, *args, **kwargs):
@@ -144,7 +148,7 @@ class StepProgram:
 
     def dispatch(self, *args, **kwargs):
         """Call, then run the retrace-guard check this program owns."""
-        with obs.span(self.site):
+        with obs.site_span(self.site):
             out = self._run(*args, **kwargs)
             self.guard()
         return out

@@ -10,6 +10,10 @@ land in ONE process-wide metrics registry, queryable three ways:
 - ``/metrics``            Prometheus text exposition on the UI server
 - ``obs.recent_spans()``  ring buffer of recent step spans
 
+What JAX spends tracing, lowering, compiling and reading its cache is booked
+by site and phase into ``dl4j_compile_seconds_total`` by one listener a
+process (obs/compile_phases.py), started where jax is already imported.
+
 Public surface::
 
     obs.counter/gauge/histogram(name, help, label_names)  # get-or-create
@@ -55,6 +59,7 @@ __all__ = [
     "observe_ttft",
     "observe_wait",
     "set_decode_occupancy",
+    "site_span",
     "prometheus_text",
     "recent_spans",
     "registry",
@@ -112,9 +117,17 @@ def span(name: str, **attrs):
     return _spans.tracer().span(name, **attrs)
 
 
+def site_span(site: str, **attrs):
+    """``with obs.site_span("mln.step"): ...``: the span of one call of a
+    jitted site, under the site's name. What JAX traces, lowers and compiles
+    inside it is booked to the site (see obs/compile_phases.py)."""
+    return _spans.tracer().site_span(site, site, **attrs)
+
+
 def compile_span(site: str, **attrs):
-    """``with obs.compile_span("mln.step"): ...`` — the ``compile`` span
-    kind aggregating all XLA compilation work (see obs/spans.py)."""
+    """``with obs.compile_span("mln.step", mode="aot"): ...``: the
+    ``compile`` span of the AOT and bundle paths, which names the site and
+    the mode where no call of the site is open (see obs/spans.py)."""
     return _spans.compile_span(site, **attrs)
 
 

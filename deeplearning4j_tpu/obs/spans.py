@@ -19,6 +19,15 @@ of one training iteration share one identifier. Finished spans go
 to a bounded ring buffer (most recent last) and into the
 ``dl4j_span_seconds`` histogram family in the metrics registry.
 
+A **site span** (``site_span``) also names the jitted site whose programs
+are traced, lowered and compiled inside it: every call of a ``StepProgram``
+opens one under the site's own name, ``compile_span`` one named ``compile``.
+``open_span`` hands the calling thread's innermost open span to whoever has
+to book work to it (``obs/compile_phases.py`` books JAX's compile events to
+the innermost site span, whose record then carries ``compile_s``), and
+``record`` puts a finished record into the ring for work whose extent is
+known only at its end.
+
 A span is also a ``jax.profiler.TraceAnnotation`` of the same name and
 extent, carrying ``span_depth`` and the span's scalar attributes: whenever a
 profiler trace is being taken (``ProfilerListener``, the benchmark's traced
@@ -82,7 +91,10 @@ def _annotation(name: str, depth: int, attrs: Dict[str, object]):
     if _TraceAnnotation is None:
         from jax.profiler import TraceAnnotation
 
+        from deeplearning4j_tpu.obs import compile_phases
+
         _TraceAnnotation = TraceAnnotation
+        compile_phases.install()    # jax is imported now: listen to it
     scalars = {k: v for k, v in attrs.items()
                if isinstance(v, (bool, int, float, str))}
     scalars[SPAN_DEPTH_STAT] = depth
@@ -90,11 +102,19 @@ def _annotation(name: str, depth: int, attrs: Dict[str, object]):
 
 
 class _ActiveSpan:
-    __slots__ = ("name", "attrs", "t0", "c0", "annotation")
+    """An open span. ``site`` is the jitted site a site span names (None on
+    every other span); ``compile_s`` is what ``obs/compile_phases.py`` has
+    booked to it so far."""
 
-    def __init__(self, name: str, attrs: Dict[str, object], depth: int):
+    __slots__ = ("name", "attrs", "site", "compile_s", "t0", "c0",
+                 "annotation")
+
+    def __init__(self, name: str, attrs: Dict[str, object], depth: int,
+                 site: Optional[str] = None):
         self.name = name
         self.attrs = attrs
+        self.site = site
+        self.compile_s = 0.0
         self.annotation = _annotation(name, depth, attrs)
         self.annotation.__enter__()
         self.t0 = time.perf_counter()
@@ -105,16 +125,18 @@ class _SpanContext:
     """Context manager handed out by ``SpanTracer.span``. Re-entrant-safe in
     the sense that each ``with`` creates a fresh context."""
 
-    __slots__ = ("_tracer", "_name", "_attrs", "_active")
+    __slots__ = ("_tracer", "_name", "_attrs", "_site", "_active")
 
-    def __init__(self, tracer: "SpanTracer", name: str, attrs: Dict[str, object]):
+    def __init__(self, tracer: "SpanTracer", name: str,
+                 attrs: Dict[str, object], site: Optional[str] = None):
         self._tracer = tracer
         self._name = name
         self._attrs = attrs
+        self._site = site
         self._active: Optional[_ActiveSpan] = None
 
     def __enter__(self):
-        self._active = self._tracer._push(self._name, self._attrs)
+        self._active = self._tracer._push(self._name, self._attrs, self._site)
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -133,6 +155,13 @@ class _NullContext:
 
 
 _NULL = _NullContext()
+
+
+def _with_step(attrs: Dict[str, object], stack: List[_ActiveSpan]):
+    """The spans of one iteration share its step number."""
+    if stack and "step" in stack[-1].attrs and "step" not in attrs:
+        return dict(attrs, step=stack[-1].attrs["step"])
+    return attrs
 
 
 class SpanTracer:
@@ -165,11 +194,29 @@ class SpanTracer:
     def span(self, name: str, **attrs) -> object:
         """Context manager timing one unit of work. With observability
         disabled (DL4J_TPU_OBS=0) returns a shared no-op context."""
+        return self._context(name, attrs, None)
+
+    def site_span(self, name: str, site: str, /, **attrs) -> object:
+        """``span(name)`` that also names the jitted ``site`` whose programs
+        are traced, lowered and compiled inside it."""
+        return self._context(name, attrs, site)
+
+    def _context(self, name: str, attrs: Dict[str, object],
+                 site: Optional[str]) -> object:
         from deeplearning4j_tpu import obs
 
         if not obs.enabled():
             return _NULL
-        return _SpanContext(self, name, attrs)
+        return _SpanContext(self, name, attrs, site)
+
+    def open_span(self, with_site: bool = False) -> Optional[_ActiveSpan]:
+        """The innermost span open on the calling thread (``name``,
+        ``attrs``, ``site``, ``t0``, ``compile_s``), or None; with
+        ``with_site`` the innermost site span."""
+        for sp in reversed(self._stack()):
+            if sp.site is not None or not with_site:
+                return sp
+        return None
 
     def _stack(self) -> List[_ActiveSpan]:
         st = getattr(self._tls, "stack", None)
@@ -177,12 +224,10 @@ class SpanTracer:
             st = self._tls.stack = []
         return st
 
-    def _push(self, name: str, attrs: Dict[str, object]) -> _ActiveSpan:
+    def _push(self, name: str, attrs: Dict[str, object],
+              site: Optional[str] = None) -> _ActiveSpan:
         stack = self._stack()
-        if stack and "step" in stack[-1].attrs and "step" not in attrs:
-            # the spans of one iteration share its step number
-            attrs = dict(attrs, step=stack[-1].attrs["step"])
-        sp = _ActiveSpan(name, attrs, len(stack))
+        sp = _ActiveSpan(name, _with_step(attrs, stack), len(stack), site)
         stack.append(sp)
         return sp
 
@@ -198,32 +243,53 @@ class SpanTracer:
             stack.pop()
         if stack:
             stack.pop()
-        parent = stack[-1].name if stack else None
+        rec = self._finished(sp.name, sp.t0, wall, cpu, sp.attrs, stack)
+        if error:
+            rec["error"] = True
+        if sp.compile_s:
+            rec["compile_s"] = sp.compile_s
+        self._keep(rec)
+        self._hist.observe(wall, span=sp.name)
+        self._cpu.observe(cpu, span=sp.name)
+
+    def record(self, name: str, t0_s: float, wall_s: float, /,
+               **attrs) -> None:
+        """Put a finished record into the ring for work whose extent is
+        known only at its end: ``t0_s`` on the ring's ``perf_counter``
+        timeline, ``parent``, ``depth`` and ``step`` from the spans open on
+        the calling thread, as a child opened there would have them. The
+        ring alone: no histogram series, no profiler annotation (none can be
+        back-dated)."""
+        stack = self._stack()
+        self._keep(self._finished(name, t0_s, wall_s, 0.0,
+                                  _with_step(attrs, stack), stack))
+
+    def _finished(self, name: str, t0: float, wall: float, cpu: float,
+                  attrs: Dict[str, object], stack: List[_ActiveSpan]) -> dict:
         th = threading.current_thread()
         rec = {
-            "span": sp.name,
-            "t0_s": sp.t0,
+            "span": name,
+            "t0_s": t0,
             "wall_s": wall,
             "cpu_s": cpu,
-            "parent": parent,
+            "parent": stack[-1].name if stack else None,
             "depth": len(stack),
             "tid": th.ident,
             "thread": th.name,
         }
-        if error:
-            rec["error"] = True
-        if sp.attrs:
-            rec["attrs"] = sp.attrs
+        if attrs:
+            rec["attrs"] = attrs
         # rank/incarnation + active trace ids (obs/fleet.py) — cheap dict
         # writes; records keep the rank current when they were recorded,
         # which matters across elastic reforms
         fleet.stamp_span(rec)
+        return rec
+
+    def _keep(self, rec: dict) -> None:
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self._dropped.inc()
             self._ring.append(rec)
-        self._hist.observe(wall, span=sp.name)
-        self._cpu.observe(cpu, span=sp.name)
 
     # -- views -------------------------------------------------------------
 
@@ -297,10 +363,12 @@ atexit.register(_dump_at_exit)
 
 
 def compile_span(site: str, **attrs):
-    """The ``compile`` span kind: one span family for all XLA compilation
-    work — AOT warmup (``nn/aot.py``), lazy jit traces instrumented by
-    callers, bundle re-validation. The jitted site rides as an attribute so
-    every compile aggregates under the single ``compile`` series: its
-    ``wall_sum_s`` in ``obs.snapshot()`` IS the process's total compile
-    cost, the number the cold_start bench drives down."""
-    return tracer().span("compile", site=site, **attrs)
+    """The ``compile`` span kind: the site span of compilation work that no
+    call of the site surrounds: AOT warm-up and bundle restore
+    (``nn/aot.py``). It names the jitted site (the ``site`` attribute, and
+    the site ``obs/compile_phases.py`` books JAX's compile events inside it
+    to) and the ``mode``. Its wall time is that of those two paths alone: a
+    site's first lazy call compiles inside the site's own span, and the
+    process's compile cost on every path is the sum of
+    ``dl4j_compile_seconds_total``."""
+    return tracer().site_span("compile", site, site=site, **attrs)

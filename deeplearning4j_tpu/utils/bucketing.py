@@ -148,8 +148,9 @@ class BucketTelemetry:
 
     Since PR 5 this class is an **adapter shim** over the obs metrics
     registry (``deeplearning4j_tpu/obs/``): the counters live in registry
-    families (``dl4j_bucketing_*``, ``dl4j_comm_bytes``,
-    ``dl4j_guard_events_total``) so they are scrapeable at /metrics, while
+    families (``dl4j_compiles_total``, ``dl4j_bucketing_*``,
+    ``dl4j_comm_bytes``, ``dl4j_guard_events_total``) so they are scrapeable
+    at /metrics, while
     every pre-existing accessor (``traces``, ``bucket_hits``, ``comm``,
     ``guard_events``, ``snapshot()``, ...) keeps its exact shape. The
     process singleton (``telemetry()``) shares the process registry and
@@ -162,19 +163,15 @@ class BucketTelemetry:
         self._lock = threading.Lock()
         self._emit_events = emit_events
         reg = registry if registry is not None else _obs_metrics.MetricsRegistry()
-        self._traces = reg.counter(
-            "dl4j_bucketing_traces_total",
-            "XLA traces/compiles by jitted site (recorded inside traced "
-            "bodies, so this counts compiles, not calls)", ("site",))
-        # the public compile counter (docs/OBSERVABILITY.md): same increment
-        # as the legacy bucketing family above, under the name dashboards and
-        # the cold_start bench key on — zero delta across a request window
+        # the public compile counter (docs/OBSERVABILITY.md) and what the
+        # ``traces`` accessor reads: zero delta across a request window
         # proves the request hit only pre-compiled executables
         self._compiles = reg.counter(
             "dl4j_compiles_total",
-            "XLA compiles by jitted site (every trace of a jitted body, "
-            "lazy or AOT — see dl4j_aot_warm_hits_total for AOT dispatch "
-            "hits)", ("site",))
+            "XLA traces/compiles by jitted site (recorded inside traced "
+            "bodies, lazy or AOT, so this counts compiles, not calls; see "
+            "dl4j_aot_warm_hits_total for AOT dispatch hits and "
+            "dl4j_compile_seconds_total for what they cost)", ("site",))
         self._hits = reg.counter(
             "dl4j_bucketing_hits_total",
             "padded dispatches by site and bucket rung", ("site", "bucket"))
@@ -198,7 +195,7 @@ class BucketTelemetry:
 
     def reset(self):
         with self._lock:
-            for fam in (self._traces, self._compiles, self._hits,
+            for fam in (self._compiles, self._hits,
                         self._padded, self._real, self._comm, self._guard):
                 fam.clear()
             self.trace_shapes = {}
@@ -206,8 +203,7 @@ class BucketTelemetry:
     def record_trace(self, site: str, shape: Sequence[int]):
         with self._lock:
             self.trace_shapes.setdefault(site, set()).add(tuple(shape))
-        self._compiles.inc(site=site)
-        count = self._traces.inc(site=site)
+        count = self._compiles.inc(site=site)
         # flag the site for lazy cost harvest (obs/profile.py): a set add,
         # no jax — runs inside the traced body exactly once per compile
         from deeplearning4j_tpu.obs import profile
@@ -251,7 +247,7 @@ class BucketTelemetry:
 
     @property
     def traces(self) -> Dict[str, int]:
-        return {k[0]: int(v) for k, v in self._traces.as_dict().items()}
+        return {k[0]: int(v) for k, v in self._compiles.as_dict().items()}
 
     @property
     def bucket_hits(self) -> Dict[Tuple[str, int], int]:
@@ -279,7 +275,7 @@ class BucketTelemetry:
 
     def compiles(self, site: Optional[str] = None) -> int:
         if site is not None:
-            return int(self._traces.value(site=site))
+            return int(self._compiles.value(site=site))
         return sum(self.traces.values())
 
     def buckets_used(self, site: Optional[str] = None) -> Tuple[int, ...]:

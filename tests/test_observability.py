@@ -278,7 +278,7 @@ class TestExposition:
                 body = resp.read().decode()
         finally:
             srv.stop()
-        assert 'dl4j_bucketing_traces_total{site="mln.step"} 1' in body
+        assert 'dl4j_compiles_total{site="mln.step"} 1' in body
         assert 'dl4j_bucketing_hits_total' in body
         assert 'dl4j_events_total{kind="route_check"} 1' in body
 

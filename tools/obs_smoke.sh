@@ -96,7 +96,8 @@ finally:
     srv.stop()
 assert "version=0.0.4" in ctype, ctype
 assert body.strip(), "/metrics returned an empty body"
-for family in ("dl4j_bucketing_traces_total", "dl4j_span_seconds",
+for family in ("dl4j_compiles_total", "dl4j_compile_seconds_total",
+               "dl4j_span_seconds",
                "dl4j_checkpoint_saves_total", "dl4j_events_total",
                "dl4j_xla_flops", "dl4j_requests_total"):
     assert family in body, f"/metrics missing family {family!r}"
