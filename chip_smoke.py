@@ -389,7 +389,8 @@ def phase_kernels(ctx, *, flash=((16, 2048, 16, 128, jnp.bfloat16, 2),
 
 class _Watch(TrainingListener):
     """The loss of every step, and how many backend compiles had happened
-    when step 1 finished."""
+    when step 1 was reported: fit() has enqueued step 2 by then, whose
+    retrace would be the site's trace count's to catch."""
 
     def __init__(self, compiles: Compiles):
         self._compiles = compiles

@@ -39,7 +39,7 @@ from deeplearning4j_tpu.nn.config import LayerConfig, layer_from_dict, _encode_v
 from deeplearning4j_tpu.nn.input_type import InputType
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrent
 from deeplearning4j_tpu.nn.model import _cast_input, _cast_labels, _sig_dtype
-from deeplearning4j_tpu.nn.step_program import layer_scope
+from deeplearning4j_tpu.nn.step_program import StepReports, layer_scope
 from deeplearning4j_tpu.nn.preprocessors import infer_preprocessor
 from deeplearning4j_tpu.utils import bucketing
 from deeplearning4j_tpu.train.updaters import (
@@ -1155,6 +1155,9 @@ class ComputationGraph:
                        else 0)
             if not _tbptt0 and _chain0 <= 1:
                 aot.warm_fit(self, data, batch_size)
+        reports = StepReports(
+            self, "cg", {name: v.config for name, v in self.rt.items()},
+            overlap=guard is None)
         try:
             for _ in range(epochs):
                 skip_n, resume_skip = resume_skip, 0
@@ -1221,10 +1224,12 @@ class ComputationGraph:
                 while True:
                     # one loop turn = one cg.iter span (mirrors
                     # MultiLayerNetwork.fit)
-                    with obs.span("cg.iter", step=self.iteration):
+                    step_no = self.iteration
+                    with obs.span("cg.iter", step=step_no):
                         with obs.span("cg.feed"):
                             item = next(stream, None)
                         if item is None:
+                            reports.flush()
                             break
                         f, l, fm, lm, ew, n_real = item
                         batch = (f, l, fm, lm)
@@ -1241,6 +1246,7 @@ class ComputationGraph:
                                 flush(True)
                             continue
                         flush(False)
+                        reports.hold()
                         with obs.span("cg.fit_batch"):
                             if tbptt:
                                 score = self._fit_tbptt(*batch)
@@ -1252,18 +1258,18 @@ class ComputationGraph:
                         if self.listeners:
                             # n_real came from the pre-padding host side of the
                             # stream
-                            with obs.span("cg.loss_fetch"):
-                                score = float(score)  # graftlint: disable=host-sync
-                            resilience.note_score(score)
-                            with obs.span("cg.listeners"):
-                                for lst in self.listeners:
-                                    lst.iteration_done(self, self.iteration, score, n_real)
+                            reports.step(score, step_no, n_real)
                 flush(False)
                 if guard is not None:
                     guard.flush(self)
                 for l in self.listeners:
                     l.on_epoch_end(self, self.epoch)
                 self.epoch += 1
+        except Exception:
+            # mirrors MultiLayerNetwork.fit: a step dispatched and not
+            # reported yet is reported before the loop's exception goes on
+            reports.flush_quietly()
+            raise
         finally:
             # a run ending inside a ProfilerListener [start, stop) window
             # (normally or via an exception/chaos preempt) must not leak an

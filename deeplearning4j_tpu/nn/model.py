@@ -172,6 +172,7 @@ def _as_batch(batch):
 from deeplearning4j_tpu.nn.step_program import (  # noqa: F401,E402
     CHAIN_AUTO_PARAM_LIMIT,
     StepProgram,
+    StepReports,
     accum_applicable as _accum_applicable,
     accum_value_and_grad as _accum_value_and_grad,
     chain_k_from_env as _chain_k_from_env,
@@ -714,6 +715,8 @@ class MultiLayerNetwork:
             # executable for the exact first-batch signature is compiled
             # (or already bundle-restored) before the epoch loop dispatches
             aot.warm_fit(self, data, batch_size)
+        reports = StepReports(self, "mln", self.layers,
+                              overlap=sgd and guard is None)
         try:
             for _ in range(epochs):
                 skip_n, resume_skip = resume_skip, 0
@@ -772,11 +775,14 @@ class MultiLayerNetwork:
                 stream = iter(stream)
                 while True:
                     # one loop turn = one mln.iter span; its children share
-                    # its step number (obs/spans.py hands it down)
-                    with obs.span("mln.iter", step=self.iteration):
+                    # its step number (obs/spans.py hands it down), but for
+                    # the report of the step before, which names its own
+                    step_no = self.iteration
+                    with obs.span("mln.iter", step=step_no):
                         with obs.span("mln.feed"):
                             item = next(stream, None)
                         if item is None:
+                            reports.flush()
                             break
                         x, y, fm, lm, ew, n_real = item
                         chainable = (
@@ -792,6 +798,7 @@ class MultiLayerNetwork:
                                 flush(True)
                             continue
                         flush(False)
+                        reports.hold()
                         with obs.span("mln.fit_batch"):
                             if not sgd:
                                 score = self._fit_solver(x, y, fm, lm)
@@ -803,42 +810,28 @@ class MultiLayerNetwork:
                         if guard is not None:
                             guard.observe(self, score)
                         # score is a device scalar; only sync the host when a
-                        # listener actually consumes it (keeps dispatch async);
+                        # listener actually consumes it (keeps dispatch async),
+                        # and then one step behind the dispatch (StepReports);
                         # n_real came from the pre-padding host side of the stream
                         if self.listeners:
-                            with obs.span("mln.loss_fetch"):
-                                score = self._fetch_score(score)
-                            resilience.note_score(score)
-                            with obs.span("mln.listeners"):
-                                for l in self.listeners:
-                                    l.iteration_done(self, self.iteration, score, n_real)
+                            reports.step(score, step_no, n_real)
                 flush(False)
                 if guard is not None:
                     guard.flush(self)
                 for l in self.listeners:
                     l.on_epoch_end(self, self.epoch)
                 self.epoch += 1
+        except Exception:
+            # the feed, the dispatch or the chaos harness raised, perhaps
+            # with a step dispatched and not reported yet
+            reports.flush_quietly()
+            raise
         finally:
             # a run ending inside a ProfilerListener [start, stop) window
             # (normally or via an exception/chaos preempt) must not leak an
             # open jax.profiler trace
             close_listeners(self.listeners)
         return self
-
-    def _fetch_score(self, score) -> float:
-        """The step's loss on the host and, in the same fetch, the step
-        counters of the layers that keep some in their state under
-        ``"stats"`` (an expert layer's load), handed to
-        ``layer.publish_stats``: no second wait for the device."""
-        idx = [i for i, s in enumerate(self.state)
-               if isinstance(s, dict) and "stats" in s]
-        if not idx:
-            return float(score)  # graftlint: disable=host-sync
-        score, stats = jax.device_get(  # graftlint: disable=host-sync
-            (score, [self.state[i]["stats"] for i in idx]))
-        for i, st in zip(idx, stats):
-            self.layers[i].publish_stats(i, st)
-        return float(score)
 
     def _fit_batch(self, x, y, fm, lm, ew=None):
         """One step. Returns the loss as a DEVICE scalar — callers decide

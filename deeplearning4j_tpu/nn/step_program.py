@@ -13,6 +13,10 @@ now the only place that wiring exists:
   AOT-warm dispatch (``nn/aot.py``), retrace-guard hookup
   (``analysis/retrace_guard.py``) and cost-exemplar harvest, behind a
   callable that quacks like the ``AotFunction`` it wraps.
+- :class:`StepReports` — what ``fit()`` tells its listeners of each step
+  (the loss on the host, the layers' step counters), one step behind the
+  dispatch where nothing attached needs the model at its own step, for
+  ``MultiLayerNetwork.fit`` and ``ComputationGraph.fit`` alike.
 - the **micro-batching policy** shared by every step builder:
   :func:`grad_accum_from_env` / :func:`accum_applicable` /
   :func:`accum_value_and_grad` (the lax.scan gradient accumulation INSIDE
@@ -30,6 +34,7 @@ time. See docs/PARALLELISM.md.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Any, Callable, List, Optional, Tuple
 
 import numpy as np
@@ -40,9 +45,12 @@ import jax.numpy as jnp
 from deeplearning4j_tpu import obs
 from deeplearning4j_tpu.analysis import donation_guard, retrace_guard
 
+logger = logging.getLogger("deeplearning4j_tpu")
+
 __all__ = [
     "CHAIN_AUTO_PARAM_LIMIT",
     "StepProgram",
+    "StepReports",
     "accum_applicable",
     "accum_value_and_grad",
     "chain_k_from_env",
@@ -171,6 +179,160 @@ class StepProgram:
         # anything else (signatures/install/lower/_compiled/...) is the
         # wrapped callable's business
         return getattr(self.__dict__["_fn"], name)
+
+
+# ---------------------------------------------------------------------------
+# fit()'s reports to its listeners (shared by MLN / CG)
+# ---------------------------------------------------------------------------
+
+_FETCHED = obs.counter(
+    "dl4j_fit_fetch_total",
+    "steps whose loss fit() fetched to the host for its listeners", ("site",))
+_OVERLAPPED = obs.counter(
+    "dl4j_fit_fetch_overlapped_total",
+    "of dl4j_fit_fetch_total, the steps fetched after the next step was "
+    "enqueued", ("site",))
+
+
+def _stats_keys(state) -> list:
+    """Where ``state`` (a tuple by layer index, a dict by vertex name) keeps
+    a layer's step counters under ``"stats"``."""
+    items = state.items() if isinstance(state, dict) else enumerate(state)
+    return [k for k, s in items if isinstance(s, dict) and "stats" in s]
+
+
+def _swap_stats(model, keys, arrays) -> list:
+    """Put ``arrays`` under ``"stats"`` at ``keys`` of ``model.state`` and
+    return what was there. The tree keeps its structure: the step sees the
+    same signature."""
+    state = model.state
+    old = [state[k]["stats"] for k in keys]
+    new = dict(zip(keys, arrays))
+    if isinstance(state, dict):
+        model.state = {k: {**s, "stats": new[k]} if k in new else s
+                       for k, s in state.items()}
+    else:
+        model.state = tuple({**s, "stats": new[i]} if i in new else s
+                            for i, s in enumerate(state))
+    return old
+
+
+def _blank_like(arrays) -> list:
+    """Zeros on the device with each array's shape, dtype and placement, made
+    by a transfer: nothing is compiled for them."""
+    return [jax.device_put(np.zeros(a.shape, a.dtype),
+                           a.sharding if a.committed else None)
+            for a in arrays]
+
+
+class StepReports:
+    """What ``fit()`` tells its listeners of each step: the loss on the host,
+    the step counters of the layers that keep some in their state under
+    ``"stats"`` (``layer.publish_stats``, in the same fetch: no second wait
+    for the device), ``resilience.note_score``, ``iteration_done``.
+
+    Waiting for step N's loss right after its dispatch leaves the device
+    with nothing queued while the host reports, pulls the next batch and
+    walks the next call's signature. So the report of step N is made after
+    step N + 1 has been enqueued, and the last one when the stream ends:
+    every listener gets the ``iteration``, ``score`` and ``batch_size`` of
+    every step, in order; only the moment moves. The loop stays synchronous
+    where something attached must act on the model at its own step: a
+    ``DivergenceGuard``, a listener that declares ``reads_model``, or a
+    score that is a host float already (the solvers).
+
+    The step donates the state, ``"stats"`` leaves included, so step N's
+    are not in it when step N + 1 is dispatched: :meth:`hold` keeps them
+    for the report and puts in their place arrays the step overwrites
+    without reading them (blank ones the first time of a ``fit()`` call,
+    then those of the step reported last). The compiled step is the same.
+
+    ``site`` is ``"mln"`` or ``"cg"``: the spans ``<site>.loss_fetch`` and
+    ``<site>.listeners`` carry the ``step=`` of the step they report, and the
+    counters ``dl4j_fit_fetch_total{site}`` /
+    ``dl4j_fit_fetch_overlapped_total{site}`` say how often the wait lay
+    behind the next step.
+    """
+
+    def __init__(self, model, site: str, layers, overlap: bool):
+        self.model = model
+        self.site = site
+        self.layers = layers        # model.state's keys -> layer
+        self.overlap = bool(overlap) and not any(
+            getattr(l, "reads_model", False) for l in model.listeners)
+        self._keys = _stats_keys(model.state)
+        self._pending = None        # (loss, step, iteration, n_real)
+        self._held = None           # the pending step's "stats", taken out
+        self._spare = None          # "stats" arrays of a step reported
+
+    def _stats(self) -> list:
+        return [self.model.state[k]["stats"] for k in self._keys]
+
+    def hold(self) -> None:
+        """Before a dispatch: the pending step's counters out of the state
+        that the dispatch donates."""
+        if self._pending is None or not self._keys:
+            return
+        blanks = self._spare or _blank_like(self._stats())
+        self._spare = None
+        self._held = _swap_stats(self.model, self._keys, blanks)
+
+    def step(self, loss, step: int, n_real: int) -> None:
+        """After a dispatch: report the step before it, and this one too
+        where the loop is synchronous."""
+        prev, self._pending = self._pending, None
+        if prev is not None:
+            self._report(prev, overlapped=True)
+        this = (loss, step, self.model.iteration, n_real)
+        if self.overlap:
+            self._pending = this
+        else:
+            self._report(this, overlapped=False)
+
+    def flush(self) -> None:
+        """The stream has ended, or the loop is about to raise: report the
+        pending step. Its counters go back into the state if a dispatch that
+        never came about had them taken out."""
+        pending, self._pending = self._pending, None
+        if pending is None:
+            return
+        if self._held is not None:
+            _swap_stats(self.model, self._keys, self._held)
+            self._held = None
+        self._report(pending, overlapped=False)
+
+    def flush_quietly(self) -> None:
+        """:meth:`flush` for a loop that is raising already: what the report
+        raises in its turn is logged, and the loop's own exception goes on."""
+        try:
+            self.flush()
+        except Exception:
+            logger.exception("%s.fit: the pending step's report failed",
+                             self.site)
+
+    def _report(self, pending, overlapped: bool) -> None:
+        from deeplearning4j_tpu.train import resilience
+
+        loss, step, iteration, n_real = pending
+        model, site = self.model, self.site
+        stats, self._held = self._held, None
+        if stats is None:
+            stats = self._stats()
+        with obs.span(f"{site}.loss_fetch", step=step):
+            if stats:
+                loss, values = jax.device_get(  # graftlint: disable=host-sync
+                    (loss, stats))
+                for k, v in zip(self._keys, values):
+                    self.layers[k].publish_stats(k, v)
+            score = float(loss)  # graftlint: disable=host-sync
+        _FETCHED.inc(site=site)
+        if overlapped:
+            _OVERLAPPED.inc(site=site)
+            self._spare = stats or None
+        resilience.note_score(score)
+        with obs.span(f"{site}.listeners", step=step):
+            for l in model.listeners:
+                l.iteration_done(model, iteration, score, n_real)
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ class CheckpointListener(TrainingListener):
     keep_all / keep_last=k / keep_last_and_every=(k, n)."""
 
     INDEX = "checkpointInfo.json"
+    reads_model = True      # saves the arrays of the step it is called for
 
     def __init__(
         self,

@@ -335,6 +335,10 @@ class EarlyStoppingTrainer:
                 def __init__(self, cond):
                     self.cond = cond
 
+            # stops the run at the step that met a condition, and hands
+            # needs_model conditions that step's arrays
+            reads_model = True
+
             def __init__(self, conds):
                 self.conds = conds
 

@@ -21,7 +21,22 @@ logger = logging.getLogger("deeplearning4j_tpu")
 
 
 class TrainingListener:
-    """Hook interface. All methods are optional no-ops."""
+    """Hook interface. All methods are optional no-ops.
+
+    ``iteration_done(model, iteration, score, batch_size)`` is called once
+    for every step, in order, with the step's own ``iteration`` (the count
+    of steps made when it ended), ``score`` (its loss, a host float) and
+    ``batch_size`` (its real rows). ``fit()`` makes the call after it has
+    enqueued the next step, so that the device is not left waiting for the
+    host: ``model.params``, ``model.state`` and ``model.iteration`` may then
+    be the next step's already. A listener that reads the model's arrays at
+    ``iteration_done``, or raises there to stop the run at that very step,
+    sets ``reads_model = True``: with one attached ``fit()`` reports every
+    step before it dispatches the next. The last step of a stream is
+    reported before ``on_epoch_end``, which always sees the model as the
+    epoch left it. A listener without the attribute counts as ``False``."""
+
+    reads_model = False
 
     def on_epoch_start(self, model, epoch: int):  # noqa: D102
         pass
@@ -167,6 +182,8 @@ class TimeIterationListener(TrainingListener):
 class EvaluativeListener(TrainingListener):
     """Periodically evaluate on a held-out set (EvaluativeListener.java)."""
 
+    reads_model = True
+
     def __init__(self, data, frequency_epochs: int = 1,
                  out: Optional[Callable[[str], None]] = None):
         self.data = data
@@ -186,6 +203,10 @@ class ComposedListener(TrainingListener):
 
     def __init__(self, listeners: List[TrainingListener]):
         self.listeners = list(listeners)
+
+    @property
+    def reads_model(self) -> bool:
+        return any(getattr(l, "reads_model", False) for l in self.listeners)
 
     def on_epoch_start(self, model, epoch):
         for l in self.listeners:

@@ -71,6 +71,8 @@ class ConvolutionalIterationListener:
     ``probe_input``: [1,H,W,C] (or [B,...]; only the first example is
     rendered, like the reference's minibatch slice)."""
 
+    reads_model = True      # feeds the probe through the reported step's arrays
+
     def __init__(self, probe_input, out_dir: str, frequency: int = 10,
                  max_channels: int = 64):
         if frequency < 1:

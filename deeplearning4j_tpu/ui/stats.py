@@ -58,6 +58,8 @@ class StatsListener(TrainingListener):
     the reference; session_id groups one training run.
     """
 
+    reads_model = True      # the parameters' statistics at the reported step
+
     def __init__(self, storage: StatsStorage, frequency: int = 1,
                  session_id: Optional[str] = None, worker_id: str = "0",
                  histogram_bins: int = 20, collect_histograms: bool = True):

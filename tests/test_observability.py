@@ -475,9 +475,18 @@ class TestProfilerClock:
                         "mln.listeners"]
             for prev, nxt in zip(children, children[1:]):
                 assert spans[prev][0][1] <= spans[nxt][0][0]
-            for c in children:
+            for c in children[:2]:
                 a, b, depth = spans[c][0]
                 assert ia <= a and b <= ib and depth == 1
+            # the step's report is made in the next turn, after that turn's
+            # dispatch (or in the turn that finds the stream at its end), and
+            # carries its own step's number (nn/step_program.py StepReports)
+            (na, nb, _), = by_step[step + 1]["mln.iter"]
+            after = by_step[step + 1].get("mln.fit_batch",
+                                          by_step[step + 1]["mln.feed"])[0][1]
+            for c in children[2:]:
+                a, b, depth = spans[c][0]
+                assert na <= after <= a and b <= nb and depth == 1
             (fa, fb, _), (sa, sb, sdepth) = (spans["mln.fit_batch"][0],
                                              spans["mln.step"][0])
             assert fa <= sa and sb <= fb and sdepth == 2
