@@ -13,7 +13,7 @@ from deeplearning4j_tpu.models.labels import (
 )
 from deeplearning4j_tpu.models.pretrained import init_pretrained, pretrained_path
 from deeplearning4j_tpu.models.zoo import (
-    HybridLM, LatentAttentionLM, LeNet5, ShortConvLM, SimpleCNN,
+    HybridLM, LatentAttentionLM, LeNet5, LoopedLM, ShortConvLM, SimpleCNN,
     TextGenerationLSTM, TransformerLM)
 from deeplearning4j_tpu.models.zoo_graph import (
     AlexNet,
@@ -29,7 +29,7 @@ from deeplearning4j_tpu.models.zoo_graph import (
 
 __all__ = [
     "LeNet5", "SimpleCNN", "TextGenerationLSTM", "TransformerLM", "HybridLM",
-    "LatentAttentionLM", "ShortConvLM",
+    "LatentAttentionLM", "ShortConvLM", "LoopedLM",
     "AlexNet", "VGG16", "VGG19", "ResNet50", "GoogLeNet", "Darknet19",
     "TinyYOLO", "InceptionResNetV1", "FaceNetNN4Small2",
     "init_pretrained", "pretrained_path",

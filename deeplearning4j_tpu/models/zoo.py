@@ -284,3 +284,44 @@ def ShortConvLM(layer_types, vocab_size: int, d_model: int, n_dense: int = 0,
         seed=seed,
         dtype=dtype,
     )
+
+
+def LoopedLM(vocab_size: int, d_model: int, n_layers: int, loops: int,
+             attention: dict = None, dense_width: int = 0, eps: float = 1e-6,
+             remat: bool = False, beta: float = 0.1, updater=None,
+             seed: int = 12345, dtype: str = "float32") -> MultiLayerConfiguration:
+    """A looped language model of the Ouro kind: an embedding, one
+    ``LoopedStack`` of ``n_layers`` layers run ``loops`` times with the same
+    weights (a layer is two sandwich-normed ``ResidualBlock``s, ``x <- x +
+    RMSNorm_2(mixer(RMSNorm_1(x)))``, around a ``GroupedQueryAttention``
+    built from ``attention`` and then a ``GatedMLP`` of ``dense_width``; a
+    final RMSNorm after every loop), and a ``LoopExitOutputLayer``: an untied
+    bias-free head and an exit gate after every loop, the loss the expected
+    cross-entropy under the gate's exit distribution less ``beta`` times its
+    entropy. ``remat`` recomputes each block, and each loop's logits, in the
+    backward pass."""
+    from deeplearning4j_tpu.nn.layers import (
+        EmbeddingSequence,
+        GatedMLP,
+        GroupedQueryAttention,
+        LoopedStack,
+        LoopExitOutputLayer,
+        ResidualBlock,
+    )
+
+    block = lambda mixer: ResidualBlock(mixer=mixer, eps=eps, remat=remat,  # noqa: E731
+                                        post_norm=True)
+    blocks = tuple(b for _ in range(n_layers) for b in (
+        block(GroupedQueryAttention(eps=eps, **(attention or {}))),
+        block(GatedMLP(width=dense_width))))
+    return MultiLayerConfiguration(
+        layers=(EmbeddingSequence(n_in=vocab_size, n_out=d_model),
+                LoopedStack(blocks=blocks, loops=loops, eps=eps),
+                LoopExitOutputLayer(n_out=vocab_size, activation="softmax",
+                                    loss="mcxent", loops=loops, beta=beta,
+                                    remat=remat)),
+        input_type=InputType.recurrent(vocab_size),
+        updater=updater or {"type": "adam", "lr": 3e-4},
+        seed=seed,
+        dtype=dtype,
+    )

@@ -51,6 +51,7 @@ from deeplearning4j_tpu.nn.layers.attention import (
 )
 from deeplearning4j_tpu.nn.layers.moe import GatedMLP, MixtureOfExperts, SparseMoE
 from deeplearning4j_tpu.nn.layers.residual import ResidualBlock
+from deeplearning4j_tpu.nn.layers.looped import LoopedStack, LoopExitOutputLayer
 from deeplearning4j_tpu.nn.layers.mtp import MTPOutputLayer
 from deeplearning4j_tpu.nn.layers.shortconv import ShortConvMixer
 from deeplearning4j_tpu.nn.layers.ssm import Mamba2Mixer
@@ -115,6 +116,8 @@ __all__ = [
     "GatedMLP",
     "MultiHeadLatentAttention",
     "MTPOutputLayer",
+    "LoopedStack",
+    "LoopExitOutputLayer",
     "ResidualBlock",
     "Mamba2Mixer",
     "ShortConvMixer",

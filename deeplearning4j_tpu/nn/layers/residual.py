@@ -1,6 +1,7 @@
 """The pre-norm residual wrapper of the hybrid stacks:
 ``x <- x + mixer(RMSNorm(x))`` with any mixer layer (a state-space mixer,
-attention, an expert layer), one mixer a layer."""
+attention, an expert layer), one mixer a layer; with ``post_norm`` the
+sandwich form ``x <- x + RMSNorm_2(mixer(RMSNorm_1(x)))``."""
 
 from __future__ import annotations
 
@@ -31,11 +32,17 @@ class ResidualBlock(LayerConfig):
     equals the model width an attention block keeps twice its input where it
     kept once, and where ``H * Dv`` is twice the width (JoyAI-LLM-Flash)
     three times. The mixer's state (an expert layer's routing bias and load
-    counters) is this layer's state."""
+    counters) is this layer's state.
+
+    ``post_norm`` norms the mixer's output too, with a gain of its own
+    (``"post_norm"``), before it joins the residual stream: the sandwich
+    norm of Ouro's layers. Off, the parameter tree and the step are what
+    they were before the field."""
 
     mixer: Any = None           # a LayerConfig
     eps: float = 1e-5
     remat: bool = False
+    post_norm: bool = False
 
     def output_type(self, input_type: InputType) -> InputType:
         return input_type
@@ -47,8 +54,11 @@ class ResidualBlock(LayerConfig):
         return {"mixer": self.mixer}
 
     def init(self, key, input_type, dtype=jnp.float32):
-        return {"norm": {"gamma": jnp.ones((input_type.size,), dtype)},
-                "mixer": self.mixer.init(key, input_type, dtype)}
+        p = {"norm": {"gamma": jnp.ones((input_type.size,), dtype)},
+             "mixer": self.mixer.init(key, input_type, dtype)}
+        if self.post_norm:
+            p["post_norm"] = {"gamma": jnp.ones((input_type.size,), dtype)}
+        return p
 
     def init_state(self, input_type: InputType):
         return self.mixer.init_state(input_type)
@@ -62,6 +72,8 @@ class ResidualBlock(LayerConfig):
         def body(p, st, xx, r, m):
             h = rms_norm(xx, p["norm"]["gamma"], self.eps)
             y, new_st = self.mixer.apply(p["mixer"], st, h, train=train, rng=r, mask=m)
+            if self.post_norm:
+                y = rms_norm(y, p["post_norm"]["gamma"], self.eps)
             return xx + y, new_st
 
         if self.remat:

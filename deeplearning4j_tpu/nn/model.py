@@ -451,10 +451,11 @@ class MultiLayerNetwork:
         with jax.named_scope("loss"):
             shared = getattr(out_layer, "shared_params", dict)()
             with layer_scope(out_layer, len(self.layers) - 1):
-                if shared:
+                if shared or getattr(out_layer, "SCORES_STATE", False):
                     # an output layer that reads other layers' parameters (a
                     # second head over the embedding) declares them and is
-                    # handed them by reference: jax.grad sums the uses
+                    # handed them by reference: jax.grad sums the uses; one
+                    # that keeps step counters returns its state with the loss
                     loss, out_state = out_layer.score_shared(
                         params[-1], state[-1], a, y,
                         shared={k: params[i] for k, i in shared.items()},
