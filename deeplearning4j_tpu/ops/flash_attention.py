@@ -21,7 +21,7 @@ that fit neither (D = 80, an odd head count at D = 64) are transposed to
 ``[B*H, T, D]`` around the calls, as every shape was before, and run the same
 kernels one head a block.
 
-Tiling: each of the three kernels picks its blocks from the shapes of the
+Tiling: each kernel picks its blocks from the shapes of the
 call (``choose_blocks``: a sequence of up to 1024 rows is one grid block,
 a longer one is cut into the largest multiples of 128, up to 512, that
 divide its padded length), and its loop is split by what a tile needs:
@@ -37,10 +37,15 @@ Beyond-reference scope: the reference (DL4J 0.9.2) has no attention layer
 at all (SURVEY.md §5.7); this accelerates the framework's TransformerLM
 extension. Training uses a custom VJP whose backward is ALSO blockwise
 Pallas (FlashAttention-2 style): the forward emits a per-row logsumexp
-residual, the dq kernel grids over q-blocks and the dk/dv kernel over
-k-blocks, each rebuilding p = exp(s - lse) in VMEM — no [T, T] tensor in
-either direction. A rematerialising XLA backward (``bwd="xla"``) remains
-as the correctness oracle and fallback.
+residual, and one kernel gridded over key blocks rebuilds each tile's
+p = exp(s - lse) once in VMEM and takes dv, dk and dq from it, dq added
+into a whole-length float32 accumulator that lives across the key blocks
+(``flash_bwd_dqkv``) — no [T, T] tensor in either direction. Where the
+offsets differ (ring and chunked blocks) or that dq does not fit VMEM, the
+split pair runs instead: a dq kernel over q-blocks and a dk/dv kernel over
+k-blocks, each computing every tile again (``_bwd_plan`` chooses from the
+shapes and offsets). A rematerialising XLA backward (``bwd="xla"``)
+remains as the correctness oracle and fallback.
 
 CPU/tests: ``interpret=True`` runs the identical kernel in the Pallas
 interpreter; the layer's default ("auto") uses the kernel only on TPU and
@@ -69,6 +74,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_BIG = -1e30
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T without a materialised transpose
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
 
 # Mosaic gives a kernel 16 MiB of VMEM unless told otherwise. A call whose
 # estimated working set stays under _VMEM_SHARE of that says nothing; one
@@ -79,7 +85,9 @@ _VMEM_SHARE = 0.75
 _VMEM_HEADROOM = 1.25
 _VMEM_MAX = 100 << 20
 _NAMES = types.MappingProxyType(    # read under jit: not to be mutated
-    {"fwd": "flash_fwd", "dq": "flash_bwd_dq", "dkv": "flash_bwd_dkv"})
+    {"fwd": "flash_fwd", "dq": "flash_bwd_dq", "dkv": "flash_bwd_dkv",
+     "dqkv": "flash_bwd_dqkv"})
+_BY_KEY_BLOCK = ("dkv", "dqkv")     # kernels whose grid runs over key blocks
 _STRAIGHT = 2       # tiles of a statically known count emitted without a loop
 # What a forward rule calls the two results its backward reads again, here and
 # in ops/flash_mla.py: a ``jax.checkpoint`` whose policy saves these names
@@ -108,6 +116,12 @@ def _and(a, b):
 def _dot_nt(a, b):
     """a @ b.T, float32 result, operands in the type they arrive in."""
     return lax.dot_general(a, b, _NT, preferred_element_type=jnp.float32)
+
+
+def _dot_tn(a, b):
+    """a.T @ b, float32 result: the one product of the one-kernel backward
+    whose left operand is a transposed tile (dq from ds^T)."""
+    return lax.dot_general(a, b, _TN, preferred_element_type=jnp.float32)
 
 
 def _aligned(i, block):
@@ -199,7 +213,7 @@ def _query_ranges(ki, block_q, block_k, q_pad, k_pad, t_real_q, causal,
 
 
 class _Layout(NamedTuple):
-    """How the three kernels find a head in the arrays they are handed.
+    """How the kernels find a head in the arrays they are handed.
 
     ``heads`` heads of width ``D`` share one lane block of ``heads * D``
     lanes; a role (q, k or v) spans ``groups`` such blocks, and its first one
@@ -369,8 +383,10 @@ def _lane(n: int) -> int:
 
 def _working_set(kernel: str, bq: int, bk: int, q_pad: int, k_pad: int,
                  D: int, item: int, has_kmask: bool, heads: int = 1) -> int:
-    """Bytes of VMEM one grid program of ``kernel`` ("fwd", "dq", "dkv")
-    holds at blocks (bq, bk): every BlockSpec operand twice (Pallas
+    """Bytes of VMEM one grid program of ``kernel`` ("fwd", "dq", "dkv",
+    "dqkv": "dkv" with dq's whole-length result block, its float32
+    accumulator and one more tile) holds at blocks (bq, bk): every BlockSpec
+    operand twice (Pallas
     double-buffers inputs and outputs), a lane block of ``heads * D`` counted
     at its padding to 128 lanes, a [1, n] float32 row at its sublane padding
     to 8, one lse or delta row a head; then the float32 [bq, bk] tiles the
@@ -392,16 +408,19 @@ def _working_set(kernel: str, bq: int, bk: int, q_pad: int, k_pad: int,
         # q and do tiles, whole K and V, lse and delta rows; dq tile
         io = 2 * mat(bq) + 2 * mat(k_pad) + 2 * heads * row(bq) + mat(bq)
         live = 5 * tile + gather * bq * wide * 4
-    elif kernel == "dkv":
+    elif kernel in _BY_KEY_BLOCK:
         # whole q and do with their lse and delta rows, k and v tiles;
         # dk and dv tiles
         io = (2 * mat(q_pad) + 2 * heads * row(q_pad) + 2 * mat(bk)
               + 2 * mat(bk))
         live = 5 * tile + gather * 2 * bk * wide * 4
+        if kernel == "dqkv":
+            io += mat(q_pad)
+            live += tile + q_pad * wide * 4
     else:
         raise ValueError(f"unknown flash kernel {kernel!r}")
     if has_kmask:
-        io += row(k_pad if kernel != "dkv" else bk)
+        io += row(bk if kernel in _BY_KEY_BLOCK else k_pad)
     return 2 * io + live
 
 
@@ -427,11 +446,11 @@ def _padded(T: int) -> int:
 
 def choose_blocks(kernel: str, Tq: int, Tk: int, D: int, item: int,
                   has_kmask: bool = False, heads: int = 1, working_set=None):
-    """(bq, bk) for one of the three kernels, from what the call can see
+    """(bq, bk) for one of the kernels, from what the call can see
     (``heads``: how many share its lane blocks, ``heads_per_block``).
-    The grid side (q for forward and dq, k for dk/dv) is the whole padded
-    length up to ``_MAX_WHOLE``, else its largest divisor that is a multiple
-    of 128 up to ``_MAX_BLOCK``; the loop side the same up to
+    The grid side (q for forward and dq, k for dk/dv and dqkv) is the
+    whole padded length up to ``_MAX_WHOLE``, else its largest divisor that
+    is a multiple of 128 up to ``_MAX_BLOCK``; the loop side the same up to
     ``_MAX_BLOCK``. The same sweep showed a larger VMEM limit to be worth
     more than a smaller block (dk/dv at T = 8192: 7.4 ms at 512 x 512 under
     a raised limit, 11.7 ms at the 128 x 512 that fits the default), so
@@ -448,7 +467,7 @@ def choose_blocks(kernel: str, Tq: int, Tk: int, D: int, item: int,
             return [n]
         return [b for b in range(min(_MAX_BLOCK, n), 127, -128) if n % b == 0]
 
-    on_q = kernel != "dkv"                   # which side the grid runs over
+    on_q = kernel not in _BY_KEY_BLOCK       # which side the grid runs over
     cands = [(bq, bk) for bq in sizes(q_pad, _MAX_WHOLE if on_q else 128)
              for bk in sizes(k_pad, 128 if on_q else _MAX_WHOLE)]
     cands.sort(key=lambda c: c if on_q else c[::-1], reverse=True)
@@ -481,6 +500,29 @@ def _plan(kernels, Tq, Tk, D, item, has_kmask, block_q, block_k,
     return {kn: (bq, bk) for kn in kernels}, q_pad, k_pad
 
 
+def _bwd_plan(Tq, Tk, D, item, has_kmask, block_q, block_k, same_len,
+              heads, aligned):
+    """:func:`_plan` for the backward, which also picks its kernels: the one
+    "dqkv" kernel where the offsets are ``aligned`` (self-attention; the
+    ring's diagonal) and its working set, dq's whole length resident, passes
+    ``_VMEM_MAX``'s test; else the split "dq" and "dkv" pair (chunked blocks
+    at unequal offsets, a length whose dq does not fit), each of which
+    computes every score tile again."""
+    args = (Tq, Tk, D, item, has_kmask, block_q, block_k, same_len, heads)
+    if aligned:
+        blocks, q_pad, k_pad = _plan(("dqkv",), *args)
+        need = _working_set("dqkv", *blocks["dqkv"], q_pad, k_pad, D, item,
+                            has_kmask, heads)
+        if _VMEM_HEADROOM * need <= _VMEM_MAX:
+            return blocks, q_pad, k_pad
+    return _plan(("dq", "dkv"), *args)
+
+
+def _block_kw(blocks):
+    """A backward plan's blocks as :func:`_bwd_pallas_calls` takes them."""
+    return {f"{kn}_blocks": b for kn, b in blocks.items()}
+
+
 def _compiler_params(kernel, bq, bk, q_pad, k_pad, D, item, has_kmask,
                      heads=1):
     """Nothing while the estimate fits the default limit; else the estimate
@@ -502,7 +544,7 @@ def _vmem_params(need: int) -> dict:
 
 
 def _call_kw(kernel, interpret, lay, bq, bk, q_pad, k_pad, dtype, has_kmask):
-    """What the three pallas_calls share: the interpreter switch, the VMEM
+    """What the pallas_calls share: the interpreter switch, the VMEM
     limit, and the name, which carries the heads of a lane block and the
     blocks that ran into the profiler's trace (the roofline patterns of
     benchmark/metrics anchor at the end of the Mosaic call's name, on its
@@ -719,11 +761,20 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     dq_ref[0] = (out * scale).astype(dq_ref.dtype)
 
 
+def _when(cond):
+    """``pl.when`` that also takes a Python bool (a grid of one block along
+    the axis): the body is then emitted, or not, at trace time."""
+    if isinstance(cond, bool):
+        return lambda body: body() if cond else None
+    return pl.when(cond)
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     *rest, lay: _Layout, block_q: int, block_k: int,
                     t_real_q: int, t_real_k: int, q_pad: int, k_pad: int,
                     causal: bool, scale: float, q_off: int = 0,
-                    k_off: int = 0, has_kmask: bool = False):
+                    k_off: int = 0, has_kmask: bool = False,
+                    with_dq: bool = False):
     """dk/dv for one k-block, looping over q-blocks, once a head of the lane
     block: dv = sum_q p^T @ do;  dk = scale * sum_q [p*(do@v^T - delta)]^T @ q.
     Same delta/offset semantics as _bwd_dq_kernel. Optional kmask ref
@@ -733,12 +784,29 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     along the lanes (s^T = k @ q^T, [bk, bq]): the forward's row-layout lse
     and delta then broadcast down the tile as they are stored (no
     row -> column relayout per q-block), and p^T @ do and ds^T @ q are
-    plain matmuls with nothing to transpose."""
-    if has_kmask:
-        km_ref, dk_ref, dv_ref = rest
+    plain matmuls with nothing to transpose.
+
+    ``with_dq`` (the one-kernel backward, "dqkv"): the same tiles also give
+    dq = scale * sum_k ds @ k, added a q tile at a time into a float32
+    [q_pad, lanes] accumulator that lives across the k-block grid axis
+    (zeroed at the first k block, written at the last: that axis runs in
+    order, Mosaic's default). k holds this head's lanes alone, so each
+    head's product fills its own columns of dq and the heads simply add.
+    Refs after the kmask: dq [1, q_pad, lanes] (whole length, resident),
+    dk, dv, then the accumulator."""
+    km_ref = rest[0] if has_kmask else None
+    outs = rest[1:] if has_kmask else rest
+    if with_dq:
+        dq_ref, dk_ref, dv_ref, acc_ref = outs
     else:
-        (dk_ref, dv_ref), km_ref = rest, None
-    ki = 0 if k_pad == block_k else pl.program_id(2)
+        (dk_ref, dv_ref), acc_ref = outs, None
+    n_kb = k_pad // block_k
+    ki = 0 if n_kb == 1 else pl.program_id(2)
+    if with_dq:
+        @_when(ki == 0)
+        def _():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
     k_all = k_ref[0]                                             # [bk, lanes]
     v_all = v_ref[0]
     k_loc = ki * block_k + lax.broadcasted_iota(
@@ -774,6 +842,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                               preferred_element_type=jnp.float32)
             dst = (pt * (_dot_nt(v, do) - delta)).astype(q.dtype)
             dk = dk + jnp.dot(dst, q, preferred_element_type=jnp.float32)
+            if with_dq:
+                acc_ref[pl.ds(start, block_q), :] += _dot_tn(dst, k)
             return dk, dv
 
         dk, dv = _tiles(
@@ -782,18 +852,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_out, dv_out = _keep(lanes, dk, dk_out), _keep(lanes, dv, dv_out)
     dk_ref[0] = (dk_out * scale).astype(dk_ref.dtype)
     dv_ref[0] = dv_out.astype(dv_ref.dtype)
+    if with_dq:
+        @_when(ki == n_kb - 1)
+        def _():
+            dq_ref[0] = (acc_ref[...] * scale).astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC + (
-    "dq_blocks", "dkv_blocks", "t_real_q"))
-def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, lay: _Layout,
-                      dq_blocks, dkv_blocks, q_pad, k_pad, t_real_q, t_real_k,
-                      causal, scale, q_off, k_off, interpret, kmask=None):
-    """The two backward pallas_calls over padded arrays as ``lay`` addresses
+    "dq_blocks", "dkv_blocks", "dqkv_blocks", "t_real_q"))
+def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, lay: _Layout, q_pad,
+                      k_pad, t_real_q, t_real_k, causal, scale, q_off, k_off,
+                      interpret, kmask=None, dq_blocks=None, dkv_blocks=None,
+                      dqkv_blocks=None):
+    """The backward's pallas_calls over padded arrays as ``lay`` addresses
     them (``dot`` and the results are arrays of their own: [N, ., groups *
-    lanes]); returns padded (dq, dk, dv). ``dq_blocks``, ``dkv_blocks``:
-    (bq, bk), each kernel at its own. ``delta`` may already carry the -dlse
-    shift. ``kmask``: optional [B, 1, k_pad] f32 rows, per batch."""
+    lanes]); returns padded (dq, dk, dv). ``dqkv_blocks`` (bq, bk): the one
+    kernel that gives all three; else ``dq_blocks`` and ``dkv_blocks``, the
+    split pair, each kernel at its own (:func:`_bwd_plan` says which).
+    ``delta`` may already carry the -dlse shift. ``kmask``: optional
+    [B, 1, k_pad] f32 rows, per batch."""
     N, dtype = qt.shape[0], qt.dtype
     lanes, rows, mask = _specs(lay, interpret)
     has_km = kmask is not None
@@ -802,6 +879,30 @@ def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, lay: _Layout,
                   has_kmask=has_km)
     args = [qt, kt, vt, dot, lse, delta] + ([kmask] if has_km else [])
     width = lay.groups * lay.heads * lay.D
+
+    def by_key_block(bq, bk):
+        """What the dk/dv and the one-kernel grids over key blocks read."""
+        return [lanes(q_pad, 0, True), lanes(bk, 1), lanes(bk, 2),
+                lanes(q_pad, None, True), rows(q_pad, True),
+                rows(q_pad, True)] + ([mask(bk)] if has_km else [])
+
+    if dqkv_blocks is not None:
+        bq, bk = dqkv_blocks
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk,
+                              t_real_q=t_real_q, with_dq=True, **shared),
+            grid=(N, lay.groups, k_pad // bk),
+            in_specs=by_key_block(bq, bk),
+            out_specs=[lanes(q_pad, None, True), lanes(bk, None),
+                       lanes(bk, None)],
+            out_shape=[jax.ShapeDtypeStruct((N, q_pad, width), dtype)]
+            + [jax.ShapeDtypeStruct((N, k_pad, width), dtype)] * 2,
+            scratch_shapes=[pltpu.VMEM((q_pad, lay.heads * lay.D),
+                                       jnp.float32)],
+            **_call_kw("dqkv", interpret, lay, bq, bk, q_pad, k_pad, dtype,
+                       has_km),
+        )(*args)
+        return dq, dk, dv
 
     bq, bk = dq_blocks
     dq = pl.pallas_call(
@@ -820,9 +921,7 @@ def _bwd_pallas_calls(qt, kt, vt, dot, lse, delta, *, lay: _Layout,
         functools.partial(_bwd_dkv_kernel, block_q=bq, block_k=bk,
                           t_real_q=t_real_q, **shared),
         grid=(N, lay.groups, k_pad // bk),
-        in_specs=[lanes(q_pad, 0, True), lanes(bk, 1), lanes(bk, 2),
-                  lanes(q_pad, None, True), rows(q_pad, True),
-                  rows(q_pad, True)] + ([mask(bk)] if has_km else []),
+        in_specs=by_key_block(bq, bk),
         out_specs=[lanes(bk, None), lanes(bk, None)],
         out_shape=[jax.ShapeDtypeStruct((N, k_pad, width), dtype)] * 2,
         **_call_kw("dkv", interpret, lay, bq, bk, q_pad, k_pad, dtype,
@@ -857,6 +956,22 @@ def _delta_rows(do, o, dims, t_pad, dlse=None):
     return _row_layout(delta, B, H, T, t_pad)
 
 
+def _side_by_side(parts):
+    """[B, T, C_i] arrays joined along their last axis, written as the sum of
+    their zero-padded copies: XLA fuses that into what reads the join (the
+    projection's weight, bias and input gradients), where it writes a
+    concatenation of one call's three results out to HBM first (a
+    [B, T, 3*H*D] copy a layer: on a v5e about 6 ms of a GPT-2 medium
+    float32 training step)."""
+    width = sum(x.shape[-1] for x in parts)
+    out, at = 0, 0
+    for x in parts:
+        out = out + jnp.pad(
+            x, ((0, 0), (0, 0), (at, width - at - x.shape[-1])))
+        at += x.shape[-1]
+    return out
+
+
 def _flash_bwd_pallas(q, k, v, kmask, o, lse, g, H, causal: bool, block_q,
                       block_k, interpret: bool):
     """Blockwise backward: scores are rebuilt in VMEM from q/k/v and the
@@ -866,20 +981,20 @@ def _flash_bwd_pallas(q, k, v, kmask, o, lse, g, H, causal: bool, block_q,
     fused = k is None
     ops, lay, dims = _self_operands(q, k, v, H)
     B, T, H, D = dims
-    blocks, t_pad, _ = _plan(("dq", "dkv"), T, T, D, q.dtype.itemsize,
-                             kmask is not None, block_q, block_k, True,
-                             lay.heads)
+    blocks, t_pad, _ = _bwd_plan(T, T, D, q.dtype.itemsize,
+                                 kmask is not None, block_q, block_k, True,
+                                 lay.heads, aligned=True)
     km = _pad_km(kmask, t_pad) if kmask is not None else None
     dq, dk, dv = _bwd_pallas_calls(
         *_pad_each(ops, t_pad), _operand(g.reshape(dims), t_pad, lay), lse,
-        _delta_rows(g, o, dims, t_pad), lay=lay, dq_blocks=blocks["dq"],
-        dkv_blocks=blocks["dkv"], q_pad=t_pad, k_pad=t_pad, t_real_q=T,
-        t_real_k=T, causal=causal, scale=1.0 / (D ** 0.5), q_off=0, k_off=0,
-        interpret=interpret, kmask=km)
+        _delta_rows(g, o, dims, t_pad), lay=lay, q_pad=t_pad, k_pad=t_pad,
+        t_real_q=T, t_real_k=T, causal=causal, scale=1.0 / (D ** 0.5),
+        q_off=0, k_off=0, interpret=interpret, kmask=km,
+        **_block_kw(blocks))
     if fused and not lay.transposed:
         # joined as [B, T, C] arrays: a [.., H, D] view of them with D = 64
         # would be laid out T-minor and cost a copy of each
-        return jnp.concatenate((dq, dk, dv), axis=2)[:, :T], None, None
+        return _side_by_side((dq, dk, dv))[:, :T], None, None
     grads = tuple(_result(x, B, T, H, lay) for x in (dq, dk, dv))
     if fused:
         return join_qkv(*grads), None, None
@@ -1012,8 +1127,8 @@ def flash_attention(q, k, v, *, kmask=None, causal: bool = False,
     """Blockwise flash attention over [B, T, H, D] (differentiable).
 
     Forward runs the Pallas kernel (never materialises [T, T]); the
-    backward is a blockwise Pallas kernel pair too (dq grid over q-blocks,
-    dk/dv grid over k-blocks) consuming the forward's logsumexp residual —
+    backward is one blockwise Pallas kernel too (grid over k-blocks, dq
+    accumulated across them) consuming the forward's logsumexp residual —
     ``bwd="xla"`` selects the rematerialising XLA fallback (the tests'
     correctness oracle). ``interpret=True`` runs the kernels in the Pallas
     interpreter (CPU tests). ``kmask`` [B, T]: key validity (1=real,
@@ -1108,19 +1223,18 @@ def _flash_block_diff_bwd(q_offset, k_offset, causal, block_q, block_k,
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     lay = _layout(H, D)
-    blocks, q_pad, k_pad = _plan(("dq", "dkv"), Tq, Tk, D, q.dtype.itemsize,
-                                 kmask is not None, block_q, block_k,
-                                 heads=lay.heads)
+    blocks, q_pad, k_pad = _bwd_plan(Tq, Tk, D, q.dtype.itemsize,
+                                     kmask is not None, block_q, block_k,
+                                     False, lay.heads, q_offset == k_offset)
     km = _pad_km(kmask, k_pad) if kmask is not None else None
     dq, dk, dv = _bwd_pallas_calls(
         _operand(q, q_pad, lay), _operand(k, k_pad, lay),
         _operand(v, k_pad, lay), _operand(do, q_pad, lay),
         _row_layout(lse, B, H, Tq, q_pad),
         _delta_rows(do, o, q.shape, q_pad, dlse), lay=lay,
-        dq_blocks=blocks["dq"], dkv_blocks=blocks["dkv"],
         q_pad=q_pad, k_pad=k_pad, t_real_q=Tq, t_real_k=Tk, causal=causal,
         scale=1.0 / (D ** 0.5), q_off=q_offset, k_off=k_offset,
-        interpret=interpret, kmask=km)
+        interpret=interpret, kmask=km, **_block_kw(blocks))
     dkm = jnp.zeros_like(kmask) if kmask is not None else None
     return (_result(dq, B, Tq, H, lay), _result(dk, B, Tk, H, lay),
             _result(dv, B, Tk, H, lay), dkm)
@@ -1166,7 +1280,10 @@ def merge_attention_blocks(parts):
 # 2 MiB at T = 1024 float32, 8 MiB at T = 8192 bfloat16) beside bq-row
 # tiles of q/o (and do/dq) and three to five float32 [bq, bk] score tiles.
 # dk/dv: the whole q and do with their lse/delta rows beside bk-row tiles of
-# k/v/dk/dv. The chooser keeps that under 12 of the default 16 MiB by
+# k/v/dk/dv; the one backward kernel adds dq's whole [t_pad, lanes] result
+# block and its float32 accumulator (at T = 8192 and 128 float32 lanes,
+# 4 MiB of accumulator and twice 4 MiB of result). The chooser keeps that
+# under 12 of the default 16 MiB by
 # shrinking the blocks (T = 8192 bfloat16 still runs 512 x 256 forward);
 # where the whole-sequence operands alone pass it (T = 16384 at D = 128
 # bfloat16, T = 32768 at D = 64) the call runs 128 x 128 and asks Mosaic for

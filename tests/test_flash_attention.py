@@ -586,7 +586,7 @@ class TestBlockChooser:
     the tier-1 kernels run in the interpreter at explicit small blocks, so
     the sizes the chip runs at are checked here from the estimate alone."""
 
-    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "dqkv"])
     @pytest.mark.parametrize("T,D,item,mask,heads", [
         (50, 64, 4, False, 1),     # below one block: the whole length
         (128, 64, 2, True, 1),
@@ -614,7 +614,7 @@ class TestBlockChooser:
         # _MAX_WHOLE (one program a head, no loop) and the same divisor past it
         cap = max(b for b in (t_pad, 128, 256, 384, 512)
                   if t_pad % b == 0 and b <= fa._MAX_BLOCK)
-        grid, loop = (bq, bk) if kernel != "dkv" else (bk, bq)
+        grid, loop = (bk, bq) if kernel in fa._BY_KEY_BLOCK else (bq, bk)
         assert loop == cap
         assert grid == (t_pad if t_pad <= fa._MAX_WHOLE else cap)
         need = fa._working_set(kernel, bq, bk, t_pad, t_pad, D, item, mask,
@@ -650,7 +650,7 @@ class TestBlockChooser:
         b = fa._working_set("fwd", 256, 256, 1024, 1024, 128, 4, False)
         assert a == b           # D = 64 occupies 128 lanes all the same
 
-    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+    @pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "dqkv"])
     def test_two_heads_a_block_count_their_rows_and_results(self, kernel):
         """Two heads of 64 in a lane block: the operands are the 128 lanes
         one head's were counted at, the float32 tiles are one head's (the
@@ -658,9 +658,9 @@ class TestBlockChooser:
         row a block and the result the heads' columns are gathered into."""
         one = fa._working_set(kernel, 512, 512, 1024, 1024, 64, 4, False)
         two = fa._working_set(kernel, 512, 512, 1024, 1024, 64, 4, False, 2)
-        rows = {"fwd": 1, "dq": 2, "dkv": 2}[kernel] * 8 * (
-            1024 if kernel == "dkv" else 512) * 4
-        results = {"fwd": 1, "dq": 1, "dkv": 2}[kernel] * 512 * 128 * 4
+        rows = {"fwd": 1, "dq": 2, "dkv": 2, "dqkv": 2}[kernel] * 8 * (
+            1024 if kernel in fa._BY_KEY_BLOCK else 512) * 4
+        results = {"fwd": 1, "dq": 1, "dkv": 2, "dqkv": 2}[kernel] * 512 * 128 * 4
         assert two - one == 2 * rows + results
 
     @pytest.mark.parametrize("same_len", [False, True])
@@ -839,11 +839,154 @@ class TestTileSchedule:
             (0, 4, {"diag": True, "tail": False})]
 
 
+def _split_plan(*args, **kw):
+    """``_bwd_plan`` held to the split dq + dk/dv pair, what every backward
+    ran before the one kernel."""
+    return fa._plan(("dq", "dkv"), *args[:9])
+
+
+def _kernel_names(fn, *args):
+    return {e.params["name"]
+            for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr, [])
+            if e.primitive.name == "pallas_call"}
+
+
+class TestOneKernelBackward:
+    """The backward of a call at aligned offsets is one kernel (``dqkv``)
+    that takes dq, dk and dv from one pass over the score tiles: against the
+    XLA oracle (``bwd="xla"``) and against the split pair it replaces, at
+    each addressing, mask and schedule it meets. The split pair stays for
+    chunked blocks at unequal offsets and for lengths whose whole dq does
+    not fit VMEM."""
+
+    CASES = [
+        # B, T, H, key-value heads, D, causal, masked, blocks, fused qkv
+        pytest.param(2, 64, 4, 4, 64, True, False, (16, 32), True,
+                     id="h2-fused-qkv"),
+        pytest.param(2, 64, 1, 1, 128, True, False, (32, 16), False,
+                     id="d128-h1"),
+        pytest.param(1, 48, 32, 8, 64, True, False, (16, 16), False,
+                     id="gqa-32-8-d64"),
+        pytest.param(2, 40, 2, 2, 80, True, False, (16, 16), False,
+                     id="transposed-d80"),
+        pytest.param(2, 50, 4, 4, 64, True, False, (16, 32), False,
+                     id="padded-t50"),
+        pytest.param(2, 64, 4, 4, 32, True, True, (32, 16), False,
+                     id="kmask"),
+        pytest.param(2, 64, 4, 4, 64, False, False, (16, 16), False,
+                     id="non-causal"),
+        # the chooser's blocks: three 512-row key blocks, dq accumulated
+        # across the grid's key axis
+        pytest.param(1, 1536, 2, 2, 64, True, False, None, False,
+                     id="t1536-three-key-blocks"),
+    ]
+
+    @pytest.mark.parametrize("B,T,H,kv,D,causal,masked,blocks,fused", CASES)
+    def test_matches_the_oracle_and_the_split_pair(
+            self, monkeypatch, B, T, H, kv, D, causal, masked, blocks, fused):
+        rs = np.random.RandomState(T + H + D)
+        q = _qkv(rs, B, T, H, D)[0]
+        k, v = _qkv(rs, B, T, kv, D)[1:]
+        km = TestKmask._mask(rs, B, T) if masked else None
+        bq, bk = blocks or (None, None)
+        kw = dict(kmask=km, causal=causal, block_q=bq, block_k=bk,
+                  interpret=True)
+        probe = jnp.asarray(rs.randn(B, T, H * D).astype(np.float32))
+
+        def loss(bwd):
+            def f(q, k, v):
+                k, v = (jnp.repeat(x, H // kv, axis=2) for x in (k, v))
+                if fused:
+                    out = fa.flash_attention_qkv(fa.join_qkv(q, k, v), H,
+                                                 bwd=bwd, **kw)
+                else:
+                    out = flash_attention(q, k, v, bwd=bwd, **kw)
+                return jnp.sum(out.reshape(B, T, H * D) * probe)
+            return jax.grad(f, argnums=(0, 1, 2))
+
+        bwd_names = lambda: [n for n in _kernel_names(    # noqa: E731
+            loss("pallas"), q, k, v) if "_bwd_" in n]
+        (name,) = bwd_names()
+        assert name.startswith("flash_bwd_dqkv_"), name
+        one, oracle = loss("pallas")(q, k, v), loss("xla")(q, k, v)
+        monkeypatch.setattr(fa, "_bwd_plan", _split_plan)
+        assert sorted(n.split("_q")[0].rsplit("_h", 1)[0]
+                      for n in bwd_names()) == ["flash_bwd_dkv",
+                                                "flash_bwd_dq"]
+        split = loss("pallas")(q, k, v)
+        for a, b, c in zip(one, oracle, split):
+            assert np.all(np.isfinite(np.asarray(a)))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-4)
+            np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                       rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("H,D", [(2, 16), (4, 64)],
+                             ids=["transposed", "h2"])
+    def test_chunked_blocks_at_unequal_offsets_keep_the_split_pair(self, H,
+                                                                   D):
+        """The second q shard against both key chunks: the chunk at its own
+        offset takes the one kernel, the earlier chunk (unequal offsets) the
+        split pair, and the merged gradients are the full causal
+        attention's rows."""
+        from deeplearning4j_tpu.ops.flash_attention import (
+            flash_attention_block_grad, merge_attention_blocks)
+
+        rs = np.random.RandomState(11)
+        B, T = 1, 64
+        q, k, v = _qkv(rs, B, T, H, D)
+        half = T // 2
+
+        def chunk(ko):
+            return lambda q, k, v: flash_attention_block_grad(
+                q[:, half:], k[:, ko:ko + half], v[:, ko:ko + half],
+                q_offset=half, k_offset=ko, causal=True, block_q=16,
+                block_k=16, interpret=True)
+
+        for ko, want in ((0, {"dq", "dkv"}), (half, {"dqkv"})):
+            names = _kernel_names(jax.grad(
+                lambda q, k, v: jnp.sum(chunk(ko)(q, k, v)[0]),
+                argnums=(0, 1, 2)), q, k, v)
+            assert {n.split("flash_bwd_")[1].split("_")[0]
+                    for n in names if "_bwd_" in n} == want, names
+
+        def loss_chunks(q, k, v):
+            parts = [chunk(ko)(q, k, v) for ko in (0, half)]
+            return jnp.sum(merge_attention_blocks(parts) ** 2)
+
+        def loss_full(q, k, v):
+            return jnp.sum(_reference(q, k, v, True)[:, half:] ** 2)
+
+        gc = jax.grad(loss_chunks, argnums=(0, 1, 2))(q, k, v)
+        gf = jax.grad(loss_full, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gc, gf):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=5e-4)
+
+    @pytest.mark.parametrize("T,D,heads", [(1024, 64, 2), (256, 64, 2),
+                                           (4096, 128, 1), (8192, 64, 2),
+                                           (1000, 80, 1)])
+    def test_the_plan_takes_one_kernel_where_its_dq_fits(self, T, D, heads):
+        """The cells' shapes (and a transposed one) take the one kernel at
+        the blocks the chooser gives dk/dv; unequal offsets, or a length
+        whose whole float32 dq passes ``_VMEM_MAX``, the split pair."""
+        args = (T, T, D, 4, False, None, None, True, heads)
+        blocks, t_pad, _ = fa._bwd_plan(*args, aligned=True)
+        assert blocks == {"dqkv": fa.choose_blocks("dkv", T, T, D, 4,
+                                                   False, heads)}
+        assert t_pad == fa._padded(T)
+        assert set(fa._bwd_plan(*args, aligned=False)[0]) == {"dq", "dkv"}
+        long = (65536, 65536, 128, 4, False, None, None, True, 1)
+        assert set(fa._bwd_plan(*long, aligned=True)[0]) == {"dq", "dkv"}
+
+
 class TestKernelNamesAndResults:
-    """benchmark/metrics/flash_{fwd,bwd}_roofline.json find the kernels by
-    the END of the Mosaic call's name, which is its result types: forward
-    = (x[BH,t_pad,D], f32[BH,1,t_pad]); dk/dv = two equal results; dq = one
-    three-dimensional result. The names carry the blocks that ran. A change
+    """benchmark/metrics/flash_fwd_roofline.json finds the forward by the
+    END of the Mosaic call's name, which is its result types: (x[BH,t_pad,D],
+    f32[BH,1,t_pad]); the one backward kernel is found by the start of its
+    name, ``flash_bwd_dqkv_``, and its three equal results are read by
+    neither pattern of flash_bwd_roofline.json (two equal results; one
+    three-dimensional result). The names carry the blocks that ran. A change
     that would silence a roofline fails here first."""
 
     @staticmethod
@@ -863,8 +1006,9 @@ class TestKernelNamesAndResults:
         """``tag``: the heads that share a lane block, in the name; none
         where the call transposes to [B*H, T, D] (an odd head count at
         D = 64 says so by its name). Head-addressed results are
-        [B, t_pad, H*D]: still one rank-3 array for dq, two equal ones for
-        dk/dv, and a float32 [B*H, 1, t_pad] beside the forward's."""
+        [B, t_pad, H*D]: three equal ones for the one backward kernel
+        (dq, dk, dv; neither accepted ``flash_bwd_roofline`` pattern reads
+        three) and a float32 [B*H, 1, t_pad] beside the forward's."""
         B = 2
         q = jnp.ones((B, T, H, D), jnp.float32)
         jp = jax.make_jaxpr(jax.grad(lambda q, k, v: jnp.sum(flash_attention(
@@ -879,8 +1023,7 @@ class TestKernelNamesAndResults:
         assert got == {
             f"flash_fwd{tag}_q{bq}_k{bk}": [(res, x),
                                             ((BH, 1, t_pad), "float32")],
-            f"flash_bwd_dq{tag}_q{bq}_k{bk}": [(res, x)],
-            f"flash_bwd_dkv{tag}_q{bq}_k{bk}": [(res, x)] * 2,
+            f"flash_bwd_dqkv{tag}_q{bq}_k{bk}": [(res, x)] * 3,
         }
 
     def test_chosen_blocks_are_in_the_names(self):
@@ -893,7 +1036,7 @@ class TestKernelNamesAndResults:
             argnums=(0, 1, 2)))(q, q, q)
         names = {e.params["name"] for e in self._pallas_eqns(jp.jaxpr, [])}
         want = {f"{fa._NAMES[kn]}_q%d_k%d" % fa.choose_blocks(kn, T, T, D, 2)
-                for kn in ("fwd", "dq", "dkv")}
+                for kn in ("fwd", "dqkv")}
         assert names == want
 
 
@@ -1044,7 +1187,7 @@ class TestHeadAddressing:
                                        (1, 4096, 32, 128)])
     def test_no_q_sized_transpose_in_the_gradient(self, shape, fused):
         """The jaxpr of ``jax.grad`` through the kernels at the benchmark's
-        three attention shapes: three Pallas calls and no ``transpose`` of
+        three attention shapes: two Pallas calls and no ``transpose`` of
         an array as large as q (what ``_pad_bh``/``_from_bh`` were twelve
         times a layer); delta's [B, T, H] -> [B, H, T] is the only
         transposition left, a 64th of that."""
@@ -1060,7 +1203,7 @@ class TestHeadAddressing:
         jp = jax.make_jaxpr(jax.grad(fn, argnums=tuple(range(len(args)))))(
             *args)
         eqns = _eqns(jp.jaxpr, [])
-        assert sum(e.primitive.name == "pallas_call" for e in eqns) == 3
+        assert sum(e.primitive.name == "pallas_call" for e in eqns) == 2
         moved = [e for e in eqns if e.primitive.name == "transpose"
                  and e.invars[0].aval.size >= B * T * H * D]
         assert moved == []

@@ -571,11 +571,11 @@ class TestProfilerClock:
         # each name holds the heads that share a lane block (two of 64) and
         # ends in the blocks the kernel ran at (one 128-row tile)
         names = lambda t: set(re.findall(  # noqa: E731
-            r"flash_(?:fwd|bwd_dq|bwd_dkv)(?:_h\d+)?_q\d+_k\d+", t))
+            r"flash_(?:fwd|bwd_dqkv|bwd_dq|bwd_dkv)(?:_h\d+)?_q\d+_k\d+", t))
         assert names(fwd) == {"flash_fwd_h2_q128_k128"}
+        # the backward is one kernel that gives dq, dk and dv together
         assert names(both) == {"flash_fwd_h2_q128_k128",
-                               "flash_bwd_dq_h2_q128_k128",
-                               "flash_bwd_dkv_h2_q128_k128"}
+                               "flash_bwd_dqkv_h2_q128_k128"}
 
     def test_scopes_leave_the_steps_results_bit_identical(self, monkeypatch):
         import contextlib

@@ -1,21 +1,28 @@
-"""Device time of the three flash kernels by tile size and by addressing, read
+"""Device time of the flash kernels by tile size and by addressing, read
 from a profiler trace on the chip (PERF.md section 5 holds the tables this
 printed; the caps in ``ops/flash_attention.py`` ``_MAX_BLOCK`` stand on it).
 
     python tools/flash_tile_sweep.py [--shapes gpt2m-f32,...] [--blocks 128,256,512 | chosen]
         [--baseline path/to/another/flash_attention.py] [--yardstick] [--layer]
         [--out chiprun_out/flash_sweep.jsonl]
+        [--transposed-tn]
     JAX_PLATFORMS=cpu python tools/flash_tile_sweep.py --compile-only
 
 A kernel variant is one jitted call of one kernel over pre-padded operands,
 causal, no mask: ``ours`` over three ``[B, T, H*D]`` arrays, ``fused`` over the
 one ``[B, T, 3*H*D]`` projection the attention layer hands the kernels,
 ``baseline`` (``--baseline``: another copy of the kernel module, the parent
-commit's) over whatever layout that module's kernels take. ``--layer`` adds,
-for this tree and for the baseline, one attention layer's forward and backward
-through the public entry point, from the fused projection to its cotangent:
-its ``all`` is every device operation of the program, so ``all`` less the
-kernels is what the layout costs around them (**kernels + copies**).
+commit's) over whatever layout that module's kernels take. The backward runs
+as the split pair (pass ``bwd``: ``dq`` and ``dkv``) and, where the module has
+it, as the one kernel that gives dq, dk and dv from one pass over the tiles
+(pass ``bwd1``: ``dqkv``); ``--transposed-tn`` adds ``ours.T``, the one
+kernel with its ``ds^T``-on-the-left product written as an explicit transpose
+and a plain matmul in place of a ``dot_general`` that contracts dimension 0.
+``--layer`` adds, for this tree and for the baseline, one attention layer's
+forward and backward through the public entry point, from the fused
+projection to its cotangent: its ``all`` is every device operation of the
+program, so ``all`` less the kernels is what the layout costs around them
+(**kernels + copies**).
 ``--yardstick`` times jax.experimental.pallas.ops.tpu.flash_attention, to say
 what Mosaic allows at the same head width, not to adopt it.
 
@@ -47,13 +54,15 @@ import jax.numpy as jnp         # noqa: E402
 SHAPES = {                      # (B, H, T, D, dtype): what runs today
     "gpt2m-f32-b8-t1024": (8, 16, 1024, 64, "float32"),
     "gpt2m-f32-b32-t256": (32, 16, 256, 64, "float32"),
+    "ouro-f32-t4096-d128": (1, 16, 4096, 128, "float32"),
+    "lfm2-f32-b2-t8192": (2, 32, 8192, 64, "float32"),
     "gpt2m-bf16-b16-t1024": (16, 16, 1024, 64, "bfloat16"),
     "smoke-bf16-t2048-d128": (16, 16, 2048, 128, "bfloat16"),
     "twotower-f32-t4096-d128": (1, 32, 4096, 128, "float32"),
     "long-bf16-t8192": (2, 16, 8192, 64, "bfloat16"),
 }
 REPEATS = 3
-KERNELS = ("fwd", "dq", "dkv")
+KERNELS = ("fwd", "dq", "dkv", "dqkv")
 
 
 def load_module(path):
@@ -63,13 +72,18 @@ def load_module(path):
     return mod
 
 
+def _kernels(mod):
+    """The kernels ``mod`` has: an older module has no one-kernel backward."""
+    return [kn for kn in KERNELS if kn in getattr(mod, "_NAMES", KERNELS[:3])]
+
+
 def _blocks(mod, shape, bq, bk, heads=1):
     B, H, T, D, dtype = shape
     if bq is not None:
-        return {kn: (bq, bk) for kn in KERNELS}
+        return {kn: (bq, bk) for kn in _kernels(mod)}
     kw = {"heads": heads} if heads > 1 else {}   # what the chooser takes
     return {kn: mod.choose_blocks(kn, T, T, D, jnp.dtype(dtype).itemsize, **kw)
-            for kn in KERNELS}
+            for kn in _kernels(mod)}
 
 
 def kernel_fns(mod, shape, bq, bk, fused=False):
@@ -106,8 +120,15 @@ def kernel_fns(mod, shape, bq, bk, fused=False):
         return mod._bwd_pallas_calls(*spread(a), t_real_q=T, **blocks,
                                      **common)
 
-    return ({"fwd": fwd, "bwd": bwd},
-            {"fwd": qkv, "bwd": qkv + (x, rows, rows)})
+    def bwd1(*a):
+        return mod._bwd_pallas_calls(*spread(a), t_real_q=T,
+                                     dqkv_blocks=chosen["dqkv"], **common)
+
+    fns = {"fwd": fwd, "bwd": bwd}
+    if "dqkv" in chosen:
+        fns["bwd1"] = bwd1
+    return fns, {"fwd": qkv, "bwd": qkv + (x, rows, rows),
+                 "bwd1": qkv + (x, rows, rows)}
 
 
 def layer_fns(mod, shape, bq, bk):
@@ -182,7 +203,23 @@ def calls_by_annotation(trace_dir, prefix):
 
 
 def kind(name):
-    return ("dkv" if "dkv" in name else "dq" if "dq" in name else "fwd")
+    return next((k for k in ("dqkv", "dkv", "dq") if f"_{k}_" in name),
+                "fwd")
+
+
+def only(made, *passes):
+    """``(fns, avals)`` of a variant cut to the named passes."""
+    fns, avals = made
+    return {p: fns[p] for p in passes}, avals
+
+
+def transposed_tn(mod_path):
+    """A copy of the kernel module whose ``a.T @ b`` is an explicit
+    transpose and a plain matmul."""
+    mod = load_module(mod_path)
+    mod._dot_tn = lambda a, b: jnp.dot(               # noqa: E731
+        a.T, b, preferred_element_type=jnp.float32)
+    return mod
 
 
 def main(argv=None) -> int:
@@ -195,6 +232,9 @@ def main(argv=None) -> int:
     ap.add_argument("--yardstick", action="store_true")
     ap.add_argument("--layer", action="store_true",
                     help="also one layer's forward and backward, whole")
+    ap.add_argument("--transposed-tn", action="store_true",
+                    help="also the one-kernel backward with ds^T transposed "
+                         "explicitly")
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default=os.path.join(ROOT, "chiprun_out",
                                                   "flash_sweep.jsonl"))
@@ -245,6 +285,10 @@ def main(argv=None) -> int:
         todo = [("ours", lambda s, q, k: kernel_fns(ours, s, q, k), pairs),
                 ("fused", lambda s, q, k: kernel_fns(ours, s, q, k, True),
                  pairs)]
+        if a.transposed_tn:
+            alt = transposed_tn(ours.__file__)
+            todo.append(("ours.T", lambda s, q, k: only(
+                kernel_fns(alt, s, q, k), "bwd1"), pairs))
         if a.layer:
             todo.append(("ours", lambda s, q, k: layer_fns(ours, s, q, k),
                          pairs))
