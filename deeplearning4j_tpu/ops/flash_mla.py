@@ -21,13 +21,18 @@ heads at Dr = 64); the heads of a group run one after another over the same
 resident keys, each on its own lanes of the blocks. Same streaming softmax,
 same split of the loop into unmasked tiles and the diagonal's masked ones,
 same row-layout log-sum-exp and transposed dk/dv tiles as
-ops/flash_attention.py, whose helpers these kernels share. The dk/dv kernel
-writes the rotary key's gradient a head (``[B, T, H*Dr]``); the sum over heads
-is one small XLA reduction outside.
+ops/flash_attention.py, whose helpers these kernels share. The backward is one
+kernel over key blocks that takes dq, dk and dv from one pass over the score
+tiles, dq's whole length resident across the key axis; where that does not fit
+VMEM at the blocks dk/dv alone would get, the split dq and dk/dv pair, each of
+which computes every score tile again (:func:`_bwd_plan`). The kernel that
+gives dk writes the rotary key's gradient a head (``[B, T, H*Dr]``); the sum
+over heads is one small XLA reduction outside.
 
-Names: ``mla_flash_fwd``, ``mla_flash_bwd_dq``, ``mla_flash_bwd_dkv``, each
-followed by the heads of a group and the blocks that ran
-(``mla_flash_fwd_h2_q512_k512``). Off the TPU the layer takes
+Names: ``mla_flash_fwd``, the one backward ``mla_flash_bwd_fused``, the split
+pair ``mla_flash_bwd_dq`` and ``mla_flash_bwd_dkv``, each followed by the heads
+of a group and the blocks that ran (``mla_flash_fwd_h2_q512_k512``,
+``mla_flash_bwd_fused_h2_q512_k512``). Off the TPU the layer takes
 :func:`mla_attention_xla`; ``interpret=True`` runs the kernels in the Pallas
 interpreter (tests).
 """
@@ -46,14 +51,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.ops.flash_attention import (
-    ATTN_LSE, ATTN_OUT, _NEG_BIG, _aligned, _and, _delta_rows, _dot_nt,
-    _key_ranges, _lane, _pad_km, _pad_rows, _query_ranges, _tiles,
-    _vmem_params)
+    _VMEM_HEADROOM, _VMEM_MAX, ATTN_LSE, ATTN_OUT, _NEG_BIG, _aligned, _and,
+    _block_kw, _delta_rows, _dot_nt, _dot_tn, _key_ranges, _lane, _pad_km,
+    _pad_rows, _query_ranges, _tiles, _vmem_params, _when)
 from deeplearning4j_tpu.ops.flash_attention import _plan as _fa_plan
 
+# "dqkv" is the one backward kernel; its name is matched by no pattern that
+# reads the split pair's two events a call
 _NAMES = types.MappingProxyType(
     {"fwd": "mla_flash_fwd", "dq": "mla_flash_bwd_dq",
-     "dkv": "mla_flash_bwd_dkv"})
+     "dkv": "mla_flash_bwd_dkv", "dqkv": "mla_flash_bwd_fused"})
 
 
 class MlaDims(NamedTuple):
@@ -88,7 +95,10 @@ def heads_per_program(H: int, Dn: int, Dr: int, Dv: int) -> Optional[int]:
 def _working_set(kernel: str, bq: int, bk: int, q_pad: int, k_pad: int,
                  m: MlaDims, item: int, has_kmask: bool) -> int:
     """Bytes of VMEM one grid program holds, counted as
-    ops/flash_attention.py ``_working_set`` counts them."""
+    ops/flash_attention.py ``_working_set`` counts them. "dqkv" is "dkv"
+    with dq's whole-length result blocks (both parts) and one more tile; dq
+    accumulates in those blocks where they are float32, else in a float32
+    scratch of the same lanes, counted once."""
     row = lambda n: 8 * _lane(n) * 4                              # noqa: E731
     mat = lambda n, w: n * _lane(w) * item                        # noqa: E731
     G = m.G
@@ -102,15 +112,19 @@ def _working_set(kernel: str, bq: int, bk: int, q_pad: int, k_pad: int,
               + mat(k_pad, G * m.W) + mat(k_pad, m.Dr) + mat(bq, G * m.Dv)
               + 2 * G * row(bq))
         live = 6 * tile + bq * (_lane(m.Dn) + _lane(m.Dr)) * 4
-    elif kernel == "dkv":
+    elif kernel in ("dkv", "dqkv"):
         io = (mat(q_pad, G * m.Dn) + mat(q_pad, G * m.Dr)
               + mat(q_pad, G * m.Dv) + 2 * G * row(q_pad)
               + 2 * mat(bk, G * m.W) + mat(bk, m.Dr) + mat(bk, G * m.Dr))
         live = 6 * tile + bk * (_lane(m.Dn) + _lane(m.Dr) + _lane(m.Dv)) * 4
+        if kernel == "dqkv":
+            dq = mat(q_pad, G * m.Dn) + mat(q_pad, G * m.Dr)
+            io += dq
+            live += tile + (0 if item == 4 else dq * 4 // item)
     else:
         raise ValueError(f"unknown flash kernel {kernel!r}")
     if has_kmask:
-        io += row(k_pad if kernel != "dkv" else bk)
+        io += row(k_pad if kernel in ("fwd", "dq") else bk)
     return 2 * io + live
 
 
@@ -122,6 +136,22 @@ def _plan(kernels, T, m, item, has_kmask, block_q, block_k):
         working_set=lambda kn, bq, bk, q_pad, k_pad: _working_set(
             kn, bq, bk, q_pad, k_pad, m, item, has_kmask))
     return blocks, t_pad
+
+
+def _bwd_plan(T, m, item, has_kmask, block_q, block_k):
+    """:func:`_plan` for the backward, which also picks its kernels: the one
+    "dqkv" kernel where it takes the blocks the split pair's dk/dv kernel
+    would and its working set, dq's whole length resident, passes
+    ``_VMEM_MAX``'s test; else the split "dq" and "dkv" pair (a length whose
+    dq would cost the one kernel smaller, slower tiles)."""
+    args = (T, m, item, has_kmask, block_q, block_k)
+    one, t_pad = _plan(("dqkv",), *args)
+    split, _ = _plan(("dq", "dkv"), *args)
+    need = _working_set("dqkv", *one["dqkv"], t_pad, t_pad, m, item,
+                        has_kmask)
+    if one["dqkv"] == split["dkv"] and _VMEM_HEADROOM * need <= _VMEM_MAX:
+        return one, t_pad
+    return split, t_pad
 
 
 def _call_kw(kernel, interpret, m, bq, bk, t_pad, dtype, has_kmask):
@@ -243,16 +273,36 @@ def _dq_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, delta_ref,
 
 def _dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, delta_ref,
                 *rest, m: MlaDims, block_q: int, block_k: int, t_real: int,
-                t_pad: int, causal: bool, scale: float, has_kmask: bool):
+                t_pad: int, causal: bool, scale: float, has_kmask: bool,
+                with_dq: bool = False):
     """dk (both parts) and dv for one k-block of ``G`` heads, looping over
     q-blocks with the tiles transposed (keys down the sublanes), as
     ops/flash_attention.py ``_bwd_dkv_kernel``. The rotary key's gradient is
-    written a head: the caller sums over heads."""
-    if has_kmask:
-        km_ref, dkv_ref, dkr_ref = rest
+    written a head: the caller sums over heads.
+
+    ``with_dq`` (the one backward kernel, "dqkv"): the same tiles also give
+    ``dqn = scale * ds kn`` and ``dqr = scale * ds kr``, added a q tile at a
+    time into head h's lanes of accumulators that live across the k-block
+    grid axis (zeroed at the first k block, scaled and written at the last:
+    that axis runs in order). Refs after the kmask: dqn [1, t_pad, G*Dn] and
+    dqr [1, t_pad, G*Dr] (whole length, resident), dkv, dkr, then two
+    float32 scratch accumulators of dq's shapes where dq is not float32
+    (where it is, dq accumulates in its own result blocks)."""
+    km_ref = rest[0] if has_kmask else None
+    outs = rest[1:] if has_kmask else rest
+    if with_dq:
+        dqn_ref, dqr_ref, dkv_ref, dkr_ref, *acc = outs
+        accn_ref, accr_ref = acc or (dqn_ref, dqr_ref)
     else:
-        (dkv_ref, dkr_ref), km_ref = rest, None
-    ki = 0 if t_pad == block_k else pl.program_id(2)
+        dkv_ref, dkr_ref = outs
+    n_kb = t_pad // block_k
+    ki = 0 if n_kb == 1 else pl.program_id(2)
+    if with_dq:
+        @_when(ki == 0)
+        def _():
+            accn_ref[...] = jnp.zeros(accn_ref.shape, jnp.float32)
+            accr_ref[...] = jnp.zeros(accr_ref.shape, jnp.float32)
+
     k_loc = ki * block_k + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
     k_valid = (k_loc < t_real) if t_pad != t_real else None
     if km_ref is not None:
@@ -285,6 +335,10 @@ def _dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, delta_ref,
             dv = dv + jnp.dot(pt.astype(do.dtype), do,
                               preferred_element_type=jnp.float32)
             dst = (pt * (_dot_nt(v, do) - delta)).astype(qn.dtype)
+            if with_dq:
+                rows = pl.ds(start, block_q)
+                accn_ref[0, rows, h * Dn:(h + 1) * Dn] += _dot_tn(dst, kn)
+                accr_ref[0, rows, h * Dr:(h + 1) * Dr] += _dot_tn(dst, kr)
             return (dkn + jnp.dot(dst, qn, preferred_element_type=jnp.float32),
                     dkr + jnp.dot(dst, qr, preferred_element_type=jnp.float32),
                     dv)
@@ -298,6 +352,18 @@ def _dkv_kernel(qn_ref, qr_ref, kv_ref, kr_ref, do_ref, lse_ref, delta_ref,
         dkv_ref[0, :, h * W:h * W + Dn] = (dkn * scale).astype(dkv_ref.dtype)
         dkv_ref[0, :, h * W + Dn:(h + 1) * W] = dv.astype(dkv_ref.dtype)
         dkr_ref[0, :, h * Dr:(h + 1) * Dr] = (dkr * scale).astype(dkr_ref.dtype)
+    if with_dq:
+        @_when(ki == n_kb - 1)
+        def _():
+            def put(qb, carry):
+                rows = pl.ds(_aligned(qb, block_q), block_q)
+                for acc_ref, dq_ref in ((accn_ref, dqn_ref),
+                                        (accr_ref, dqr_ref)):
+                    dq_ref[0, rows, :] = (acc_ref[0, rows, :] * scale).astype(
+                        dq_ref.dtype)
+                return carry
+
+            _tiles([(0, t_pad // block_q, {})], put, 0)
 
 
 def _specs(m: MlaDims, interpret: bool):
@@ -362,13 +428,15 @@ def _fwd_call(qn, qr, kv, kr, *, m: MlaDims, bq, bk, t_pad, t_real, causal,
     )(*args)
 
 
-@functools.partial(jax.jit, static_argnames=_STATIC + ("dq_blocks",
-                                                       "dkv_blocks"))
-def _bwd_calls(qn, qr, kv, kr, do, lse, delta, *, m: MlaDims, dq_blocks,
-               dkv_blocks, t_pad, t_real, causal, scale, interpret,
-               kmask=None):
-    """The two backward calls over padded operands -> (dqn, dqr, dkv, the
-    rotary key's gradient a head [B, t_pad, H*Dr])."""
+@functools.partial(jax.jit, static_argnames=_STATIC + (
+    "dq_blocks", "dkv_blocks", "dqkv_blocks"))
+def _bwd_calls(qn, qr, kv, kr, do, lse, delta, *, m: MlaDims, t_pad, t_real,
+               causal, scale, interpret, kmask=None, dq_blocks=None,
+               dkv_blocks=None, dqkv_blocks=None):
+    """The backward's calls over padded operands -> (dqn, dqr, dkv, the
+    rotary key's gradient a head [B, t_pad, H*Dr]). ``dqkv_blocks`` (bq,
+    bk): the one kernel that gives all four; else ``dq_blocks`` and
+    ``dkv_blocks``, the split pair (:func:`_bwd_plan` says which)."""
     B, dtype = qn.shape[0], qn.dtype
     lanes, shared, rows, mask = _specs(m, interpret)
     G, has_km = m.G, kmask is not None
@@ -376,6 +444,32 @@ def _bwd_calls(qn, qr, kv, kr, do, lse, delta, *, m: MlaDims, dq_blocks,
                   has_kmask=has_km)
     args = [qn, qr, kv, kr, do, lse, delta] + ([kmask] if has_km else [])
     like = lambda x: jax.ShapeDtypeStruct(x.shape, dtype)        # noqa: E731
+
+    def by_key_block(kernel, bq, bk, dq_specs=(), dq_shapes=(), **kw):
+        """The dk/dv kernel's call, with dq's results first where it gives
+        them too."""
+        return pl.pallas_call(
+            functools.partial(_dkv_kernel, block_q=bq, block_k=bk,
+                              with_dq=bool(dq_specs), **common),
+            grid=(B, m.groups, t_pad // bk),
+            in_specs=[lanes(t_pad, G * m.Dn, True),
+                      lanes(t_pad, G * m.Dr, True), lanes(bk, G * m.W),
+                      shared(bk, m.Dr), lanes(t_pad, G * m.Dv, True),
+                      rows(t_pad, True), rows(t_pad, True)]
+            + ([mask(bk)] if has_km else []),
+            out_specs=[*dq_specs, lanes(bk, G * m.W), lanes(bk, G * m.Dr)],
+            out_shape=[*dq_shapes, like(kv), like(qr)],
+            **kw, **_call_kw(kernel, interpret, m, bq, bk, t_pad, dtype,
+                             has_km),
+        )(*args)
+
+    if dqkv_blocks is not None:
+        dq_specs = [lanes(t_pad, G * m.Dn, True), lanes(t_pad, G * m.Dr, True)]
+        scratch = [] if dtype == jnp.float32 else [
+            pltpu.VMEM((1, t_pad, G * w), jnp.float32) for w in (m.Dn, m.Dr)]
+        return tuple(by_key_block("dqkv", *dqkv_blocks, dq_specs,
+                                  [like(qn), like(qr)],
+                                  scratch_shapes=scratch))
 
     bq, bk = dq_blocks
     dqn, dqr = pl.pallas_call(
@@ -389,19 +483,7 @@ def _bwd_calls(qn, qr, kv, kr, do, lse, delta, *, m: MlaDims, dq_blocks,
         out_shape=[like(qn), like(qr)],
         **_call_kw("dq", interpret, m, bq, bk, t_pad, dtype, has_km),
     )(*args)
-
-    bq, bk = dkv_blocks
-    dkv, dkr = pl.pallas_call(
-        functools.partial(_dkv_kernel, block_q=bq, block_k=bk, **common),
-        grid=(B, m.groups, t_pad // bk),
-        in_specs=[lanes(t_pad, G * m.Dn, True), lanes(t_pad, G * m.Dr, True),
-                  lanes(bk, G * m.W), shared(bk, m.Dr),
-                  lanes(t_pad, G * m.Dv, True), rows(t_pad, True),
-                  rows(t_pad, True)] + ([mask(bk)] if has_km else []),
-        out_specs=[lanes(bk, G * m.W), lanes(bk, G * m.Dr)],
-        out_shape=[like(kv), like(qr)],
-        **_call_kw("dkv", interpret, m, bq, bk, t_pad, dtype, has_km),
-    )(*args)
+    dkv, dkr = by_key_block("dkv", *dkv_blocks)
     return dqn, dqr, dkv, dkr
 
 
@@ -436,14 +518,14 @@ def _flash_mla_fwd(qn, qr, kv, kr, kmask, m, causal, scale, block_q, block_k,
 def _flash_mla_bwd(m, causal, scale, block_q, block_k, interpret, res, g):
     qn, qr, kv, kr, kmask, o, lse = res
     B, T, _ = qn.shape
-    blocks, t_pad = _plan(("dq", "dkv"), T, m, qn.dtype.itemsize,
-                          kmask is not None, block_q, block_k)
+    blocks, t_pad = _bwd_plan(T, m, qn.dtype.itemsize, kmask is not None,
+                              block_q, block_k)
     km = _pad_km(kmask, t_pad) if kmask is not None else None
     dqn, dqr, dkv, dkr = _bwd_calls(
         *(_pad_rows(x, t_pad) for x in (qn, qr, kv, kr, g)), lse,
-        _delta_rows(g, o, (B, T, m.H, m.Dv), t_pad), m=m,
-        dq_blocks=blocks["dq"], dkv_blocks=blocks["dkv"], t_pad=t_pad,
-        t_real=T, causal=causal, scale=scale, interpret=interpret, kmask=km)
+        _delta_rows(g, o, (B, T, m.H, m.Dv), t_pad), m=m, t_pad=t_pad,
+        t_real=T, causal=causal, scale=scale, interpret=interpret, kmask=km,
+        **_block_kw(blocks))
     dkr = jnp.sum(dkr[:, :T].reshape(B, T, m.H, m.Dr).astype(jnp.float32),
                   axis=2).astype(kr.dtype)
     dkm = jnp.zeros_like(kmask) if kmask is not None else None

@@ -123,15 +123,43 @@ def _as_reference(qn, qr, kv, kr, H, Dn, Dr, kmask, scale):
     return _reference(q, k, kv[..., Dn:], True, kmask, scale).reshape(Bk, Tk, -1)
 
 
+def _split_plan(T, m, item, has_kmask, block_q, block_k):
+    """``_bwd_plan`` held to the split dq + dk/dv pair, what every backward
+    ran before the one kernel."""
+    return flash_mla._plan(("dq", "dkv"), T, m, item, has_kmask, block_q,
+                           block_k)
+
+
+def _bwd_kernels(fn, *args):
+    """The backward kernels a function's jaxpr calls, by name without its
+    heads and blocks (``mla_flash_bwd_fused_h2_q128_k128`` ->
+    ``mla_flash_bwd_fused``)."""
+    names, todo = [], [jax.make_jaxpr(fn)(*args).jaxpr]
+    while todo:
+        for e in todo.pop().eqns:
+            if e.primitive.name == "pallas_call":
+                names.append(e.params["name"].rsplit("_h", 1)[0])
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        todo.append(sub)
+    return sorted(n for n in names if "_bwd_" in n)
+
+
+@pytest.mark.parametrize("bwd", ["one", "split"],
+                         ids=["one-kernel", "split-pair"])
 @pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kmask"])
 @pytest.mark.parametrize("Tk,blocks", [(256, (128, 128)), (200, (64, 128)),
                                        (300, (None, None))],
                          ids=["aligned", "padded", "chosen"])
-def test_flash_mla_kernels_against_the_reference(Tk, blocks, masked):
+def test_flash_mla_kernels_against_the_reference(Tk, blocks, masked, bwd,
+                                                 monkeypatch):
     """Forward, dq (both parts), dk/dv and the rotary key's summed gradient
     at the published widths (128 | 64 score lanes, 128 value lanes, two heads
     a program), causal, with and without a key mask, at lengths that do and
-    do not fill the blocks."""
+    do not fill the blocks; the backward as the one kernel the plan takes at
+    these lengths, which also matches the split pair, or as that pair."""
     H, Dn, Dr, Dv = 2, 128, 64, 128
     ops, w, km = _operands(1 if Tk > 256 else 2, Tk, H, Dn, Dr, Dv)
     km = km if masked else None
@@ -141,14 +169,81 @@ def test_flash_mla_kernels_against_the_reference(Tk, blocks, masked):
         block_k=blocks[1], interpret=True) * w)
     plain = lambda *a: jnp.sum(_as_reference(                    # noqa: E731
         *a, H, Dn, Dr, km, scale) * w)
-    got = jax.value_and_grad(kern, argnums=(0, 1, 2, 3))(*ops)
+    # a new function each time: JAX keeps the trace of one it has seen
+    grad = lambda: jax.value_and_grad(                           # noqa: E731
+        lambda *a: kern(*a), argnums=(0, 1, 2, 3))
+    one = None
+    if bwd == "one":
+        assert _bwd_kernels(grad(), *ops) == ["mla_flash_bwd_fused"]
+        one = grad()(*ops)
+    monkeypatch.setattr(flash_mla, "_bwd_plan", _split_plan)
+    assert _bwd_kernels(grad(), *ops) == ["mla_flash_bwd_dkv",
+                                          "mla_flash_bwd_dq"]
+    split = grad()(*ops)
+    got = one or split
     want = jax.value_and_grad(plain, argnums=(0, 1, 2, 3))(*ops)
     _close(got[0], want[0], 1e-5)
-    for g, r in zip(got[1], want[1]):
+    for g, r, c in zip(got[1], want[1], split[1]):
         _close(g, r, 2e-5)
+        _close(g, c, 2e-5)
     # the XLA form the layer takes off the TPU is the same function
     _close(flash_mla.mla_attention_xla(*ops, n_heads=H, scale=scale, kmask=km),
            _as_reference(*ops, H, Dn, Dr, km, scale), 1e-5)
+
+
+def test_a_bfloat16_backward_keeps_dq_in_float32_scratch(monkeypatch):
+    """bfloat16 operands: the one kernel adds dq into float32 scratch blocks
+    and writes it once, cast, at the last key block; its gradients are the
+    split pair's, which carries dq in float32 over the same tiles in the same
+    order, to the last bfloat16 bit or so."""
+    H, Dn, Dr, Dv = 2, 128, 64, 128
+    ops, w, km = _operands(2, 256, H, Dn, Dr, Dv, seed=3)
+    ops = tuple(x.astype(jnp.bfloat16) for x in ops)
+    grad = lambda: jax.grad(lambda *a: jnp.sum(                  # noqa: E731
+        flash_mla.flash_mla(*a, n_heads=H, scale=1.0 / np.sqrt(Dn + Dr),
+                            kmask=km, block_q=128, block_k=128,
+                            interpret=True).astype(jnp.float32) * w),
+        argnums=(0, 1, 2, 3))
+    assert _bwd_kernels(grad(), *ops) == ["mla_flash_bwd_fused"]
+    one = grad()(*ops)
+    monkeypatch.setattr(flash_mla, "_bwd_plan", _split_plan)
+    for g, c in zip(one, grad()(*ops)):
+        assert g.dtype == jnp.bfloat16
+        _close(g.astype(jnp.float32), c.astype(jnp.float32), 1e-2)
+
+
+@pytest.mark.parametrize("item", [4, 2], ids=["float32", "bfloat16"])
+def test_the_backward_plan_takes_one_kernel_where_it_keeps_the_split_blocks(
+        item):
+    """At the benchmark cell's call (H 32, Dn 128, Dr 64, Dv 128, T 8192) the
+    backward is the one kernel at the 512 x 512 blocks dk/dv alone gets:
+    float32 dq accumulates in its own two result blocks (2 x 12 MiB more
+    than dk/dv, and one tile), a bfloat16 one in a float32 scratch of the
+    same lanes (2 x 6 MiB, one tile and 12 MiB). At 8704 the one kernel
+    would have to shrink its blocks where dk/dv keeps 512 x 512 in float32:
+    the split pair; in bfloat16 it still fits."""
+    m, MiB = flash_mla.MlaDims(32, 128, 64, 128, 2), 1 << 20
+    need = lambda kn, T, b: flash_mla._working_set(              # noqa: E731
+        kn, *b, T, T, m, item, False)
+    extra = (2 * 12 if item == 4 else 2 * 6 + 12) + 1
+    assert (need("dqkv", 8192, (512, 512)) - need("dkv", 8192, (512, 512))
+            == extra * MiB)
+    assert flash_mla._bwd_plan(8192, m, item, False, None, None) == (
+        {"dqkv": (512, 512)}, 8192)
+    assert 1.25 * need("dqkv", 8192, (512, 512)) <= 100 * MiB
+    blocks, t_pad = flash_mla._bwd_plan(8704, m, item, False, None, None)
+    assert t_pad == 8704
+    if item == 4:
+        assert blocks == {"dq": (512, 512), "dkv": (512, 512)}
+        assert flash_mla._plan(("dqkv",), 8704, m, item, False, None,
+                               None)[0]["dqkv"] < (512, 512)
+    else:
+        assert blocks == {"dqkv": (512, 512)}
+    # a key mask, or explicit blocks, leave the choice to the same test
+    assert set(flash_mla._bwd_plan(8192, m, item, True, None, None)[0]) == {
+        "dqkv"}
+    assert flash_mla._bwd_plan(300, m, item, False, 64, 128) == (
+        {"dqkv": (64, 128)}, 384)
 
 
 def test_flash_mla_widths_that_fill_no_lane_block_are_refused():

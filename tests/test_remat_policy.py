@@ -43,9 +43,8 @@ MIXERS = {
     "gated_mlp": lambda: GatedMLP(width=40),
 }
 # the forward kernel, the width of what it writes and the number of backward
-# kernels (one that gives dq, dk and dv; latent attention's split pair), by
-# attention mixer
-KERNELS = {"gqa": ("flash_fwd", 2 * 64, 1), "mla": ("mla_flash_fwd", 2 * 128, 2)}
+# kernels (one that gives dq, dk and dv, in either module), by attention mixer
+KERNELS = {"gqa": ("flash_fwd", 2 * 64, 1), "mla": ("mla_flash_fwd", 2 * 128, 1)}
 PLAIN = sorted(set(MIXERS) - set(KERNELS))
 
 
